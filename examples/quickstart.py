@@ -34,7 +34,7 @@ print(f"quantized 256x384 weight to 6-bit exponents: "
 ref = x @ w
 deq = ed.dequant_matmul(
     eq.encode(x, eq.fit(x, 7)), eq.fit(x, 7), codes, qp)  # both quantized
-kern = lut_dequant_matmul(x, codes, eq.decode_table(qp),
+kern = lut_dequant_matmul(x, codes, eq.pack_qmeta(qp),
                           out_dtype=jnp.float32)           # activations fp
 count = ed.counting_dot(
     eq.encode(x[0], qp_x := eq.fit(x[0], 7)), qp_x,

@@ -1,5 +1,5 @@
 """qwen3-1.7b [dense] — 28L d_model=2048 16H (GQA kv=8) d_ff=6144
-vocab=151936, qk_norm, GQA [hf:Qwen/Qwen3-8B; hf]."""
+vocab=151936, qk_norm, GQA, tied embeddings [hf:Qwen/Qwen3-1.7B; hf]."""
 
 from repro.configs.base import ModelConfig
 

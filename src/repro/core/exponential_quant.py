@@ -113,13 +113,12 @@ def decode_table(params: ExpQuantParams, dtype=jnp.float32) -> jax.Array:
 
     Entries outside the live exponent range decode via the same formula
     (they are never produced by :func:`encode`); this keeps the table a
-    pure function of ``params`` and gather-friendly.
+    pure function of ``params`` and gather-friendly.  Built through
+    :func:`decode_meta`, the closed form the kernels decode with, so a
+    table lookup and an in-kernel decode give the same values.
     """
-    code = jnp.arange(256, dtype=jnp.int32)
-    sign = jnp.where((code >> 7) > 0, -1.0, 1.0)
-    e = (code & 0x7F).astype(jnp.float32) + params.e_min
-    mag = params.alpha * jnp.power(params.base, e) + params.beta
-    return (sign * mag).astype(dtype)
+    return decode_meta(jnp.arange(256, dtype=jnp.int32), pack_qmeta(params),
+                       dtype)
 
 
 def decode(codes: jax.Array, params: ExpQuantParams, dtype=jnp.float32) -> jax.Array:
@@ -157,8 +156,24 @@ def encode_meta(x: jax.Array, qmeta: jax.Array) -> jax.Array:
     mag = jnp.abs(x).astype(jnp.float32)
     arg = jnp.maximum((mag - beta) / alpha, 1e-30)
     e = jnp.clip(jnp.round(jnp.log(arg) / jnp.log(base)), e_min, e_max)
-    biased = (e - e_min).astype(jnp.uint8)
-    return ((x < 0).astype(jnp.uint8) << 7) | biased
+    # assemble the byte in int32 and narrow once: Mosaic casts neither
+    # f32 nor bool straight to u8
+    code = jnp.where(x < 0, 128, 0) + (e - e_min).astype(jnp.int32)
+    return code.astype(jnp.uint8)
+
+
+def decode_exp(codes: jax.Array, alpha, beta, base, bits,
+               dtype=jnp.float32) -> jax.Array:
+    """The DNA-TEQ closed form ``S * (alpha * base**e + beta)`` with the
+    four parameters as arrays that broadcast against ``codes``.  Every
+    decode in the repository — kernel tiles, oracles, 256-entry tables —
+    is this one function."""
+    e_min = -jnp.exp2(bits - 1.0)
+    c = codes.astype(jnp.int32)
+    sign = 1.0 - 2.0 * (c >> 7).astype(jnp.float32)
+    e = (c & 0x7F).astype(jnp.float32) + e_min
+    mag = alpha * jnp.exp(e * jnp.log(base)) + beta
+    return (sign * mag).astype(dtype)
 
 
 def decode_meta(codes: jax.Array, qmeta: jax.Array,
@@ -167,14 +182,8 @@ def decode_meta(codes: jax.Array, qmeta: jax.Array,
 
     Like :func:`encode_meta`, leading qmeta dims broadcast against
     ``codes`` (per-head metas decode per-head)."""
-    alpha, beta, base, bits = (qmeta[..., 0], qmeta[..., 1],
-                               qmeta[..., 2], qmeta[..., 3])
-    e_min = -jnp.exp2(bits - 1.0)
-    c = codes.astype(jnp.int32)
-    sign = 1.0 - 2.0 * (c >> 7).astype(jnp.float32)
-    e = (c & 0x7F).astype(jnp.float32) + e_min
-    mag = alpha * jnp.exp(e * jnp.log(base)) + beta
-    return (sign * mag).astype(dtype)
+    return decode_exp(codes, qmeta[..., 0], qmeta[..., 1], qmeta[..., 2],
+                      qmeta[..., 3], dtype)
 
 
 def _ls_alpha_beta(powers: jax.Array, mag: jax.Array, weights: jax.Array):
@@ -190,22 +199,59 @@ def _ls_alpha_beta(powers: jax.Array, mag: jax.Array, weights: jax.Array):
     return alpha, beta
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "iters"))
-def _fit_one_base(x: jax.Array, base: jax.Array, bits: int, iters: int = 6):
-    """Alternating (assign, regress) fit for one candidate base.
+def _order_stat(bits: jax.Array, k: int) -> jax.Array:
+    """The ``k``-th smallest (0-indexed) of non-negative float32 values
+    given as their int32 bit patterns, which order like the values.
+    Bisection over the patterns: 31 counting passes over the tensor.  A
+    sort would do in one, but the TPU compiler takes over half a minute
+    to build a sort of even 64K elements."""
+    def body(_, lohi):
+        lo, hi = lohi
+        mid = lo + (hi - lo) // 2
+        enough = jnp.sum(bits <= mid) > k      # >= k+1 values <= mid
+        return jnp.where(enough, lo, mid + 1), jnp.where(enough, mid, hi)
+
+    lo, _ = jax.lax.fori_loop(0, 31, body,
+                              (jnp.int32(0), jnp.int32(0x7F800000)))
+    return jax.lax.bitcast_convert_type(lo, jnp.float32)
+
+
+def _percentile(mag: jax.Array, q: float) -> jax.Array:
+    """``jnp.percentile(mag, q)`` (linear interpolation, the same float32
+    index arithmetic) for non-negative float32 ``mag``, without a sort."""
+    n = np.float32(mag.size)
+    pos = np.float32(q) / np.float32(100) * (n - np.float32(1))
+    low, high = np.floor(pos), np.ceil(pos)
+    w_high = pos - low
+    w_low = np.float32(1) - w_high
+    bits = jax.lax.bitcast_convert_type(mag, jnp.int32)
+    return (_order_stat(bits, int(low)) * w_low
+            + _order_stat(bits, int(high)) * w_high)
+
+
+def _magnitude_range(mag: jax.Array):
+    """(lo, hi): the 1st and 99.5th percentiles of the magnitudes, the
+    span the exponent range is first mapped onto.  A tensor holding a
+    zero (or a NaN) gets the fixed span (1e-6, 1)."""
+    degenerate = jnp.any(~(mag > 0))
+    lo, hi = (jnp.where(degenerate, jnp.nan, _percentile(mag, q))
+              for q in (1.0, 99.5))
+    lo = jnp.nan_to_num(lo, nan=1e-6)
+    hi = jnp.nan_to_num(hi, nan=1.0)
+    return lo, jnp.maximum(hi, lo * (1.0 + 1e-3))
+
+
+def _fit_one_base(mag: jax.Array, lo, hi, base: jax.Array, bits: int,
+                  iters: int = 6):
+    """Alternating (assign, regress) fit of flat magnitudes ``mag`` for
+    one candidate base.
 
     Returns (alpha, beta, mse).
     """
-    mag = jnp.abs(x.reshape(-1)).astype(jnp.float32)
     live = (mag > 0).astype(jnp.float32)  # zeros carry no information
-    e_min = -(2 ** (bits - 1))
     e_max = 2 ** (bits - 1) - 1
 
     # --- init: map magnitude quantiles onto the exponent range -----------
-    lo = jnp.percentile(jnp.where(mag > 0, mag, jnp.nan), 1.0)
-    hi = jnp.percentile(jnp.where(mag > 0, mag, jnp.nan), 99.5)
-    lo = jnp.nan_to_num(lo, nan=1e-6)
-    hi = jnp.maximum(jnp.nan_to_num(hi, nan=1.0), lo * (1.0 + 1e-3))
     # alpha*b^e_max ~ hi ; alpha*b^e_min ~ lo  (beta starts at 0)
     log_b = jnp.log(base)
     alpha0 = hi / jnp.exp(e_max * log_b)
@@ -229,6 +275,19 @@ def _fit_one_base(x: jax.Array, base: jax.Array, bits: int, iters: int = 6):
     return alpha, beta, mse
 
 
+@functools.partial(jax.jit, static_argnames=("bases", "bits", "iters"))
+def _fit(x: jax.Array, bases: tuple[float, ...], bits: int, iters: int):
+    mag = jnp.abs(x.reshape(-1)).astype(jnp.float32)
+    lo, hi = _magnitude_range(mag)
+    bases_arr = jnp.asarray(bases, dtype=jnp.float32)
+    # one base at a time: a vmap would hold a copy of every per-element
+    # temporary for each base (gigabytes for an embedding table)
+    alphas, betas, mses = jax.lax.map(
+        lambda b: _fit_one_base(mag, lo, hi, b, bits, iters), bases_arr)
+    k = jnp.argmin(mses)
+    return alphas[k], betas[k], bases_arr[k]
+
+
 DEFAULT_BASES: tuple[float, ...] = tuple(
     float(b) for b in (2.0 ** (1.0 / k) for k in (1, 2, 3, 4, 6, 8, 12, 16))
 )
@@ -241,12 +300,8 @@ def fit(
     iters: int = 6,
 ) -> ExpQuantParams:
     """Search (base, alpha, beta) minimising magnitude-domain MSE."""
-    bases_arr = jnp.asarray(bases, dtype=jnp.float32)
-    alphas, betas, mses = jax.vmap(
-        lambda b: _fit_one_base(x, b, bits, iters)
-    )(bases_arr)
-    k = jnp.argmin(mses)
-    return ExpQuantParams(alphas[k], betas[k], bases_arr[k], bits)
+    alpha, beta, base = _fit(x, tuple(float(b) for b in bases), bits, iters)
+    return ExpQuantParams(alpha, beta, base, bits)
 
 
 def quantize(x: jax.Array, bits: int, **kw):

@@ -25,10 +25,10 @@ uses is canonicalized to a 2-D ``[M, K] @ [K, N]`` (codes reshaped /
 byte-transposed, never decoded) and dispatched to the fused Pallas
 kernel, with batched specs vmapped over the kernel.  A
 :class:`FusedPolicy` (context-scoped) replaces the old module-global
-kernel switch: it picks fused vs. materialize per call, selects the
-decode mode, and controls epilogue fusion and the flash-decode
-attention kernel.  Specs the canonicalizer cannot express (repeated
-labels, diagonal-style contractions) fall back to materialize+einsum.
+kernel switch: it picks fused vs. materialize per call and controls
+epilogue fusion and the flash-decode attention kernel.  Specs the
+canonicalizer cannot express (repeated labels, diagonal-style
+contractions) fall back to materialize+einsum.
 """
 
 from __future__ import annotations
@@ -61,9 +61,8 @@ class FusedPolicy:
     """
 
     mode: str = "auto"              # auto | fused | materialize
-    decode_mode: str = "gather"     # gather | alu
     fuse_epilogues: bool = True     # act/bias/gate epilogues in-kernel
-    flash_decode: bool = True       # decode_gqa kernel in decode_step
+    flash_decode: bool = True       # paged attention kernels (else oracles)
     autotune: bool | None = None    # None = only on real TPU
     act_quant: bool = True          # honor act-quant params when present
                                     # (calibrated metas ride the params
@@ -211,7 +210,7 @@ def _fused_einsum(x, w: dict, plan: _EinsumPlan, spec: str,
     way)."""
     from repro.kernels.lut_dequant_matmul import ops as _ops
 
-    codes, lut, qmeta = w["codes"], w["lut"], w["qmeta"]
+    codes, qmeta = w["codes"], w["qmeta"]
     x_is_q = isinstance(x, eq.QTensor)
     if x_is_q and not plan.batch and codes.ndim == 2 \
             and plan.w_perm == (1, 0):
@@ -244,13 +243,11 @@ def _fused_einsum(x, w: dict, plan: _EinsumPlan, spec: str,
     ct = codes if kernel_transpose else _maybe_transpose(codes, plan.w_perm)
     if x_is_q:
         call = functools.partial(
-            _ops.lut_dequant_matmul_dual, lut_x=x.lut, lut_w=lut,
-            qmeta_x=x.qmeta, qmeta_w=qmeta, decode_mode=pol.decode_mode,
+            _ops.lut_dequant_matmul_dual, qmeta_x=x.qmeta, qmeta_w=qmeta,
             out_dtype=jnp.float32, autotune=pol.autotune)
     else:
         call = functools.partial(
-            _ops.lut_dequant_matmul, lut=lut, qmeta=qmeta,
-            decode_mode=pol.decode_mode, out_dtype=jnp.float32,
+            _ops.lut_dequant_matmul, qmeta=qmeta, out_dtype=jnp.float32,
             autotune=pol.autotune)
     if plan.batch:
         x2 = xt.reshape((b, m, k))
@@ -291,8 +288,7 @@ def dense(x, w, *, dtype=None, epilogue: str | None = None,
             if x_is_q:
                 x2 = x.codes.reshape((-1, x.shape[-1]))
                 out = _ops.lut_dequant_matmul_dual(
-                    x2, w["codes"], x.lut, w["lut"], x.qmeta, w["qmeta"],
-                    decode_mode=pol.decode_mode,
+                    x2, w["codes"], x.qmeta, w["qmeta"],
                     epilogue=epilogue if fuse_ep else None,
                     bias=bias if fuse_ep else None,
                     out_qmeta=(out_quant["qmeta"]
@@ -304,9 +300,7 @@ def dense(x, w, *, dtype=None, epilogue: str | None = None,
                                       out_quant["lut"], out_quant["qmeta"])
             else:
                 out = _ops.lut_dequant_matmul(
-                    x.reshape((-1, x.shape[-1])), w["codes"],
-                    w["lut"], w["qmeta"],
-                    decode_mode=pol.decode_mode,
+                    x.reshape((-1, x.shape[-1])), w["codes"], w["qmeta"],
                     epilogue=epilogue if fuse_ep else None,
                     bias=bias if fuse_ep else None,
                     out_dtype=jnp.float32, autotune=pol.autotune)
@@ -397,22 +391,19 @@ def gated_mlp(x, w_gate, w_up, activation: str, *,
         if x_is_q:
             x2 = x.codes.reshape((-1, x.shape[-1]))
             out = _ops.lut_dequant_matmul_dual_gated(
-                x2, w_gate["codes"], w_up["codes"], x.lut,
-                w_gate["lut"], w_up["lut"], x.qmeta, w_gate["qmeta"],
-                w_up["qmeta"], activation=activation,
+                x2, w_gate["codes"], w_up["codes"], x.qmeta,
+                w_gate["qmeta"], w_up["qmeta"], activation=activation,
                 out_qmeta=(out_quant["qmeta"] if out_quant is not None
                            else None),
-                decode_mode=pol.decode_mode, out_dtype=jnp.float32,
-                autotune=pol.autotune)
+                out_dtype=jnp.float32, autotune=pol.autotune)
             if out_quant is not None:
                 return eq.QTensor(out.reshape(lead + (n,)),
                                   out_quant["lut"], out_quant["qmeta"])
         else:
             out = _ops.lut_dequant_matmul_gated(
                 x.reshape((-1, x.shape[-1])), w_gate["codes"],
-                w_up["codes"], w_gate["lut"], w_up["lut"],
-                w_gate["qmeta"], w_up["qmeta"], activation=activation,
-                decode_mode=pol.decode_mode, out_dtype=jnp.float32,
+                w_up["codes"], w_gate["qmeta"], w_up["qmeta"],
+                activation=activation, out_dtype=jnp.float32,
                 autotune=pol.autotune)
         out = out.reshape(lead + (n,)).astype(cdtype)
         return _finish_out(out, out_quant)
@@ -468,21 +459,25 @@ def _path_str(path) -> tuple:
     return tuple(out)
 
 
+@functools.partial(jax.jit, static_argnames=("bits", "lut_dtype"))
+def _quantize_leaf(x, bits: int, lut_dtype):
+    """Fit, encode and pack one tensor as one program: run op by op, the
+    encode and SQNR passes would each hold full-size temporaries at once
+    (gigabytes for an embedding table, next to the f32 model)."""
+    x = x.astype(jnp.float32)
+    qp = eq.fit(x, bits)
+    return eq.pack_qtensor(eq.encode(x, qp), qp, lut_dtype), eq.sqnr_db(x, qp)
+
+
 def _quantize_stacked(leaf, bits: int, lut_dtype):
     """Per-layer DNA-TEQ fit for scan-stacked weights [L, ...]: one
     quantizer per layer (faithful to the paper's per-layer precision),
     packed with leading L on every field so lax.scan slices cleanly."""
-    def enc(x):
-        qp = eq.fit(x, bits)
-        codes = eq.encode(x, qp)
-        lut = eq.decode_table(qp, lut_dtype)
-        meta = jnp.stack([qp.alpha, qp.beta, qp.base,
-                          jnp.float32(bits)]).astype(jnp.float32)
-        return codes, lut, meta, eq.sqnr_db(x, qp)
-
-    codes, luts, metas, sqnrs = jax.vmap(enc)(leaf.astype(jnp.float32))
-    return ({"codes": codes, "lut": luts, "qmeta": metas},
-            float(jnp.mean(sqnrs)))
+    # layer by layer (not vmapped) so the fit's temporaries are one
+    # layer's worth, not the whole stack's
+    packed, sqnrs = jax.lax.map(
+        lambda x: _quantize_leaf(x, bits, lut_dtype), leaf)
+    return packed, float(jnp.mean(sqnrs))
 
 
 def quantize_tree(
@@ -513,9 +508,9 @@ def quantize_tree(
             packed, sqnr = _quantize_stacked(leaf, bits, lut_dtype)
             report[key] = (bits, sqnr)
             return packed
-        codes, qp = eq.quantize(leaf.astype(jnp.float32), bits)
-        report[key] = (bits, float(eq.sqnr_db(leaf, qp)))
-        return eq.pack_qtensor(codes, qp, lut_dtype)
+        packed, sqnr = _quantize_leaf(leaf, bits, lut_dtype)
+        report[key] = (bits, float(sqnr))
+        return packed
 
     new = jax.tree_util.tree_map_with_path(visit, params)
     return new, report

@@ -1,134 +1,33 @@
 """Decode-step GQA attention with in-kernel quantized-KV dequantization
 (flash-decoding over the cache; the serving hot-spot of §Perf Cell A).
 
-One new query token per sequence attends over a [S, n_kv, hd] cache that
-may be stored in float8_e4m3fn (or any narrow dtype): the cast to f32
-happens *inside* the kernel, after the HBM→VMEM DMA — so the bytes that
-actually cross HBM are the narrow ones.  This is the kernel-level
-guarantee that EXPERIMENTS.md §Perf A2 found XLA will not give you for
-free (it hoists dequantization above the data movement).
+One new query token per sequence attends over a cache that may be
+stored in float8_e4m3fn (or any narrow dtype), or as uint8 DNA-TEQ
+codes: the decode to f32 happens *inside* the kernel, after the
+HBM→VMEM DMA — so the bytes that actually cross HBM are the narrow
+ones.  This is the kernel-level guarantee that EXPERIMENTS.md §Perf A2
+found XLA will not give you for free (it hoists dequantization above
+the data movement).
 
-Grid: (B, S/bs) — batch parallel, cache blocks "arbitrary" with the
-classic online-softmax (m, l, acc) VMEM carries; causal validity comes
-from the per-sequence length prefetch (lengths[b] <= S), so one compiled
-kernel serves ragged batches.
+A decode step is a prefill chunk of one token whose query sits at
+position ``lengths[b] - 1``: it attends exactly the cache positions
+``< lengths[b]``.  So every variant here runs the paged flash-prefill
+kernel with S = 1 — one kernel body, one set of Mosaic layouts — and a
+contiguous ``[B, S, n_kv, hd]`` cache is a paged one whose block table
+is the identity.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import exponential_quant as eq
-from repro.kernels._codes import decode_heads
-from repro.kernels._compat import CompilerParams
-
-
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, block_s: int, num_kv: int, groups: int, out_dtype):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)              # [n_kv, g, hd]
-    k = k_ref[0].astype(jnp.float32)              # [bs, n_kv, hd]  (dequant!)
-    v = v_ref[0].astype(jnp.float32)
-    hd = q.shape[-1]
-    scale = 1.0 / math.sqrt(hd)
-
-    logit = jnp.einsum("ngh,snh->ngs", q, k,
-                       preferred_element_type=jnp.float32) * scale
-    pos = j * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, block_s), 2)
-    valid = pos < len_ref[b]
-    logit = jnp.where(valid, logit, -1e30)
-
-    m_prev = m_ref[...]                            # [n_kv, g]
-    m_new = jnp.maximum(m_prev, jnp.max(logit, axis=-1))
-    p = jnp.exp(logit - m_new[..., None])          # [n_kv, g, bs]
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-        "ngs,snh->ngh", p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _flush():
-        # A sequence with no valid entries (lengths[b] == 0) never
-        # raised the running max off its -1e30 init; emit zeros for it
-        # instead of the softmax-of-all-masked mean.
-        seen = m_ref[...] > -5e29                      # [n_kv, g]
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        o_ref[0] = jnp.where(seen[..., None], out, 0.0).astype(out_dtype)
-
-
-def _paged_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, block_s: int, num_kv: int,
-                  groups: int, out_dtype):
-    # Same online-softmax body; the block table only changes *which*
-    # page the DMA fetched (the index_map), not the math.
-    del bt_ref
-    _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            block_s=block_s, num_kv=num_kv, groups=groups,
-            out_dtype=out_dtype)
-
-
-def _paged_codes_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, qlut_ref,
-                        klut_ref, vlut_ref, om_ref, o_ref, m_ref, l_ref,
-                        acc_ref, *, block_s: int):
-    """Codes-mode body: q and the KV pages arrive as uint8 DNA-TEQ
-    codes, decoded through 256-entry VMEM LUTs *after* the HBM→VMEM DMA
-    (1 B/elem crosses HBM); the flush re-encodes the context under
-    ``om_ref`` (the attn_out site meta) so the kernel is code-in/
-    code-out — no f32 activation ever leaves it."""
-    del bt_ref
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = jnp.take(qlut_ref[0], q_ref[0].astype(jnp.int32), axis=0)
-    k = decode_heads(klut_ref[...], k_ref[0])     # [bs, n_kv, hd] (dequant!)
-    v = decode_heads(vlut_ref[...], v_ref[0])
-    hd = q.shape[-1]
-    scale = 1.0 / math.sqrt(hd)
-
-    logit = jnp.einsum("ngh,snh->ngs", q, k,
-                       preferred_element_type=jnp.float32) * scale
-    pos = j * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, block_s), 2)
-    valid = pos < len_ref[b]
-    logit = jnp.where(valid, logit, -1e30)
-
-    m_prev = m_ref[...]                            # [n_kv, g]
-    m_new = jnp.maximum(m_prev, jnp.max(logit, axis=-1))
-    p = jnp.exp(logit - m_new[..., None])          # [n_kv, g, bs]
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-        "ngs,snh->ngh", p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _flush():
-        seen = m_ref[...] > -5e29                      # [n_kv, g]
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        out = jnp.where(seen[..., None], out, 0.0)     # [n_kv, g, hd]
-        o_ref[0] = eq.encode_meta(out, om_ref[0])
+from repro.kernels.flash_prefill.flash_prefill import (
+    flash_prefill_paged_codes_kernel,
+    flash_prefill_paged_kernel,
+)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -136,9 +35,9 @@ def decode_gqa_paged_codes_kernel(
     q_codes: jax.Array,       # [B, n_kv, g, hd] uint8 — roped q codes
     k_pages: jax.Array,       # [N_blocks, bs, n_kv, hd] uint8 codes
     v_pages: jax.Array,       # [N_blocks, bs, n_kv, hd] uint8 codes
-    q_lut: jax.Array,         # [256] f32 — attn_q decode table
-    k_lut: jax.Array,         # [n_kv, 256] f32 — per-head K decode tables
-    v_lut: jax.Array,         # [n_kv, 256] f32 — per-head V decode tables
+    q_qmeta: jax.Array,       # [4] f32 — attn_q parameters
+    k_qmeta: jax.Array,       # [n_kv, 4] f32 — per-head K parameters
+    v_qmeta: jax.Array,       # [n_kv, 4] f32 — per-head V parameters
     out_qmeta: jax.Array,     # [4] f32 — attn_out (alpha, beta, base, bits)
     block_tables: jax.Array,  # [B, max_blk] int32
     lengths: jax.Array,       # [B] int32
@@ -147,52 +46,12 @@ def decode_gqa_paged_codes_kernel(
 ) -> jax.Array:
     """Codes-mode flash decode: same paging/masking contract as
     :func:`decode_gqa_paged_kernel`, but every operand is uint8 DNA-TEQ
-    codes.  Decode tables ride as VMEM-resident blocks (constant
-    index_map — fetched once, the dual-LUT matmul idiom); the output is
-    the uint8 re-encode of the context under ``out_qmeta``.  Returns
-    [B, n_kv, g, hd] uint8.
-    """
-    b, n_kv, g, hd = q_codes.shape
-    block_s = k_pages.shape[1]
-    max_blk = block_tables.shape[1]
-    grid = (b, max_blk)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,   # lengths, block_tables
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n_kv, g, hd), lambda i, j, L, T: (i, 0, 0, 0)),
-            pl.BlockSpec((1, block_s, n_kv, hd),
-                         lambda i, j, L, T: (T[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, block_s, n_kv, hd),
-                         lambda i, j, L, T: (T[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, 256), lambda i, j, L, T: (0, 0)),
-            pl.BlockSpec((n_kv, 256), lambda i, j, L, T: (0, 0)),
-            pl.BlockSpec((n_kv, 256), lambda i, j, L, T: (0, 0)),
-            pl.BlockSpec((1, 4), lambda i, j, L, T: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_kv, g, hd),
-                               lambda i, j, L, T: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_kv, g), jnp.float32),        # running max
-            pltpu.VMEM((n_kv, g), jnp.float32),        # running denom
-            pltpu.VMEM((n_kv, g, hd), jnp.float32),    # accumulator
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_codes_kernel, block_s=block_s),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.uint8),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q_codes, k_pages, v_pages,
-      q_lut.astype(jnp.float32).reshape(1, 256),
-      k_lut.astype(jnp.float32),
-      v_lut.astype(jnp.float32),
-      out_qmeta.astype(jnp.float32).reshape(1, 4))
+    codes, and the output is the uint8 re-encode of the context under
+    ``out_qmeta``.  Returns [B, n_kv, g, hd] uint8."""
+    return flash_prefill_paged_codes_kernel(
+        q_codes[:, None], k_pages, v_pages, q_qmeta, k_qmeta, v_qmeta,
+        out_qmeta, block_tables, lengths - 1, lengths,
+        interpret=interpret)[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -212,43 +71,12 @@ def decode_gqa_paged_kernel(
     ``block_tables[i, j]``; the block table rides as a scalar-prefetch
     operand so the page id is known before the HBM→VMEM DMA is issued —
     the gather happens in the BlockSpec index_map, never as a
-    materialized [B, S] cache.  Everything else (per-sequence length
-    masking, in-kernel narrow-dtype dequant, online-softmax VMEM
-    carries) matches :func:`decode_gqa_kernel`.
+    materialized [B, S] cache.  Rows with ``lengths[b] == 0`` return
+    zeros.  Returns [B, n_kv, g, hd].
     """
-    b, n_kv, g, hd = q.shape
-    block_s = k_pages.shape[1]
-    max_blk = block_tables.shape[1]
-    grid = (b, max_blk)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,   # lengths, block_tables
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n_kv, g, hd), lambda i, j, L, T: (i, 0, 0, 0)),
-            pl.BlockSpec((1, block_s, n_kv, hd),
-                         lambda i, j, L, T: (T[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, block_s, n_kv, hd),
-                         lambda i, j, L, T: (T[i, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_kv, g, hd), lambda i, j, L, T: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_kv, g), jnp.float32),        # running max
-            pltpu.VMEM((n_kv, g), jnp.float32),        # running denom
-            pltpu.VMEM((n_kv, g, hd), jnp.float32),    # accumulator
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, block_s=block_s, num_kv=n_kv,
-                          groups=g, out_dtype=out_dtype),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), out_dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_pages, v_pages)
+    return flash_prefill_paged_kernel(
+        q[:, None], k_pages, v_pages, block_tables, lengths - 1, lengths,
+        out_dtype=out_dtype, interpret=interpret)[:, 0]
 
 
 @functools.partial(
@@ -263,33 +91,13 @@ def decode_gqa_kernel(
     out_dtype=jnp.float32,
     interpret: bool = False,
 ) -> jax.Array:
-    b, n_kv, g, hd = q.shape
-    s = k_cache.shape[1]
+    """Flash decode over a contiguous cache: sequence ``b``'s blocks of
+    ``block_s`` positions are pages ``b*nb ... b*nb + nb - 1``."""
+    b, s, n_kv, hd = k_cache.shape
     assert s % block_s == 0, (s, block_s)
-    grid = (b, s // block_s)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,   # lengths
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n_kv, g, hd), lambda i, j, L: (i, 0, 0, 0)),
-            pl.BlockSpec((1, block_s, n_kv, hd), lambda i, j, L: (i, j, 0, 0)),
-            pl.BlockSpec((1, block_s, n_kv, hd), lambda i, j, L: (i, j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_kv, g, hd), lambda i, j, L: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_kv, g), jnp.float32),        # running max
-            pltpu.VMEM((n_kv, g), jnp.float32),        # running denom
-            pltpu.VMEM((n_kv, g, hd), jnp.float32),    # accumulator
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, block_s=block_s, num_kv=n_kv,
-                          groups=g, out_dtype=out_dtype),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), out_dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k_cache, v_cache)
+    nb = s // block_s
+    tables = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
+    return decode_gqa_paged_kernel(
+        q, k_cache.reshape(b * nb, block_s, n_kv, hd),
+        v_cache.reshape(b * nb, block_s, n_kv, hd), tables, lengths,
+        out_dtype=out_dtype, interpret=interpret)

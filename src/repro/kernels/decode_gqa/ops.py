@@ -1,4 +1,4 @@
-"""Public wrapper: dtype/shape handling + interpret fallback.
+"""Public wrapper: dtype/shape handling; interpret mode on the CPU.
 
 Any cache length works: the cache view is zero-padded up to a multiple
 of the kernel block internally and the padded tail is masked out via
@@ -86,17 +86,17 @@ def decode_gqa_paged(q, k_pages, v_pages, block_tables, lengths, *,
                                    interpret=bool(interpret))
 
 
-def decode_gqa_paged_codes(q_codes, k_pages, v_pages, q_lut, k_lut, v_lut,
-                           out_qmeta, block_tables, lengths, *,
+def decode_gqa_paged_codes(q_codes, k_pages, v_pages, q_qmeta, k_qmeta,
+                           v_qmeta, out_qmeta, block_tables, lengths, *,
                            interpret: bool | None = None):
     """Codes-mode flash decode over a paged KV cache: uint8 in, uint8
-    out.  ``q_codes`` [B, n_kv, g, hd] uint8 (attn_q site codes); pages
-    uint8 DNA-TEQ codes decoded in-kernel through per-head 256-entry
-    LUTs (``k_lut``/``v_lut`` [n_kv, 256]); the context is re-encoded
-    under ``out_qmeta`` (the attn_out site) before it leaves the
-    kernel.  Same paging/masking contract as :func:`decode_gqa_paged`;
-    off-TPU the default execution is the page-scan codes oracle (the
-    identical recurrence, so the two are bit-comparable).  Returns
+    out.  ``q_codes`` [B, n_kv, g, hd] uint8 (attn_q site codes, decoded
+    under ``q_qmeta`` [4]); pages uint8 DNA-TEQ codes decoded in-kernel
+    with per-head parameters (``k_qmeta``/``v_qmeta`` [n_kv, 4]); the
+    context is re-encoded under ``out_qmeta`` (the attn_out site) before
+    it leaves the kernel.  Same paging/masking contract as
+    :func:`decode_gqa_paged`; off-TPU the default execution is the
+    page-scan codes oracle (the same recurrence).  Returns
     [B, n_kv, g, hd] uint8.
     """
     b = q_codes.shape[0]
@@ -105,10 +105,10 @@ def decode_gqa_paged_codes(q_codes, k_pages, v_pages, q_lut, k_lut, v_lut,
     lengths = jnp.clip(lengths, 0, max_tokens)
     if interpret is None and jax.default_backend() == "cpu":
         return decode_gqa_paged_codes_ref(
-            q_codes, k_pages, v_pages, q_lut, k_lut, v_lut, out_qmeta,
+            q_codes, k_pages, v_pages, q_qmeta, k_qmeta, v_qmeta, out_qmeta,
             block_tables, lengths)
     return decode_gqa_paged_codes_kernel(
-        q_codes, k_pages, v_pages, q_lut, k_lut, v_lut, out_qmeta,
+        q_codes, k_pages, v_pages, q_qmeta, k_qmeta, v_qmeta, out_qmeta,
         block_tables, lengths, interpret=bool(interpret))
 
 

@@ -39,30 +39,27 @@ def decode_gqa_paged_ref(q, k_pages, v_pages, block_tables, lengths,
     return decode_gqa_ref(q, k, v, lengths, out_dtype)
 
 
-def decode_gqa_paged_codes_ref(q_codes, k_pages, v_pages, q_lut, k_lut,
-                               v_lut, out_qmeta, block_tables, lengths):
+def decode_gqa_paged_codes_ref(q_codes, k_pages, v_pages, q_qmeta, k_qmeta,
+                               v_qmeta, out_qmeta, block_tables, lengths):
     """Codes-mode oracle: unlike :func:`decode_gqa_paged_ref` (which
     gathers into a dense view and softmaxes in one shot), this runs the
-    *same* page-scan online-softmax recurrence as the kernel, with q/K/V
-    decoded through the same LUT gathers
+    same page-scan online-softmax recurrence as the kernel, with q/K/V
+    decoded by the same closed form
     (:func:`repro.kernels._codes.decode_heads`) and the context
-    re-encoded under ``out_qmeta`` — bit-comparable to
-    ``decode_gqa_paged_codes_kernel`` end to end, epilogue included.
-    Returns [B, n_kv, g, hd] uint8."""
+    re-encoded under ``out_qmeta``.  Returns [B, n_kv, g, hd] uint8."""
     b, n_kv, g, hd = q_codes.shape
     bs = k_pages.shape[1]
     max_blk = block_tables.shape[1]
-    qf = jnp.take(q_lut.astype(jnp.float32).reshape(256),
-                  q_codes.astype(jnp.int32), axis=0)
-    k_lut = k_lut.astype(jnp.float32)
-    v_lut = v_lut.astype(jnp.float32)
+    qf = decode_heads(q_qmeta.astype(jnp.float32).reshape(1, 4), q_codes)
+    k_qmeta = k_qmeta.astype(jnp.float32)
+    v_qmeta = v_qmeta.astype(jnp.float32)
     scale = 1.0 / math.sqrt(hd)
 
     def page_step(carry, j_tbl):
         m, l, acc = carry
         j, tbl_j = j_tbl                                    # tbl_j [B]
-        k = decode_heads(k_lut, k_pages[tbl_j])             # [B, bs, n, h]
-        v = decode_heads(v_lut, v_pages[tbl_j])
+        k = decode_heads(k_qmeta, k_pages[tbl_j])           # [B, bs, n, h]
+        v = decode_heads(v_qmeta, v_pages[tbl_j])
         logit = jnp.einsum("bngh,bsnh->bngs", qf, k,
                            preferred_element_type=jnp.float32) * scale
         pos = j * bs + jnp.arange(bs)                       # [bs]

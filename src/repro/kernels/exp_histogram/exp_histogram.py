@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(vals_ref, signs_ref, o_ref, acc_ref, *, num_bins: int):
     @pl.when(pl.program_id(1) == 0)
@@ -63,7 +61,7 @@ def exp_histogram_kernel(
         out_specs=pl.BlockSpec((bg, num_bins), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g, num_bins), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bg, num_bins), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,10 +1,12 @@
 """Pure-jnp oracle for chunked flash prefill over a paged KV cache.
 
-Deliberately the same recurrence as the kernel — a ``lax.scan`` over
-block-table columns with online-softmax (m, l, acc) carries — so the
-two accumulate in the same page order (bit-comparable in f32) and
-neither ever materializes an ``[S, T]`` score matrix: the largest score
-block is ``[S, block_size]``, one page's worth.
+The same recurrence as the kernel — a ``lax.scan`` over block-table
+columns with online-softmax (m, l, acc) carries — so the two accumulate
+in the same page order, and neither ever materializes an ``[S, T]``
+score matrix: the largest score block is ``[S, block_size]``, one
+page's worth.  The oracle contracts per head with einsums where the
+kernel runs one folded 2-D dot per page, so the two agree to f32
+rounding, not bit for bit.
 """
 
 from __future__ import annotations
@@ -61,30 +63,28 @@ def flash_prefill_paged_ref(q, k_pages, v_pages, block_tables, q_start,
     return jnp.transpose(out, (0, 3, 1, 2, 4)).astype(out_dtype)
 
 
-def flash_prefill_paged_codes_ref(q_codes, k_pages, v_pages, q_lut, k_lut,
-                                  v_lut, out_qmeta, block_tables, q_start,
-                                  kv_lens):
+def flash_prefill_paged_codes_ref(q_codes, k_pages, v_pages, q_qmeta,
+                                  k_qmeta, v_qmeta, out_qmeta, block_tables,
+                                  q_start, kv_lens):
     """Codes-mode oracle: identical page recurrence, but q/K/V are uint8
-    DNA-TEQ codes decoded through the same LUT gathers as the kernel
+    DNA-TEQ codes decoded by the same closed form as the kernel
     (:func:`repro.kernels._codes.decode_heads`), and the output is the
-    uint8 re-encode of the context under ``out_qmeta`` — bit-comparable
-    to ``flash_prefill_paged_codes_kernel`` end to end, epilogue
-    included.  Returns [B, S, n_kv, g, hd] uint8."""
+    uint8 re-encode of the context under ``out_qmeta``.
+    Returns [B, S, n_kv, g, hd] uint8."""
     b, s, n_kv, g, hd = q_codes.shape
     bs = k_pages.shape[1]
     max_blk = block_tables.shape[1]
-    qf = jnp.take(q_lut.astype(jnp.float32).reshape(256),
-                  q_codes.astype(jnp.int32), axis=0)
-    k_lut = k_lut.astype(jnp.float32)
-    v_lut = v_lut.astype(jnp.float32)
+    qf = decode_heads(q_qmeta.astype(jnp.float32).reshape(1, 4), q_codes)
+    k_qmeta = k_qmeta.astype(jnp.float32)
+    v_qmeta = v_qmeta.astype(jnp.float32)
     scale = 1.0 / math.sqrt(hd)
     qpos = (q_start[:, None] + jnp.arange(s)[None, :])      # [B, S]
 
     def page_step(carry, j_tbl):
         m, l, acc = carry
         j, tbl_j = j_tbl                                    # tbl_j [B]
-        k = decode_heads(k_lut, k_pages[tbl_j])             # [B, bs, n, h]
-        v = decode_heads(v_lut, v_pages[tbl_j])
+        k = decode_heads(k_qmeta, k_pages[tbl_j])           # [B, bs, n, h]
+        v = decode_heads(v_qmeta, v_pages[tbl_j])
         logit = jnp.einsum("bsngh,btnh->bngst", qf, k,
                            preferred_element_type=jnp.float32) * scale
         kvpos = j * bs + jnp.arange(bs)                     # [bs]

@@ -1,18 +1,18 @@
-"""Fused LUT-dequantize + matmul Pallas TPU kernel (the Lama perf path).
+"""Fused exponent-decode + matmul Pallas TPU kernel (the Lama perf path).
 
 ``y[M, N] = x[M, K] @ decode(codes[K, N])`` where ``decode`` maps uint8
-DNA-TEQ codes through a 256-entry table.  The decode table lives in VMEM
-for the whole kernel — the TPU analog of Lama's "open row": one
-activation (table load) serves every tile of the operand-coalesced batch
-(DESIGN.md §2).  Weights cross HBM as 1 byte/param; the bf16 tensor
-never exists in HBM.
+DNA-TEQ codes to ``sign * (alpha * base**e + beta)``.  The four packed
+parameters (``qmeta``) live in VMEM for the whole kernel — the TPU
+analog of Lama's "open row": one activation serves every tile of the
+operand-coalesced batch (DESIGN.md §2).  Weights cross HBM as
+1 byte/param; the f32 tensor never exists in HBM.
 
-Two decode modes:
-* ``gather`` — faithful LUT semantics: ``table[code]`` VMEM gather.
-* ``alu``    — exploits DNA-TEQ's closed form
-  ``sign * (alpha * base**e + beta)``: on TPU's vector unit an exp is
-  cheaper than a serialized 8-bit gather, so the "LUT" collapses into
-  arithmetic.  Bit-identical up to float rounding (tested).
+The decode is DNA-TEQ's closed form (:func:`decode_meta`), the one
+formula every table in the repository is built from: the 256-entry
+LUT of a weight or activation site is ``decode_meta(arange(256))``, so
+a kernel tile decodes to exactly the values a host-side table lookup
+gives.  On the TPU's vector unit an exp is cheaper than a serialized
+8-bit gather, and Mosaic lowers no 256-entry gather in any case.
 
 Fused epilogues (DESIGN.md §Fused-path): the accumulator flush can apply
 ``+bias`` and/or an activation (``gelu``/``silu``/``relu``) so chains
@@ -24,7 +24,7 @@ front half collapses into one kernel.
 
 Dual-operand variants (the LamaAccel Eq.1 execution path): *both*
 operands arrive as uint8 DNA-TEQ codes and each decodes through its own
-256-entry table inside the kernel — activations cross HBM as 1 B/elem
+parameters inside the kernel — activations cross HBM as 1 B/elem
 exactly like weights, and the f32 activation tensor never exists in
 HBM.  An optional **quantize epilogue** re-encodes the flushed output
 tile against a third (calibrated) parameter set and stores uint8 codes,
@@ -52,20 +52,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.exponential_quant import decode_meta, encode_meta
-from repro.kernels._compat import CompilerParams
 
 EPILOGUES = ("gelu", "silu", "relu")
-
-
-def _decode_gather(lut_row: jax.Array, codes: jax.Array) -> jax.Array:
-    return jnp.take(lut_row, codes.astype(jnp.int32), axis=0)
-
-
-def _decode_alu(qmeta: jax.Array, codes: jax.Array) -> jax.Array:
-    # one ALU decode formula repo-wide: the counting≡dual-LUT identity
-    # and the calibration cache's hit-is-bit-identical guarantee both
-    # rely on kernel and host decoding codes the same way
-    return decode_meta(codes, qmeta)
+_PARALLEL_K_LAST = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def apply_activation(x: jax.Array, kind: str | None) -> jax.Array:
@@ -82,18 +72,19 @@ def apply_activation(x: jax.Array, kind: str | None) -> jax.Array:
     raise ValueError(kind)
 
 
-def _kernel(x_ref, codes_ref, lut_ref, qmeta_ref, bias_ref, o_ref, acc_ref,
-            *, decode_mode: str, epilogue: str | None, has_bias: bool,
-            w_transposed: bool, out_dtype):
+def _decode(qmetas_ref, row: int, codes: jax.Array) -> jax.Array:
+    """Decode a code tile through parameter row ``row`` (f32)."""
+    return decode_meta(codes, qmetas_ref[row, :])
+
+
+def _kernel(x_ref, codes_ref, qmeta_ref, bias_ref, o_ref, acc_ref,
+            *, epilogue: str | None, has_bias: bool, w_transposed: bool,
+            out_dtype):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = codes_ref[...]                        # [bk, bn] (or [bn, bk])
-    if decode_mode == "gather":
-        w = _decode_gather(lut_ref[0, :], codes)  # f32
-    else:
-        w = _decode_alu(qmeta_ref[0, :], codes)
+    w = _decode(qmeta_ref, 0, codes_ref[...])    # [bk, bn] (or [bn, bk])
     x = x_ref[...].astype(jnp.float32)            # [bm, bk]
     if w_transposed:
         # codes stored [N, K] (e.g. a tied embedding table): decode the
@@ -109,26 +100,24 @@ def _kernel(x_ref, codes_ref, lut_ref, qmeta_ref, bias_ref, o_ref, acc_ref,
     def _flush():
         acc = acc_ref[...]
         if has_bias:
-            acc = acc + bias_ref[0, :][None, :]
+            acc = acc + bias_ref[...]
         o_ref[...] = apply_activation(acc, epilogue).astype(out_dtype)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "bn", "decode_mode", "epilogue",
-                     "has_bias", "w_transposed", "out_dtype", "interpret"),
+    static_argnames=("bm", "bk", "bn", "epilogue", "has_bias",
+                     "w_transposed", "out_dtype", "interpret"),
 )
 def lut_dequant_matmul_kernel(
     x: jax.Array,        # [M, K] float
     codes: jax.Array,    # [K, N] uint8 ([N, K] when w_transposed)
-    lut: jax.Array,      # [256] float32 decode table
     qmeta: jax.Array,    # [4] float32 (alpha, beta, base, bits)
     bias: jax.Array,     # [N] float32 (ignored unless has_bias)
     *,
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
-    decode_mode: str = "gather",
     epilogue: str | None = None,
     has_bias: bool = False,
     w_transposed: bool = False,
@@ -148,26 +137,21 @@ def lut_dequant_matmul_kernel(
                   if w_transposed else
                   pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)))
     return pl.pallas_call(
-        functools.partial(_kernel, decode_mode=decode_mode,
-                          epilogue=epilogue, has_bias=has_bias,
+        functools.partial(_kernel, epilogue=epilogue, has_bias=has_bias,
                           w_transposed=w_transposed, out_dtype=out_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             codes_spec,
-            pl.BlockSpec((1, 256), lambda i, j, kk: (0, 0)),   # resident LUT
-            pl.BlockSpec((1, 4), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((1, 4), lambda i, j, kk: (0, 0)),   # resident params
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_PARALLEL_K_LAST,
         interpret=interpret,
-    )(x, codes.astype(jnp.uint8), lut.reshape(1, 256).astype(jnp.float32),
-      qmeta.reshape(1, 4).astype(jnp.float32),
+    )(x, codes.astype(jnp.uint8), qmeta.reshape(1, 4).astype(jnp.float32),
       bias.reshape(1, n).astype(jnp.float32))
 
 
@@ -175,20 +159,15 @@ def lut_dequant_matmul_kernel(
 # Gated dual-matmul variant: act(x @ decode(cg)) * (x @ decode(cu))
 # ---------------------------------------------------------------------
 
-def _gated_kernel(x_ref, cg_ref, cu_ref, luts_ref, qmetas_ref, o_ref,
-                  accg_ref, accu_ref, *, decode_mode: str, activation: str,
-                  out_dtype):
+def _gated_kernel(x_ref, cg_ref, cu_ref, qmetas_ref, o_ref,
+                  accg_ref, accu_ref, *, activation: str, out_dtype):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         accg_ref[...] = jnp.zeros_like(accg_ref)
         accu_ref[...] = jnp.zeros_like(accu_ref)
 
-    if decode_mode == "gather":
-        wg = _decode_gather(luts_ref[0, :], cg_ref[...])
-        wu = _decode_gather(luts_ref[1, :], cu_ref[...])
-    else:
-        wg = _decode_alu(qmetas_ref[0, :], cg_ref[...])
-        wu = _decode_alu(qmetas_ref[1, :], cu_ref[...])
+    wg = _decode(qmetas_ref, 0, cg_ref[...])
+    wu = _decode(qmetas_ref, 1, cu_ref[...])
     x = x_ref[...].astype(jnp.float32)
     accg_ref[...] += jnp.dot(x, wg, preferred_element_type=jnp.float32)
     accu_ref[...] += jnp.dot(x, wu, preferred_element_type=jnp.float32)
@@ -201,20 +180,18 @@ def _gated_kernel(x_ref, cg_ref, cu_ref, luts_ref, qmetas_ref, o_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "bn", "decode_mode", "activation",
-                     "out_dtype", "interpret"),
+    static_argnames=("bm", "bk", "bn", "activation", "out_dtype",
+                     "interpret"),
 )
 def lut_dequant_matmul_gated_kernel(
     x: jax.Array,         # [M, K] float
     codes_g: jax.Array,   # [K, N] uint8 (gate projection)
     codes_u: jax.Array,   # [K, N] uint8 (up projection)
-    luts: jax.Array,      # [2, 256] float32 (gate table, up table)
-    qmetas: jax.Array,    # [2, 4] float32
+    qmetas: jax.Array,    # [2, 4] float32 (gate params, up params)
     *,
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
-    decode_mode: str = "gather",
     activation: str = "silu",
     out_dtype=jnp.float32,
     interpret: bool = False,
@@ -227,26 +204,22 @@ def lut_dequant_matmul_gated_kernel(
     grid = (m // bm, n // bn, k // bk)
 
     return pl.pallas_call(
-        functools.partial(_gated_kernel, decode_mode=decode_mode,
-                          activation=activation, out_dtype=out_dtype),
+        functools.partial(_gated_kernel, activation=activation,
+                          out_dtype=out_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((2, 256), lambda i, j, kk: (0, 0)),   # resident LUTs
-            pl.BlockSpec((2, 4), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((2, 4), lambda i, j, kk: (0, 0)),   # resident params
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_PARALLEL_K_LAST,
         interpret=interpret,
     )(x, codes_g.astype(jnp.uint8), codes_u.astype(jnp.uint8),
-      luts.reshape(2, 256).astype(jnp.float32),
       qmetas.reshape(2, 4).astype(jnp.float32))
 
 
@@ -254,15 +227,11 @@ def lut_dequant_matmul_gated_kernel(
 # Dual-operand variants: activation codes decoded in-kernel too
 # ---------------------------------------------------------------------
 
-def _decode_act_tile(luts_ref, qmetas_ref, codes, row: int,
-                     decode_mode: str, k_valid: int | None, bk: int):
-    """Decode one activation code tile through table ``row`` and zero
+def _decode_act_tile(qmetas_ref, codes, k_valid: int | None, bk: int):
+    """Decode one activation code tile through parameter row 0 and zero
     the K positions past the true contraction length (pad byte 0 is a
     *live* code, unlike a zero float)."""
-    if decode_mode == "gather":
-        a = _decode_gather(luts_ref[row, :], codes)
-    else:
-        a = _decode_alu(qmetas_ref[row, :], codes)
+    a = _decode(qmetas_ref, 0, codes)
     if k_valid is not None:
         kpos = (pl.program_id(2) * bk
                 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
@@ -270,27 +239,22 @@ def _decode_act_tile(luts_ref, qmetas_ref, codes, row: int,
     return a
 
 
-def _dual_kernel(xc_ref, wc_ref, luts_ref, qmetas_ref, bias_ref, o_ref,
-                 acc_ref, *, decode_mode: str, epilogue: str | None,
-                 has_bias: bool, out_quant: bool, k_valid: int | None,
-                 bk: int, out_dtype):
+def _dual_kernel(xc_ref, wc_ref, qmetas_ref, bias_ref, o_ref, acc_ref, *,
+                 epilogue: str | None, has_bias: bool, out_quant: bool,
+                 k_valid: int | None, bk: int, out_dtype):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = _decode_act_tile(luts_ref, qmetas_ref, xc_ref[...], 0,
-                         decode_mode, k_valid, bk)       # [bm, bk]
-    if decode_mode == "gather":
-        w = _decode_gather(luts_ref[1, :], wc_ref[...])  # [bk, bn]
-    else:
-        w = _decode_alu(qmetas_ref[1, :], wc_ref[...])
+    a = _decode_act_tile(qmetas_ref, xc_ref[...], k_valid, bk)  # [bm, bk]
+    w = _decode(qmetas_ref, 1, wc_ref[...])                     # [bk, bn]
     acc_ref[...] += jnp.dot(a, w, preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _flush():
         acc = acc_ref[...]
         if has_bias:
-            acc = acc + bias_ref[0, :][None, :]
+            acc = acc + bias_ref[...]
         acc = apply_activation(acc, epilogue)
         if out_quant:
             # quantize epilogue: re-encode against the *output* params
@@ -302,21 +266,18 @@ def _dual_kernel(xc_ref, wc_ref, luts_ref, qmetas_ref, bias_ref, o_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "bn", "decode_mode", "epilogue",
-                     "has_bias", "out_quant", "k_valid", "out_dtype",
-                     "interpret"),
+    static_argnames=("bm", "bk", "bn", "epilogue", "has_bias", "out_quant",
+                     "k_valid", "out_dtype", "interpret"),
 )
 def lut_dequant_matmul_dual_kernel(
     x_codes: jax.Array,  # [M, K] uint8 activation codes
     codes: jax.Array,    # [K, N] uint8 weight codes
-    luts: jax.Array,     # [3, 256] f32 (act table, weight table, out table)
     qmetas: jax.Array,   # [3, 4] f32 (act, weight, out params)
     bias: jax.Array,     # [N] f32 (ignored unless has_bias)
     *,
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
-    decode_mode: str = "gather",
     epilogue: str | None = None,
     has_bias: bool = False,
     out_quant: bool = False,
@@ -335,48 +296,38 @@ def lut_dequant_matmul_dual_kernel(
     out_dt = jnp.uint8 if out_quant else out_dtype
 
     return pl.pallas_call(
-        functools.partial(_dual_kernel, decode_mode=decode_mode,
-                          epilogue=epilogue, has_bias=has_bias,
-                          out_quant=out_quant, k_valid=k_valid, bk=bk,
-                          out_dtype=out_dtype),
+        functools.partial(_dual_kernel, epilogue=epilogue,
+                          has_bias=has_bias, out_quant=out_quant,
+                          k_valid=k_valid, bk=bk, out_dtype=out_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((3, 256), lambda i, j, kk: (0, 0)),   # resident LUTs
-            pl.BlockSpec((3, 4), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((3, 4), lambda i, j, kk: (0, 0)),   # resident params
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dt),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_PARALLEL_K_LAST,
         interpret=interpret,
     )(x_codes.astype(jnp.uint8), codes.astype(jnp.uint8),
-      luts.reshape(3, 256).astype(jnp.float32),
       qmetas.reshape(3, 4).astype(jnp.float32),
       bias.reshape(1, n).astype(jnp.float32))
 
 
-def _dual_gated_kernel(xc_ref, cg_ref, cu_ref, luts_ref, qmetas_ref, o_ref,
-                       accg_ref, accu_ref, *, decode_mode: str,
-                       activation: str, out_quant: bool,
-                       k_valid: int | None, bk: int, out_dtype):
+def _dual_gated_kernel(xc_ref, cg_ref, cu_ref, qmetas_ref, o_ref,
+                       accg_ref, accu_ref, *, activation: str,
+                       out_quant: bool, k_valid: int | None, bk: int,
+                       out_dtype):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         accg_ref[...] = jnp.zeros_like(accg_ref)
         accu_ref[...] = jnp.zeros_like(accu_ref)
 
-    a = _decode_act_tile(luts_ref, qmetas_ref, xc_ref[...], 0,
-                         decode_mode, k_valid, bk)
-    if decode_mode == "gather":
-        wg = _decode_gather(luts_ref[1, :], cg_ref[...])
-        wu = _decode_gather(luts_ref[2, :], cu_ref[...])
-    else:
-        wg = _decode_alu(qmetas_ref[1, :], cg_ref[...])
-        wu = _decode_alu(qmetas_ref[2, :], cu_ref[...])
+    a = _decode_act_tile(qmetas_ref, xc_ref[...], k_valid, bk)
+    wg = _decode(qmetas_ref, 1, cg_ref[...])
+    wu = _decode(qmetas_ref, 2, cu_ref[...])
     accg_ref[...] += jnp.dot(a, wg, preferred_element_type=jnp.float32)
     accu_ref[...] += jnp.dot(a, wu, preferred_element_type=jnp.float32)
 
@@ -391,20 +342,18 @@ def _dual_gated_kernel(xc_ref, cg_ref, cu_ref, luts_ref, qmetas_ref, o_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bk", "bn", "decode_mode", "activation",
-                     "out_quant", "k_valid", "out_dtype", "interpret"),
+    static_argnames=("bm", "bk", "bn", "activation", "out_quant", "k_valid",
+                     "out_dtype", "interpret"),
 )
 def lut_dequant_matmul_dual_gated_kernel(
     x_codes: jax.Array,   # [M, K] uint8 activation codes
     codes_g: jax.Array,   # [K, N] uint8 (gate projection)
     codes_u: jax.Array,   # [K, N] uint8 (up projection)
-    luts: jax.Array,      # [4, 256] (act, gate, up, out tables)
-    qmetas: jax.Array,    # [4, 4]
+    qmetas: jax.Array,    # [4, 4] (act, gate, up, out params)
     *,
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
-    decode_mode: str = "gather",
     activation: str = "silu",
     out_quant: bool = False,
     k_valid: int | None = None,
@@ -424,26 +373,21 @@ def lut_dequant_matmul_dual_gated_kernel(
     out_dt = jnp.uint8 if out_quant else out_dtype
 
     return pl.pallas_call(
-        functools.partial(_dual_gated_kernel, decode_mode=decode_mode,
-                          activation=activation, out_quant=out_quant,
-                          k_valid=k_valid, bk=bk, out_dtype=out_dtype),
+        functools.partial(_dual_gated_kernel, activation=activation,
+                          out_quant=out_quant, k_valid=k_valid, bk=bk,
+                          out_dtype=out_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((4, 256), lambda i, j, kk: (0, 0)),   # resident LUTs
-            pl.BlockSpec((4, 4), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((4, 4), lambda i, j, kk: (0, 0)),   # resident params
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dt),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_PARALLEL_K_LAST,
         interpret=interpret,
     )(x_codes.astype(jnp.uint8), codes_g.astype(jnp.uint8),
-      codes_u.astype(jnp.uint8),
-      luts.reshape(4, 256).astype(jnp.float32),
-      qmetas.reshape(4, 4).astype(jnp.float32))
+      codes_u.astype(jnp.uint8), qmetas.reshape(4, 4).astype(jnp.float32))

@@ -1,5 +1,4 @@
-"""Public wrapper: M-bucketing, autotuned tiling, padding, interpret
-fallback.
+"""Public wrapper: M-bucketing, autotuned tiling, padding.
 
 Two serving-critical behaviours live here (DESIGN.md §Fused-path):
 
@@ -9,9 +8,11 @@ Two serving-critical behaviours live here (DESIGN.md §Fused-path):
   serving sees a handful of compiled kernels regardless of batch mix.
 * **Autotuning** — ``(bm, bk, bn)`` per padded shape is picked by timing
   candidate tilings on the real device and cached persistently (JSON, see
-  DESIGN.md for the format).  Tuning only triggers on a real TPU backend
-  (or with ``REPRO_AUTOTUNE=1``); CPU/interpret runs use the default
-  tiling so tests never pay tuning time.
+  DESIGN.md for the format), keyed by the chip's ``device_kind``.
+  Tuning only triggers on a real TPU backend (or with
+  ``REPRO_AUTOTUNE=1``); CPU/interpret runs use the default tiling so
+  tests never pay tuning time.  A candidate that fails to compile or run
+  is counted (:func:`tune_stats`), never silently dropped.
 """
 
 from __future__ import annotations
@@ -36,16 +37,16 @@ from repro.kernels.lut_dequant_matmul.ref import (
     lut_dequant_matmul_gated_ref,
     lut_dequant_matmul_ref,
 )
+from repro.caches import repo_cache_path
 
 # Fixed ladder keeps the set of compiled M shapes small; beyond the
 # ladder, multiples of 512 (decode batches and prefill token counts both
 # land there).
 M_LADDER = (8, 16, 32, 64, 128, 256, 512)
 _VMEM_BUDGET = 8 * 1024 * 1024
-# v2: keys gained the activation-operand representation component
-# (f32/bf16 activations vs uint8 act codes), so dual-LUT tiles can
-# never collide with fp-act tiles in a persisted cache.
-_TUNE_VERSION = 2
+# v3: keys lead with the chip's device_kind (not the backend name) and
+# lost the decode-mode component (one decode remains).
+_TUNE_VERSION = 3
 
 # Activation-representation tag for uint8 DNA-TEQ act codes (fp
 # operands tag with their dtype name).
@@ -119,24 +120,32 @@ class Autotuner:
 
     Disk format (JSON)::
 
-        {"version": 2,
+        {"version": 3,
          "entries":
-            {"<backend>|<kind>|<m>|<k>|<n>|<decode_mode>|<xrep>|<extra>":
+            {"<device_kind>|<kind>|<m>|<k>|<n>|<xrep>|<extra>":
              {"tile": [bm, bk, bn], "us": 123.4}}}
 
+    ``device_kind`` is the chip as JAX names it (``TPU v5 lite``), so
+    a cache tuned on one TPU generation is never read on another.
     ``xrep`` is the activation operand's representation (``float32`` /
-    ``bfloat16`` / ``u8code``): a dual-LUT call (codes activation) and a
-    fp-act call of the same geometry have different decode work per
-    tile, so their tiles must never share a cache entry.
+    ``bfloat16`` / ``u8code``): a dual-operand call (codes activation)
+    and a fp-act call of the same geometry have different decode work
+    per tile, so their tiles must never share a cache entry.
+
+    ``tried``/``failed`` count candidate tilings timed and candidate
+    tilings that raised (compile or run); ``failures`` keeps the
+    (key, tile, error) of each failed candidate for the caller to print.
     """
 
     def __init__(self, path: str | None = None):
         self.path = path or os.environ.get(
             "REPRO_AUTOTUNE_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                         "lut_dequant_matmul_tune.json"))
+            repo_cache_path("lut_dequant_matmul_tune.json"))
         self._mem: dict[str, tuple[int, int, int]] = {}
         self._disk_loaded = False
+        self.tried = 0
+        self.failed = 0
+        self.failures: list[tuple[str, tuple[int, int, int], str]] = []
 
     # -- persistence ---------------------------------------------------
     def _load_disk(self):
@@ -174,8 +183,7 @@ class Autotuner:
     # -- selection -----------------------------------------------------
     def peek(self, key: str) -> tuple[int, int, int] | None:
         """Cached tiling only (memory -> disk); never times, never
-        writes.  Used when the call is being traced under jit — timing
-        tracers measures nothing."""
+        writes."""
         if key not in self._mem:
             self._load_disk()
         return self._mem.get(key)
@@ -183,20 +191,26 @@ class Autotuner:
     def get(self, key: str, candidates, bench) -> tuple[int, int, int]:
         """Best tiling for ``key``: memory cache -> disk cache -> tune.
 
-        ``bench(tile) -> seconds`` is injectable for tests."""
+        ``bench(tile) -> seconds`` is injectable for tests.  A candidate
+        that raises is recorded in ``failed``/``failures``; when every
+        candidate fails the default (``candidates[0]``) is returned
+        without poisoning the cache, and the failures stay on record."""
         cached = self.peek(key)
         if cached is not None:
             return cached
         best, best_t = None, float("inf")
         for tile in candidates:
+            self.tried += 1
             try:
                 t = bench(tile)
-            except Exception:
+            except Exception as e:  # noqa: BLE001 — recorded, reported
+                self.failed += 1
+                self.failures.append(
+                    (key, tuple(tile), f"{type(e).__name__}: {e}"[:300]))
                 continue
             if t < best_t:
                 best, best_t = tile, t
         if best is None:
-            # nothing validated: fall back without poisoning the cache
             return candidates[0]
         self._mem[key] = best
         self._save_disk(key, best, best_t * 1e6)
@@ -204,6 +218,13 @@ class Autotuner:
 
 
 _TUNER = Autotuner()
+
+
+def tune_stats() -> dict:
+    """Candidate tilings the process-wide autotuner has timed and seen
+    fail, with the failures themselves (key, tile, error)."""
+    return {"tried": _TUNER.tried, "failed": _TUNER.failed,
+            "failures": list(_TUNER.failures)}
 
 
 def _autotune_enabled(autotune: bool | None, interpret: bool) -> bool:
@@ -241,39 +262,40 @@ def _synth_operands(m_pad: int, k_pad: int, n_pad: int,
         x = jnp.asarray(r.normal(size=(m_pad, k_pad)), jnp.float32)
     cshape = (n_pad, k_pad) if transpose_codes else (k_pad, n_pad)
     codes = jnp.asarray(r.integers(0, 256, cshape), jnp.uint8)
-    lut = jnp.asarray(r.normal(size=(256,)) * 0.05, jnp.float32)
     qmeta = jnp.asarray([0.05, 0.0, 1.5, 7.0], jnp.float32)
     bias = jnp.zeros((n_pad,), jnp.float32)
     if gated:
         codes2 = jnp.asarray(r.integers(0, 256, cshape), jnp.uint8)
-        return x, codes, codes2, lut, qmeta, bias
-    return x, codes, lut, qmeta, bias
+        return x, codes, codes2, qmeta, bias
+    return x, codes, qmeta, bias
 
 
-def _tune_key(kind: str, m_pad: int, k_pad: int, n_pad: int,
-              decode_mode: str, xrep: str, extra: str) -> str:
-    return "|".join([jax.default_backend(), kind, str(m_pad), str(k_pad),
-                     str(n_pad), decode_mode, xrep, extra])
+def _tune_key(kind: str, m_pad: int, k_pad: int, n_pad: int, xrep: str,
+              extra: str) -> str:
+    return "|".join([jax.devices()[0].device_kind, kind, str(m_pad),
+                     str(k_pad), str(n_pad), xrep, extra])
 
 
-def _tiling_for(kind: str, m_pad: int, k_pad: int, n_pad: int,
-                decode_mode: str, xrep: str, extra: str, interpret: bool,
-                autotune: bool | None, bench_factory=None):
+def _tiling_for(kind: str, m_pad: int, k_pad: int, n_pad: int, xrep: str,
+                extra: str, interpret: bool, autotune: bool | None,
+                bench_factory=None):
     if not _autotune_enabled(autotune, interpret):
         return _default_tiling(m_pad, k_pad, n_pad)
-    key = _tune_key(kind, m_pad, k_pad, n_pad, decode_mode, xrep, extra)
+    key = _tune_key(kind, m_pad, k_pad, n_pad, xrep, extra)
     cands = _candidate_tilings(
         m_pad, k_pad, n_pad, dual=kind in ("gated", "dual_gated"))
     return _TUNER.get(key, cands, bench_factory(cands))
 
 
+def _meta(qmeta) -> jax.Array:
+    return jnp.asarray(qmeta, jnp.float32).reshape(4)
+
+
 def lut_dequant_matmul(
     x: jax.Array,          # [M, K]
     codes: jax.Array,      # [K, N] uint8 ([N, K] when transpose_codes)
-    lut: jax.Array,        # [256]
-    qmeta: jax.Array | None = None,
+    qmeta: jax.Array,      # [4] (alpha, beta, base, bits)
     *,
-    decode_mode: str = "gather",
     epilogue: str | None = None,
     bias: jax.Array | None = None,
     transpose_codes: bool = False,
@@ -281,7 +303,7 @@ def lut_dequant_matmul(
     interpret: bool | None = None,
     autotune: bool | None = None,
 ) -> jax.Array:
-    """Fused dequant+matmul with optional bias/activation epilogue.
+    """Fused decode+matmul with optional bias/activation epilogue.
 
     M is bucketed (see :func:`bucket_m`) so ragged serving batches reuse
     a small fixed set of compiled kernels; K/N pad to 128 lanes.
@@ -300,34 +322,30 @@ def lut_dequant_matmul(
         n_pad, k_pad = ck.shape
     else:
         k_pad, n_pad = ck.shape
-    if qmeta is None:
-        qmeta = jnp.zeros((4,), jnp.float32)
     has_bias = bias is not None
     bias_arr = (_pad_axis_to(bias.astype(jnp.float32), n_pad, 0)
                 if has_bias else jnp.zeros((n_pad,), jnp.float32))
 
     def bench_factory(_cands):
-        sx, sc, slut, sqm, sb = _synth_operands(
+        sx, sc, sqm, sb = _synth_operands(
             m_pad, k_pad, n_pad, transpose_codes=transpose_codes)
 
         def bench(tile):
             bm, bk, bn = tile
             return _bench_kernel(lambda: lut_dequant_matmul_kernel(
-                sx, sc, slut, sqm, sb, bm=bm, bk=bk, bn=bn,
-                decode_mode=decode_mode, epilogue=epilogue,
+                sx, sc, sqm, sb, bm=bm, bk=bk, bn=bn, epilogue=epilogue,
                 has_bias=has_bias, w_transposed=transpose_codes,
                 out_dtype=jnp.float32, interpret=interpret))
         return bench
 
     bm, bk, bn = _tiling_for(
-        "mm", m_pad, k_pad, n_pad, decode_mode, _xrep(x),
+        "mm", m_pad, k_pad, n_pad, _xrep(x),
         f"{epilogue}|{int(has_bias)}|{int(transpose_codes)}",
         interpret, autotune, bench_factory)
     out = lut_dequant_matmul_kernel(
-        xk, ck, lut, qmeta, bias_arr, bm=bm, bk=bk, bn=bn,
-        decode_mode=decode_mode, epilogue=epilogue, has_bias=has_bias,
-        w_transposed=transpose_codes, out_dtype=jnp.float32,
-        interpret=interpret)
+        xk, ck, _meta(qmeta), bias_arr, bm=bm, bk=bk, bn=bn,
+        epilogue=epilogue, has_bias=has_bias, w_transposed=transpose_codes,
+        out_dtype=jnp.float32, interpret=interpret)
     return out[:m, :n].astype(out_dtype)
 
 
@@ -335,13 +353,10 @@ def lut_dequant_matmul_gated(
     x: jax.Array,          # [M, K]
     codes_g: jax.Array,    # [K, N] uint8 (gate)
     codes_u: jax.Array,    # [K, N] uint8 (up)
-    lut_g: jax.Array,      # [256]
-    lut_u: jax.Array,      # [256]
-    qmeta_g: jax.Array | None = None,
-    qmeta_u: jax.Array | None = None,
+    qmeta_g: jax.Array,    # [4]
+    qmeta_u: jax.Array,    # [4]
     *,
     activation: str = "silu",
-    decode_mode: str = "gather",
     out_dtype=None,
     interpret: bool | None = None,
     autotune: bool | None = None,
@@ -359,66 +374,49 @@ def lut_dequant_matmul_gated(
     cg = _pad_to(_pad_to(codes_g, 128, 0), 128, 1)
     cu = _pad_to(_pad_to(codes_u, 128, 0), 128, 1)
     k_pad, n_pad = cg.shape
-    luts = jnp.stack([lut_g.astype(jnp.float32), lut_u.astype(jnp.float32)])
-    if qmeta_g is None:
-        qmeta_g = jnp.zeros((4,), jnp.float32)
-    if qmeta_u is None:
-        qmeta_u = jnp.zeros((4,), jnp.float32)
-    qmetas = jnp.stack([qmeta_g.astype(jnp.float32),
-                        qmeta_u.astype(jnp.float32)])
+    qmetas = jnp.stack([_meta(qmeta_g), _meta(qmeta_u)])
 
     def bench_factory(_cands):
-        sx, scg, scu, slut, sqm, _sb = _synth_operands(
+        sx, scg, scu, sqm, _sb = _synth_operands(
             m_pad, k_pad, n_pad, gated=True)
-        sluts = jnp.stack([slut, slut])
         sqms = jnp.stack([sqm, sqm])
 
         def bench(tile):
             bm, bk, bn = tile
             return _bench_kernel(lambda: lut_dequant_matmul_gated_kernel(
-                sx, scg, scu, sluts, sqms, bm=bm, bk=bk, bn=bn,
-                decode_mode=decode_mode, activation=activation,
-                out_dtype=jnp.float32, interpret=interpret))
+                sx, scg, scu, sqms, bm=bm, bk=bk, bn=bn,
+                activation=activation, out_dtype=jnp.float32,
+                interpret=interpret))
         return bench
 
     bm, bk, bn = _tiling_for(
-        "gated", m_pad, k_pad, n_pad, decode_mode, _xrep(x), activation,
+        "gated", m_pad, k_pad, n_pad, _xrep(x), activation,
         interpret, autotune, bench_factory)
     out = lut_dequant_matmul_gated_kernel(
-        xk, cg, cu, luts, qmetas, bm=bm, bk=bk, bn=bn,
-        decode_mode=decode_mode, activation=activation,
+        xk, cg, cu, qmetas, bm=bm, bk=bk, bn=bn, activation=activation,
         out_dtype=jnp.float32, interpret=interpret)
     return out[:m, :n].astype(out_dtype)
-
-
-def _qmeta_or_zeros(qmeta) -> jax.Array:
-    if qmeta is None:
-        return jnp.zeros((4,), jnp.float32)
-    return qmeta.astype(jnp.float32)
 
 
 def lut_dequant_matmul_dual(
     x_codes: jax.Array,    # [M, K] uint8 activation codes
     codes: jax.Array,      # [K, N] uint8 weight codes
-    lut_x: jax.Array,      # [256] activation decode table
-    lut_w: jax.Array,      # [256] weight decode table
-    qmeta_x: jax.Array | None = None,
-    qmeta_w: jax.Array | None = None,
+    qmeta_x: jax.Array,    # [4] activation params
+    qmeta_w: jax.Array,    # [4] weight params
     *,
     epilogue: str | None = None,
     bias: jax.Array | None = None,
     out_qmeta: jax.Array | None = None,
-    decode_mode: str = "gather",
     out_dtype=jnp.float32,
     interpret: bool | None = None,
     autotune: bool | None = None,
 ) -> jax.Array:
     """Dual-operand fused matmul: BOTH operands cross HBM as uint8
-    DNA-TEQ codes, each decoding through its own VMEM-resident table
-    inside the kernel.  ``out_qmeta`` turns on the quantize epilogue:
-    the flushed tile is re-encoded against those (calibrated) output
-    params and the call returns uint8 codes — consecutive quantized
-    matmuls stay code-in/code-out with no f32 intermediate in HBM.
+    DNA-TEQ codes, each decoding through its own parameters inside the
+    kernel.  ``out_qmeta`` turns on the quantize epilogue: the flushed
+    tile is re-encoded against those (calibrated) output params and the
+    call returns uint8 codes — consecutive quantized matmuls stay
+    code-in/code-out with no f32 intermediate in HBM.
 
     K is padded to 128 lanes; because a zero pad *byte* is a live code
     (it decodes to ±(alpha·base^e_min + beta)), the kernel masks the
@@ -433,39 +431,34 @@ def lut_dequant_matmul_dual(
     k_pad, n_pad = ck.shape
     k_valid = k if k_pad != k else None
     out_quant = out_qmeta is not None
-    luts = jnp.stack([lut_x.astype(jnp.float32),
-                      lut_w.astype(jnp.float32),
-                      jnp.zeros((256,), jnp.float32)])
-    qmetas = jnp.stack([_qmeta_or_zeros(qmeta_x), _qmeta_or_zeros(qmeta_w),
-                        _qmeta_or_zeros(out_qmeta)])
+    qmetas = jnp.stack([
+        _meta(qmeta_x), _meta(qmeta_w),
+        _meta(out_qmeta) if out_quant else jnp.zeros((4,), jnp.float32)])
     has_bias = bias is not None
     bias_arr = (_pad_axis_to(bias.astype(jnp.float32), n_pad, 0)
                 if has_bias else jnp.zeros((n_pad,), jnp.float32))
 
     def bench_factory(_cands):
-        sx, sc, slut, sqm, sb = _synth_operands(
+        sx, sc, sqm, sb = _synth_operands(
             m_pad, k_pad, n_pad, act_codes=True)
-        sluts = jnp.stack([slut, slut, slut])
         sqms = jnp.stack([sqm, sqm, sqm])
 
         def bench(tile):
             bm, bk, bn = tile
             return _bench_kernel(lambda: lut_dequant_matmul_dual_kernel(
-                sx, sc, sluts, sqms, sb, bm=bm, bk=bk, bn=bn,
-                decode_mode=decode_mode, epilogue=epilogue,
+                sx, sc, sqms, sb, bm=bm, bk=bk, bn=bn, epilogue=epilogue,
                 has_bias=has_bias, out_quant=out_quant, k_valid=k_valid,
                 out_dtype=jnp.float32, interpret=interpret))
         return bench
 
     bm, bk, bn = _tiling_for(
-        "dual", m_pad, k_pad, n_pad, decode_mode, _xrep(x_codes),
+        "dual", m_pad, k_pad, n_pad, _xrep(x_codes),
         f"{epilogue}|{int(has_bias)}|{int(out_quant)}",
         interpret, autotune, bench_factory)
     out = lut_dequant_matmul_dual_kernel(
-        xk, ck, luts, qmetas, bias_arr, bm=bm, bk=bk, bn=bn,
-        decode_mode=decode_mode, epilogue=epilogue, has_bias=has_bias,
-        out_quant=out_quant, k_valid=k_valid, out_dtype=jnp.float32,
-        interpret=interpret)
+        xk, ck, qmetas, bias_arr, bm=bm, bk=bk, bn=bn, epilogue=epilogue,
+        has_bias=has_bias, out_quant=out_quant, k_valid=k_valid,
+        out_dtype=jnp.float32, interpret=interpret)
     out = out[:m, :n]
     return out if out_quant else out.astype(out_dtype)
 
@@ -474,16 +467,12 @@ def lut_dequant_matmul_dual_gated(
     x_codes: jax.Array,    # [M, K] uint8 activation codes
     codes_g: jax.Array,    # [K, N] uint8 (gate)
     codes_u: jax.Array,    # [K, N] uint8 (up)
-    lut_x: jax.Array,
-    lut_g: jax.Array,
-    lut_u: jax.Array,
-    qmeta_x: jax.Array | None = None,
-    qmeta_g: jax.Array | None = None,
-    qmeta_u: jax.Array | None = None,
+    qmeta_x: jax.Array,
+    qmeta_g: jax.Array,
+    qmeta_u: jax.Array,
     *,
     activation: str = "silu",
     out_qmeta: jax.Array | None = None,
-    decode_mode: str = "gather",
     out_dtype=jnp.float32,
     interpret: bool | None = None,
     autotune: bool | None = None,
@@ -502,37 +491,31 @@ def lut_dequant_matmul_dual_gated(
     k_pad, n_pad = cg.shape
     k_valid = k if k_pad != k else None
     out_quant = out_qmeta is not None
-    luts = jnp.stack([lut_x.astype(jnp.float32),
-                      lut_g.astype(jnp.float32),
-                      lut_u.astype(jnp.float32),
-                      jnp.zeros((256,), jnp.float32)])
-    qmetas = jnp.stack([_qmeta_or_zeros(qmeta_x), _qmeta_or_zeros(qmeta_g),
-                        _qmeta_or_zeros(qmeta_u),
-                        _qmeta_or_zeros(out_qmeta)])
+    qmetas = jnp.stack([
+        _meta(qmeta_x), _meta(qmeta_g), _meta(qmeta_u),
+        _meta(out_qmeta) if out_quant else jnp.zeros((4,), jnp.float32)])
 
     def bench_factory(_cands):
-        sx, scg, scu, slut, sqm, _sb = _synth_operands(
+        sx, scg, scu, sqm, _sb = _synth_operands(
             m_pad, k_pad, n_pad, gated=True, act_codes=True)
-        sluts = jnp.stack([slut] * 4)
         sqms = jnp.stack([sqm] * 4)
 
         def bench(tile):
             bm, bk, bn = tile
             return _bench_kernel(
                 lambda: lut_dequant_matmul_dual_gated_kernel(
-                    sx, scg, scu, sluts, sqms, bm=bm, bk=bk, bn=bn,
-                    decode_mode=decode_mode, activation=activation,
-                    out_quant=out_quant, k_valid=k_valid,
-                    out_dtype=jnp.float32, interpret=interpret))
+                    sx, scg, scu, sqms, bm=bm, bk=bk, bn=bn,
+                    activation=activation, out_quant=out_quant,
+                    k_valid=k_valid, out_dtype=jnp.float32,
+                    interpret=interpret))
         return bench
 
     bm, bk, bn = _tiling_for(
-        "dual_gated", m_pad, k_pad, n_pad, decode_mode, _xrep(x_codes),
+        "dual_gated", m_pad, k_pad, n_pad, _xrep(x_codes),
         f"{activation}|{int(out_quant)}", interpret, autotune,
         bench_factory)
     out = lut_dequant_matmul_dual_gated_kernel(
-        xk, cg, cu, luts, qmetas, bm=bm, bk=bk, bn=bn,
-        decode_mode=decode_mode, activation=activation,
+        xk, cg, cu, qmetas, bm=bm, bk=bk, bn=bn, activation=activation,
         out_quant=out_quant, k_valid=k_valid, out_dtype=jnp.float32,
         interpret=interpret)
     out = out[:m, :n]
@@ -543,4 +526,5 @@ __all__ = ["lut_dequant_matmul", "lut_dequant_matmul_gated",
            "lut_dequant_matmul_dual", "lut_dequant_matmul_dual_gated",
            "lut_dequant_matmul_ref", "lut_dequant_matmul_gated_ref",
            "lut_dequant_matmul_dual_ref", "lut_dequant_matmul_dual_gated_ref",
-           "bucket_m", "Autotuner", "M_LADDER", "ACT_CODE_REP"]
+           "bucket_m", "Autotuner", "M_LADDER", "ACT_CODE_REP",
+           "tune_stats"]

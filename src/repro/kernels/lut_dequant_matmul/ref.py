@@ -1,22 +1,27 @@
-"""Pure-jnp oracles for the fused LUT-dequant matmul (+ epilogues)."""
+"""Pure-jnp oracles for the fused decode matmuls (+ epilogues): decode
+the whole operand, then one matmul."""
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import exponential_quant as eq
 from repro.kernels.lut_dequant_matmul.lut_dequant_matmul import (
     apply_activation as _act,
 )
 
 
+def _decode(codes: jax.Array, qmeta: jax.Array) -> jax.Array:
+    return eq.decode_meta(codes, jnp.asarray(qmeta, jnp.float32))
+
+
 def lut_dequant_matmul_ref(
-    x: jax.Array, codes: jax.Array, lut: jax.Array, qmeta=None,
+    x: jax.Array, codes: jax.Array, qmeta: jax.Array,
     out_dtype=jnp.float32, epilogue: str | None = None, bias=None,
 ) -> jax.Array:
-    w = lut.astype(jnp.float32)[codes.astype(jnp.int32)]
-    out = jnp.matmul(
-        x.astype(jnp.float32), w, preferred_element_type=jnp.float32)
+    out = jnp.matmul(x.astype(jnp.float32), _decode(codes, qmeta),
+                     preferred_element_type=jnp.float32)
     if bias is not None:
         out = out + bias.astype(jnp.float32)[None, :]
     return _act(out, epilogue).astype(out_dtype)
@@ -24,29 +29,23 @@ def lut_dequant_matmul_ref(
 
 def lut_dequant_matmul_gated_ref(
     x: jax.Array, codes_g: jax.Array, codes_u: jax.Array,
-    lut_g: jax.Array, lut_u: jax.Array, activation: str = "silu",
+    qmeta_g: jax.Array, qmeta_u: jax.Array, activation: str = "silu",
     out_dtype=jnp.float32,
 ) -> jax.Array:
-    g = lut_dequant_matmul_ref(x, codes_g, lut_g)
-    u = lut_dequant_matmul_ref(x, codes_u, lut_u)
+    g = lut_dequant_matmul_ref(x, codes_g, qmeta_g)
+    u = lut_dequant_matmul_ref(x, codes_u, qmeta_u)
     return (_act(g, activation) * u).astype(out_dtype)
-
-
-def _decode(codes: jax.Array, lut: jax.Array) -> jax.Array:
-    return lut.astype(jnp.float32)[codes.astype(jnp.int32)]
 
 
 def lut_dequant_matmul_dual_ref(
     x_codes: jax.Array, codes: jax.Array,
-    lut_x: jax.Array, lut_w: jax.Array,
+    qmeta_x: jax.Array, qmeta_w: jax.Array,
     out_qmeta: jax.Array | None = None,
     out_dtype=jnp.float32, epilogue: str | None = None, bias=None,
 ) -> jax.Array:
     """Decode-then-matmul oracle of the dual kernel: both operands
-    through their tables, one matmul, optional quantize epilogue."""
-    from repro.core import exponential_quant as eq
-
-    out = jnp.matmul(_decode(x_codes, lut_x), _decode(codes, lut_w),
+    decoded, one matmul, optional quantize epilogue."""
+    out = jnp.matmul(_decode(x_codes, qmeta_x), _decode(codes, qmeta_w),
                      preferred_element_type=jnp.float32)
     if bias is not None:
         out = out + bias.astype(jnp.float32)[None, :]
@@ -58,16 +57,14 @@ def lut_dequant_matmul_dual_ref(
 
 def lut_dequant_matmul_dual_gated_ref(
     x_codes: jax.Array, codes_g: jax.Array, codes_u: jax.Array,
-    lut_x: jax.Array, lut_g: jax.Array, lut_u: jax.Array,
+    qmeta_x: jax.Array, qmeta_g: jax.Array, qmeta_u: jax.Array,
     activation: str = "silu", out_qmeta: jax.Array | None = None,
     out_dtype=jnp.float32,
 ) -> jax.Array:
-    from repro.core import exponential_quant as eq
-
-    a = _decode(x_codes, lut_x)
-    g = jnp.matmul(a, _decode(codes_g, lut_g),
+    a = _decode(x_codes, qmeta_x)
+    g = jnp.matmul(a, _decode(codes_g, qmeta_g),
                    preferred_element_type=jnp.float32)
-    u = jnp.matmul(a, _decode(codes_u, lut_u),
+    u = jnp.matmul(a, _decode(codes_u, qmeta_u),
                    preferred_element_type=jnp.float32)
     out = _act(g, activation) * u
     if out_qmeta is not None:

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, SHAPES_BY_NAME, get_config, supports_shape
 from repro.configs.base import ModelConfig, RunShape
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import api as mapi
 from repro.models.params import abstract_params, logical_axes
 from repro.optim import adamw
@@ -249,7 +249,7 @@ def cost_pair_cfgs(cfg: ModelConfig):
 
 def _compile_metrics(cfg, shape, mesh, quant=None,
                      kv_dtype=jnp.bfloat16) -> dict:
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = lower_cell(cfg, shape, mesh, quant=quant,
                              kv_dtype=kv_dtype)
     compiled = lowered.compile()
@@ -382,7 +382,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
     t0 = time.time()
     try:
         # ---- phase 1: production lowering (fit + sharding proof) -------
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = lower_cell(cfg, shape, mesh, quant=quant,
                                  kv_dtype=kv_dtype)
         t_lower = time.time() - t0

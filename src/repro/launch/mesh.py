@@ -11,58 +11,18 @@ module touches no jax device state.
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 
 def get_abstract_mesh():
-    """Ambient abstract mesh across JAX versions (None when unset)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        from jax._src import mesh as mesh_lib
-
-        fn = getattr(mesh_lib, "get_abstract_mesh", None)
-        if fn is None:
-            return None
-    mesh = fn()
-    if mesh is None or getattr(mesh, "empty", False) or not getattr(
-            mesh, "axis_names", ()):
-        return None
-    return mesh
-
-
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """``jax.set_mesh`` across versions: newer JAX sets the ambient
-    (abstract + concrete) mesh directly; on older versions enter the
-    concrete mesh context and mirror its AbstractMesh thread-local so
-    ``get_abstract_mesh`` consumers (sharding constraints, EP dispatch)
-    see it."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        with set_mesh(mesh):
-            yield mesh
-        return
-    from jax._src import mesh as mesh_lib
-
-    with mesh:
-        if hasattr(mesh, "abstract_mesh") and hasattr(
-                mesh_lib, "set_abstract_mesh"):
-            with mesh_lib.set_abstract_mesh(mesh.abstract_mesh):
-                yield mesh
-        else:
-            yield mesh
+    """Ambient abstract mesh (None when unset)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer JAX."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -40,6 +40,7 @@ import time
 
 import numpy as np
 
+from repro.caches import enable_compile_cache
 from repro.configs import get_config
 from repro.runtime.chaos import ChaosConfig
 from repro.runtime.cluster import Cluster, ClusterConfig
@@ -118,6 +119,7 @@ def main():
                     help="append a JSONL snapshot of the metrics "
                          "registry here on exit or SIGINT")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, tiny=args.tiny)
     rng = np.random.default_rng(0)

@@ -473,7 +473,7 @@ def mha_decode_paged(
 
     When the pages hold uint8 DNA-TEQ codes (codes-mode KV cache), the
     roped query is encoded at the attn_q site, the codes kernel decodes
-    q/K/V through per-head VMEM LUTs and re-encodes the context under
+    q/K/V with per-head parameters in VMEM and re-encodes the context under
     the attn_out meta in-kernel, and the output projection consumes the
     resulting ``QTensor`` directly — code-in/code-out through the whole
     attend, no f32 activation at the attention boundary."""
@@ -494,11 +494,11 @@ def mha_decode_paged(
                 "attn_k/attn_v/attn_out act-quant sites (kv_codes "
                 "engines calibrate them; found none on this attend)")
         # codes mode ignores flash_decode: off-TPU the codes op runs
-        # the page-scan oracle, the *identical* recurrence.
+        # the page-scan oracle, the same recurrence.
         q_codes = ll.eq.encode_meta(qg, aq["attn_q"]["qmeta"])
         out = decode_gqa_paged_codes(
-            q_codes, k_pages, v_pages, aq["attn_q"]["lut"],
-            aq["attn_k"]["lut"], aq["attn_v"]["lut"],
+            q_codes, k_pages, v_pages, aq["attn_q"]["qmeta"],
+            aq["attn_k"]["qmeta"], aq["attn_v"]["qmeta"],
             aq["attn_out"]["qmeta"], block_tables, lengths)
         ctx = ll.eq.QTensor(out.reshape(b, 1, h, hd),
                             aq["attn_out"]["lut"], aq["attn_out"]["qmeta"])
@@ -539,14 +539,16 @@ def mha_prefill_paged(
     chunk's own K/V into the pages *before* this runs, so within-chunk
     causality falls out of the same positional mask that covers the
     cached prefix; no ``[B, S, T]`` mask or score matrix exists at any
-    point.
+    point.  ``flash_decode=False`` in the policy swaps in the pure-jnp
+    paged oracle, as in :func:`mha_decode_paged`.
 
     With uint8 codes-mode pages the chunk runs code-in/code-out exactly
     like :func:`mha_decode_paged`: attn_q-encoded queries, per-head
-    VMEM LUT decode of K/V in-kernel, attn_out re-encode epilogue, and
+    closed-form decode of K/V in-kernel, attn_out re-encode epilogue, and
     a ``QTensor`` context fed straight to the output projection."""
     from repro.kernels.flash_prefill import (flash_prefill_paged,
-                                             flash_prefill_paged_codes)
+                                             flash_prefill_paged_codes,
+                                             flash_prefill_paged_ref)
 
     dt = x.dtype
     q = roped_q(p, x, cfg, positions, use_rope=use_rope, act_q=act_q)
@@ -562,14 +564,15 @@ def mha_prefill_paged(
                 "engines calibrate them; found none on this attend)")
         q_codes = ll.eq.encode_meta(qg, aq["attn_q"]["qmeta"])
         out = flash_prefill_paged_codes(
-            q_codes, k_pages, v_pages, aq["attn_q"]["lut"],
-            aq["attn_k"]["lut"], aq["attn_v"]["lut"],
+            q_codes, k_pages, v_pages, aq["attn_q"]["qmeta"],
+            aq["attn_k"]["qmeta"], aq["attn_v"]["qmeta"],
             aq["attn_out"]["qmeta"], block_tables, q_start, kv_lens)
         ctx = ll.eq.QTensor(out.reshape(b, s, h, hd),
                             aq["attn_out"]["lut"], aq["attn_out"]["qmeta"])
         return ll.dense_general(ctx, p["wo"], "bsnh,nhd->bsd", dtype=dt)
-    out = flash_prefill_paged(qg, k_pages, v_pages, block_tables,
-                              q_start, kv_lens)
+    attend = (flash_prefill_paged if ll.get_policy().flash_decode
+              else flash_prefill_paged_ref)
+    out = attend(qg, k_pages, v_pages, block_tables, q_start, kv_lens)
     out = out.reshape(b, s, h, hd).astype(dt)
     return ll.dense_general(_q(out, act_q, "attn_out"), p["wo"],
                             "bsnh,nhd->bsd", dtype=dt)

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.caches import repo_cache_path
 from repro.core import exponential_quant as eq
 
 _CALIB_VERSION = 2
@@ -90,10 +91,8 @@ ACT_FIT_ITERS = 20
 
 
 def cache_path() -> str:
-    return os.environ.get(
-        "REPRO_ACT_CALIB_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "act_quant_calib.json"))
+    return os.environ.get("REPRO_ACT_CALIB_CACHE",
+                          repo_cache_path("act_quant_calib.json"))
 
 
 def _params_fingerprint(params) -> str:

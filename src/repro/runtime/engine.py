@@ -1136,6 +1136,44 @@ class Engine:
             self.submit(r)
         return self.run()
 
+    def prompt_logits(self, prompts: Sequence[np.ndarray]) -> np.ndarray:
+        """Next-token logits ``[len(prompts), V]`` after each prompt.
+
+        The prompts run through this engine's prefill step (its params,
+        KV representation, page size and ``prefill_chunk``) over a
+        scratch page pool, outside the scheduler: the serving numerics,
+        for checks against a reference.  The step is traced afresh on
+        every call, so the ``lama_layers`` policy in force applies."""
+        ec = self.engine_cfg
+        n, longest = len(prompts), max(len(p) for p in prompts)
+        max_blk = -(-longest // ec.block_size)
+        cache = PagedKVCache(
+            num_layers=self.cfg.num_layers,
+            num_kv_heads=self.cfg.num_kv_heads,
+            head_dim=self.cfg.resolved_head_dim, num_slots=n,
+            block_size=ec.block_size, num_blocks=n * max_blk + 1,
+            max_blocks_per_seq=max_blk, dtype=self.kv_dtype)
+        for i, p in enumerate(prompts):
+            cache.bind_slot(i, len(p), reserved=False)
+        step = jax.jit(lambda params, toks, view, start:
+                       self.api.prefill_into_cache(params, toks, view,
+                                                   self.cfg, start))
+        w = min(ec.prefill_chunk, longest)
+        out = [None] * n
+        for s0 in range(0, longest, w):
+            toks = np.zeros((n, w), np.int32)
+            for i, p in enumerate(prompts):
+                toks[i, :max(0, min(w, len(p) - s0))] = p[s0:s0 + w]
+            # rows whose prompt already ended: start = length, no work
+            start = np.asarray([min(s0, len(p)) for p in prompts], np.int32)
+            logits, view = step(self.params, jnp.asarray(toks),
+                                cache.view(), jnp.asarray(start))
+            cache.update_pages(view)
+            for i, p in enumerate(prompts):
+                if s0 < len(p) <= s0 + w:
+                    out[i] = np.asarray(logits[i, -1], np.float32)
+        return np.stack(out)
+
     # ------------------------------------------------------- diagnostics
     @property
     def prefix_stats(self):

@@ -78,35 +78,32 @@ class TestExponentIdentity:
             share_base=True)
         counting = np.asarray(ed.counting_matmul(ca, pa, cw, pw))
         ref = np.asarray(lut_dequant_matmul_dual_ref(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw)))
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw)))
         kern = np.asarray(kops.lut_dequant_matmul_dual(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw),
-            eq.pack_qmeta(pa), eq.pack_qmeta(pw),
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw),
             out_dtype=jnp.float32))
         np.testing.assert_allclose(counting, ref, rtol=2e-4, atol=1e-4)
         np.testing.assert_allclose(kern, ref, rtol=2e-5, atol=2e-5)
 
-    @pytest.mark.parametrize("decode_mode", ["gather", "alu"])
-    def test_kernel_decode_modes(self, decode_mode):
+    def test_kernel_decode_modes(self):
+        """The kernel's closed-form decode of both operands equals a
+        matmul of the operands looked up in their 256-entry tables."""
         (a, ca, pa), (w, cw, pw) = _coded_pair(3, 40, 96, 33, 7, 6)
         out = np.asarray(kops.lut_dequant_matmul_dual(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw),
-            eq.pack_qmeta(pa), eq.pack_qmeta(pw),
-            decode_mode=decode_mode, out_dtype=jnp.float32))
-        ref = np.asarray(lut_dequant_matmul_dual_ref(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw)))
-        tol = 1e-3 if decode_mode == "alu" else 2e-5
-        np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw),
+            out_dtype=jnp.float32))
+        ref = np.asarray(eq.decode(ca, pa) @ eq.decode(cw, pw))
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
     def test_k_padding_masked(self):
         """K not a lane multiple: a zero pad BYTE is a live code (it
         decodes to ±(α·base^e_min + β)) — the kernel must mask it."""
         (a, ca, pa), (w, cw, pw) = _coded_pair(4, 9, 100, 17, 7, 6)
         out = np.asarray(kops.lut_dequant_matmul_dual(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw),
-            eq.pack_qmeta(pa), eq.pack_qmeta(pw), out_dtype=jnp.float32))
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw),
+            out_dtype=jnp.float32))
         ref = np.asarray(lut_dequant_matmul_dual_ref(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw)))
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw)))
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
@@ -118,11 +115,10 @@ class TestDualKernel:
         bias = jnp.asarray(np.random.default_rng(6).normal(size=(48,)),
                            jnp.float32)
         out = np.asarray(kops.lut_dequant_matmul_dual(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw),
-            eq.pack_qmeta(pa), eq.pack_qmeta(pw), epilogue="silu",
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw), epilogue="silu",
             bias=bias, out_dtype=jnp.float32))
         ref = np.asarray(lut_dequant_matmul_dual_ref(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw),
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw),
             epilogue="silu", bias=bias))
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
@@ -131,11 +127,10 @@ class TestDualKernel:
         in-kernel, matching the reference encode of the float result."""
         (a, ca, pa), (w, cw, pw) = _coded_pair(7, 16, 64, 40, 7, 6)
         ref_f = lut_dequant_matmul_dual_ref(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw))
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw))
         qm_o = eq.pack_qmeta(eq.fit(jnp.reshape(ref_f, (-1,)), 7))
         out = kops.lut_dequant_matmul_dual(
-            ca, cw, eq.decode_table(pa), eq.decode_table(pw),
-            eq.pack_qmeta(pa), eq.pack_qmeta(pw), out_qmeta=qm_o)
+            ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw), out_qmeta=qm_o)
         assert out.dtype == jnp.uint8
         ref_c = eq.encode_meta(ref_f, qm_o)
         # f32 accumulation-order deltas may flip a rounding-boundary
@@ -150,14 +145,13 @@ class TestDualKernel:
         (a, ca, pa), (wg, cg, pg) = _coded_pair(8, 12, 64, 56, 7, 6)
         wu = jnp.asarray(r.normal(size=(64, 56)) * 0.05, jnp.float32)
         cu, pu = eq.quantize(wu, 6)
-        args = (ca, cg, cu, eq.decode_table(pa), eq.decode_table(pg),
-                eq.decode_table(pu), eq.pack_qmeta(pa), eq.pack_qmeta(pg),
+        args = (ca, cg, cu, eq.pack_qmeta(pa), eq.pack_qmeta(pg),
                 eq.pack_qmeta(pu))
         out = np.asarray(kops.lut_dequant_matmul_dual_gated(
             *args, activation="silu", out_dtype=jnp.float32))
         ref = np.asarray(lut_dequant_matmul_dual_gated_ref(
-            ca, cg, cu, eq.decode_table(pa), eq.decode_table(pg),
-            eq.decode_table(pu), activation="silu"))
+            ca, cg, cu, eq.pack_qmeta(pa), eq.pack_qmeta(pg),
+            eq.pack_qmeta(pu), activation="silu"))
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
         # with the quantize epilogue the gated flush comes back as codes
         qm_o = eq.pack_qmeta(eq.fit(jnp.asarray(ref).reshape(-1), 7))
@@ -426,15 +420,16 @@ class TestAutotunerActRep:
         assert kops._xrep(jnp.zeros((2, 2), jnp.float32)) == "float32"
         assert kops._xrep(jnp.zeros((2, 2), jnp.bfloat16)) == "bfloat16"
         assert kops._xrep(jnp.zeros((2, 2), jnp.uint8)) == kops.ACT_CODE_REP
-        k_fp = kops._tune_key("mm", 8, 128, 128, "gather", "float32", "e")
-        k_u8 = kops._tune_key("mm", 8, 128, 128, "gather",
-                              kops.ACT_CODE_REP, "e")
+        k_fp = kops._tune_key("mm", 8, 128, 128, "float32", "e")
+        k_u8 = kops._tune_key("mm", 8, 128, 128, kops.ACT_CODE_REP, "e")
         assert k_fp != k_u8
+        # keyed by the chip generation, not the backend name
+        assert k_fp.split("|")[0] == jax.devices()[0].device_kind
 
     def test_v1_cache_invalidated(self, tmp_path):
         """Pre-xrep persisted tiles (v1 keys have no representation
         component) must not be consulted."""
-        assert kops._TUNE_VERSION >= 2
+        assert kops._TUNE_VERSION >= 3
         path = tmp_path / "tune.json"
         path.write_text(json.dumps(
             {"version": 1,
@@ -462,6 +457,22 @@ def _tiny_cfg():
         compute_dtype="float32")
 
 
+# Greedy tokens on random weights flip on near-ties, so a token
+# agreement rate measures luck; the logits are what act quantization
+# moves.  7-bit codes at the calibrated sites carry about 40 dB mean SQNR
+# (1% noise) and about 25 dB at the worst site (6%).  Through two layers
+# that moves each prompt's logits by a few percent of their spread (up
+# to 8% with KV codes on top), where a wrong table, head or site moves
+# them by their whole spread.
+ACT_LOGIT_TOL = 0.15
+
+
+def _rel_logit_err(got, ref):
+    """Per-prompt RMS logit error over the reference logits' spread."""
+    return (np.sqrt(np.mean((got - ref) ** 2, axis=-1))
+            / np.std(ref, axis=-1))
+
+
 def _requests(cfg, lens, news=6, seed=3):
     rng = np.random.default_rng(seed)
     return [Request(i, rng.integers(0, cfg.vocab_size,
@@ -474,9 +485,10 @@ class TestServingActQuant:
     def test_token_agreement_and_zero_materialization(
             self, isolated_caches):
         """The acceptance harness: act-quant on vs off on the
-        tiny-config serving scenario — ≥ 0.95 greedy token agreement,
-        and with act-quant enabled NO carrier (weight codes or act
-        codes) is ever decoded outside a kernel during the run."""
+        tiny-config serving scenario — logits within
+        :data:`ACT_LOGIT_TOL` of the fp-act engine's, and with act-quant
+        enabled NO carrier (weight codes or act codes) is ever decoded
+        outside a kernel during the run."""
         cfg = _tiny_cfg()
         ecfg = EngineConfig(num_slots=4, block_size=16, max_seq_len=64)
         reqs = _requests(cfg, [16, 24, 32] * 4)
@@ -501,10 +513,11 @@ class TestServingActQuant:
 
         with mock.patch.object(ll, "materialize", guarded):
             out_act = {c.uid: c.tokens for c in act.generate(clone())}
-
-        agree = float(np.mean(
-            [np.mean(out_fp[u] == out_act[u]) for u in out_fp]))
-        assert agree >= 0.95, f"token agreement {agree:.2%} < 95%"
+            got = act.prompt_logits([r.prompt for r in reqs])
+        assert {u: len(t) for u, t in out_act.items()} == {
+            u: len(t) for u, t in out_fp.items()}
+        rel = _rel_logit_err(got, fp.prompt_logits([r.prompt for r in reqs]))
+        assert rel.max() < ACT_LOGIT_TOL, rel
 
     def test_policy_off_recovers_fp_act(self, isolated_caches):
         """act_quant=False in the policy A/B-disables encoding without

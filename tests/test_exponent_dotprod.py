@@ -47,7 +47,7 @@ def test_counting_matmul_equals_dequant_matmul():
 
 
 def test_serving_codes_oracle_matches_eq1():
-    """The codes-mode attention oracles' q·k contraction (per-head LUT
+    """The codes-mode attention oracles' q·k contraction (per-head
     decode then an MXU einsum — the page-scan refs in repro.kernels)
     IS the dequant_matmul formulation, and therefore Eq.1-consistent
     with the counting formulation when the two quantizers share a base
@@ -63,9 +63,9 @@ def test_serving_codes_oracle_matches_eq1():
     pk = eq.ExpQuantParams(pk0.alpha, pk0.beta, pq.base, 7)
     ck = eq.encode(k, pk)
     # the serving oracle's decode path: q through its 256-entry table,
-    # k through the per-head LUT helper both kernels and refs share
+    # k through the per-head closed-form helper kernels and refs share
     qd = jnp.take(eq.decode_table(pq), cq.astype(jnp.int32), axis=0)
-    kd = decode_heads(eq.decode_table(pk)[None], ck[:, None, :])
+    kd = decode_heads(eq.pack_qmeta(pk)[None], ck[:, None, :])
     logits = jnp.einsum("gh,sh->gs", qd, kd[:, 0, :],
                         preferred_element_type=jnp.float32)
     m_deq = np.asarray(ed.dequant_matmul(cq, pq, ck.T, pk))

@@ -2,9 +2,9 @@
 
 Covers the flash_prefill_paged kernel (block-table gather, per-row
 start offsets, kv_lens masking, f8 in-kernel dequant, kernel == oracle
-bit-for-bit), the unified ``prefill_into_cache`` (cold and
-prefix-offset chunking across {1-page, 2-page, odd} chunk sizes
-bit-identical to the single-call prefill in f32; zero-length tails
+to f32 rounding), the unified ``prefill_into_cache`` (cold and
+prefix-offset chunking across {1-page, 2-page, odd} chunk sizes equal
+to the single-call prefill to f32 rounding; zero-length tails
 write nothing), and the Engine's chunked-prefill scheduling (chunk
 interleaving with decode is token-identical to the un-chunked engine,
 long prompts stop monopolizing ticks, TTFT/queue-wait stats, and the
@@ -59,13 +59,17 @@ class TestFlashPrefillKernel:
         return q, kp, vp, bt, start, kv_lens
 
     def test_kernel_matches_ref_bitwise(self):
-        """The forced kernel and the jnp oracle run the identical page
-        recurrence — bit-for-bit in f32."""
+        """The forced kernel and the jnp oracle run the same page
+        recurrence.  The kernel scores all heads of a page in one folded
+        2-D dot where the oracle contracts per head, so the two agree
+        to f32 rounding (the dense-oracle tolerance below), not bit for
+        bit."""
         q, kp, vp, bt, start, kv_lens = self._inputs()
         out_k = flash_prefill_paged(q, kp, vp, bt, start, kv_lens,
                                     interpret=True)
         out_r = flash_prefill_paged_ref(q, kp, vp, bt, start, kv_lens)
-        np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                                   rtol=2e-5, atol=2e-5)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float8_e4m3fn])
     def test_matches_dense_oracle(self, dtype):
@@ -111,12 +115,14 @@ class TestFlashPrefillKernel:
             assert np.all(out == 0)
 
     def test_oracle_path_matches_kernel(self):
-        """The CPU-default oracle path (interpret=None) == kernel."""
+        """The CPU-default oracle path (interpret=None) == kernel, to
+        f32 rounding (folded vs per-head contraction, as above)."""
         q, kp, vp, bt, start, kv_lens = self._inputs()
         auto = flash_prefill_paged(q, kp, vp, bt, start, kv_lens)
         forced = flash_prefill_paged(q, kp, vp, bt, start, kv_lens,
                                      interpret=True)
-        np.testing.assert_array_equal(np.asarray(auto), np.asarray(forced))
+        np.testing.assert_allclose(np.asarray(auto), np.asarray(forced),
+                                   rtol=2e-5, atol=2e-5)
 
 
 # ------------------------------------------- unified prefill (model) --
@@ -171,18 +177,22 @@ class TestUnifiedPrefill:
 
     @pytest.mark.parametrize("chunk", [4, 8, 5])   # 1 page, 2 pages, odd
     def test_cold_chunked_bitwise_matches_single_call(self, chunk):
-        """Chunked cold prefill == the single whole-prompt call,
-        bit-for-bit in f32: same non-trash page contents, same final
-        logits, whatever the chunk size (page-aligned or odd)."""
+        """Chunked cold prefill == the single whole-prompt call: same
+        non-trash page contents, same final logits, whatever the chunk
+        size (page-aligned or odd).  The two run matmuls of different
+        shapes, whose f32 summation order XLA does not fix, so they
+        agree to f32 rounding (the prefix-offset tolerance below), not
+        bit for bit."""
         cfg, api, params, prompt = self._setup()
         logits1, view1 = self._single_call(cfg, api, params, prompt)
         logits2, view2 = self._chunked(cfg, api, params, prompt, chunk)
-        np.testing.assert_array_equal(np.asarray(view1.k_pages)[:, 1:],
-                                      np.asarray(view2.k_pages)[:, 1:])
-        np.testing.assert_array_equal(np.asarray(view1.v_pages)[:, 1:],
-                                      np.asarray(view2.v_pages)[:, 1:])
-        np.testing.assert_array_equal(np.asarray(logits1),
-                                      np.asarray(logits2))
+        for a, b in [(view1.k_pages, view2.k_pages),
+                     (view1.v_pages, view2.v_pages)]:
+            np.testing.assert_allclose(np.asarray(a)[:, 1:],
+                                       np.asarray(b)[:, 1:],
+                                       rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(logits1), np.asarray(logits2),
+                                   rtol=2e-5, atol=2e-5)
 
     @pytest.mark.parametrize("chunk", [4, 8, 5])
     def test_prefix_offset_chunked_matches_cold(self, chunk):

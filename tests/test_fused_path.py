@@ -47,17 +47,15 @@ ZOO_SPECS = [
 class TestDenseGeneralParity:
     @pytest.mark.parametrize("spec,xs,wsh", ZOO_SPECS,
                              ids=[s[0] for s in ZOO_SPECS])
-    @pytest.mark.parametrize("decode_mode", ["gather", "alu"])
-    def test_spec_parity_vs_materialize(self, spec, xs, wsh, decode_mode):
+    def test_spec_parity_vs_materialize(self, spec, xs, wsh):
         r = np.random.default_rng(hash(spec) % 2**31)
         x = jnp.asarray(r.normal(size=xs), jnp.float32)
         w, wf = _qt(r, wsh)
         ref = jnp.einsum(spec, x, wf, preferred_element_type=jnp.float32)
-        with ll.policy(mode="fused", decode_mode=decode_mode):
+        with ll.policy(mode="fused"):
             out = ll.dense_general(x, w, spec, dtype=jnp.float32)
-        tol = 1e-3 if decode_mode == "alu" else 2e-5
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=tol, atol=tol)
+                                   rtol=2e-5, atol=2e-5)
 
     def test_unsupported_spec_falls_back(self):
         """Repeated labels can't canonicalize -> materialize fallback."""
@@ -143,21 +141,18 @@ def test_zero_materialization_forward_and_decode(arch):
 
 
 class TestTransposedCodes:
-    @pytest.mark.parametrize("decode_mode", ["gather", "alu"])
-    def test_wrapper_parity(self, decode_mode):
+    def test_wrapper_parity(self):
         """codes stored [N, K] contract correctly without an HBM-side
         transpose (tied-unembedding layout)."""
         r = np.random.default_rng(11)
         wt, wtf = _qt(r, (70, 130))          # [N, K] storage
         x = jnp.asarray(r.normal(size=(33, 130)), jnp.float32)
         out = kops.lut_dequant_matmul(
-            x, wt["codes"], wt["lut"], wt["qmeta"],
-            decode_mode=decode_mode, transpose_codes=True,
+            x, wt["codes"], wt["qmeta"], transpose_codes=True,
             out_dtype=jnp.float32)
         ref = x @ wtf.T
-        tol = 1e-3 if decode_mode == "alu" else 2e-5
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=tol, atol=tol)
+                                   rtol=2e-5, atol=2e-5)
 
     def test_tied_unembed_spec_uses_kernel_transpose(self):
         """'bsd,vd->bsv' must dispatch with transpose_codes=True (the
@@ -202,7 +197,7 @@ class TestMBucketing:
         with mock.patch.object(kops, "lut_dequant_matmul_kernel", spy):
             for m in (33, 60, 64):
                 x = jnp.asarray(r.normal(size=(m, 130)), jnp.float32)
-                out = kops.lut_dequant_matmul(x, w["codes"], w["lut"])
+                out = kops.lut_dequant_matmul(x, w["codes"], w["qmeta"])
                 assert out.shape == (m, 70)
         assert shapes == {(64, 256)}, shapes
 
@@ -219,7 +214,7 @@ class TestAutotuner:
                 tile, 5.0)
 
         cands = [(32, 128, 128), (64, 128, 128), (128, 128, 128)]
-        tile = tuner.get("cpu|mm|64|128|128|gather|x", cands, bench)
+        tile = tuner.get("cpu|mm|64|128|128|x", cands, bench)
         assert tile == (64, 128, 128)
         assert len(calls) == 3
         assert os.path.exists(path)
@@ -227,7 +222,7 @@ class TestAutotuner:
         # a fresh tuner instance reads the persisted choice, no timing
         tuner2 = kops.Autotuner(path)
         calls.clear()
-        tile2 = tuner2.get("cpu|mm|64|128|128|gather|x", cands, bench)
+        tile2 = tuner2.get("cpu|mm|64|128|128|x", cands, bench)
         assert tile2 == (64, 128, 128)
         assert not calls
 
@@ -252,7 +247,7 @@ class TestAutotuner:
         path = str(tmp_path / "tune.json")
         with mock.patch.object(kops, "_TUNER", kops.Autotuner(path)):
             out = jax.jit(lambda a: kops.lut_dequant_matmul(
-                a, w["codes"], w["lut"], autotune=True,
+                a, w["codes"], w["qmeta"], autotune=True,
                 out_dtype=jnp.float32))(x)
         assert os.path.exists(path), "tuner did not persist under jit"
         (entry,) = json.load(open(path))["entries"].values()
@@ -270,6 +265,10 @@ class TestAutotuner:
         cands = [(32, 128, 128), (64, 128, 128)]
         assert tuner.get("k", cands, bench) == (32, 128, 128)
         assert not os.path.exists(path)
+        # every failed candidate is counted and kept, not swallowed
+        assert (tuner.tried, tuner.failed) == (2, 2)
+        assert [f[1] for f in tuner.failures] == cands
+        assert "RuntimeError: no fit" in tuner.failures[0][2]
         # a later working bench still tunes (nothing was cached)
         assert tuner.get("k", cands, lambda t: 1.0) == (32, 128, 128)
         assert os.path.exists(path)
@@ -333,7 +332,15 @@ def test_flash_decode_matches_dense_attend():
 
 
 def test_quantized_server_f8_kv_close_to_fp32_kv():
-    """Narrow-dtype KV serving stays logit-close to the fp32 cache."""
+    """Narrow-dtype KV serving stays logit-close to the fp32 cache.
+
+    The f8 cache must hold exactly the f8 rounding of the f32 cache, and
+    a decode step over it must match the f32 server decoding over that
+    same rounded prefix.  The f8 rounding itself (3 mantissa bits) moves
+    random-weight logits by 7-21% of their spread depending on the
+    prompt, so comparing against the unrounded cache measures the prompt,
+    not the dequant; what is left against the rounded prefix is the
+    rounding of the one new token's K/V."""
     from repro.runtime.server import InferenceServer
 
     cfg = get_config("olmo-1b", tiny=True).replace(compute_dtype="float32")
@@ -345,7 +352,13 @@ def test_quantized_server_f8_kv_close_to_fp32_kv():
         jnp.int32)
     la, ca = base._prefill(base.params, toks, None)
     lb, cb = f8._prefill(f8.params, toks, None)
-    a, _ = base._decode(base.params, ca, toks[:, :1])
+    f8_round = lambda c: c.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(f8_round(ca[key])),
+            np.asarray(cb[key].astype(jnp.float32)))
+    rounded = dict(ca, k=f8_round(ca["k"]), v=f8_round(ca["v"]))
+    a, _ = base._decode(base.params, rounded, toks[:, :1])
     b, _ = f8._decode(f8.params, cb, toks[:, :1])
     rel = float(jnp.sqrt(jnp.mean((a - b) ** 2)) / (jnp.std(a) + 1e-9))
     assert rel < 0.2, rel

@@ -7,7 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import exponential_quant as eq
 from repro.core.lut import build_lut, mul_lut
@@ -32,10 +33,10 @@ class TestLutDequantMatmul:
         r = np.random.default_rng(m * 1000 + n)
         w = jnp.asarray(r.normal(size=(k, n)) * 0.05, jnp.float32)
         codes, qp = eq.quantize(w, bits)
-        lut = eq.decode_table(qp)
+        qmeta = eq.pack_qmeta(qp)
         x = jnp.asarray(r.normal(size=(m, k)), jnp.float32)
-        ref = lut_dequant_matmul_ref(x, codes, lut)
-        out = lut_dequant_matmul(x, codes, lut, out_dtype=jnp.float32)
+        ref = lut_dequant_matmul_ref(x, codes, qmeta)
+        out = lut_dequant_matmul(x, codes, qmeta, out_dtype=jnp.float32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=1e-5)
 
@@ -44,24 +45,28 @@ class TestLutDequantMatmul:
         r = np.random.default_rng(7)
         w = jnp.asarray(r.normal(size=(128, 128)) * 0.1, jnp.float32)
         codes, qp = eq.quantize(w, 6)
-        lut = eq.decode_table(qp)
+        qmeta = eq.pack_qmeta(qp)
         x = jnp.asarray(r.normal(size=(64, 128)), dtype)
-        ref = lut_dequant_matmul_ref(x, codes, lut)
-        out = lut_dequant_matmul(x, codes, lut, out_dtype=jnp.float32)
+        ref = lut_dequant_matmul_ref(x, codes, qmeta)
+        out = lut_dequant_matmul(x, codes, qmeta, out_dtype=jnp.float32)
         rtol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=rtol, atol=1e-3)
 
     def test_alu_mode_matches_gather(self):
+        """The in-kernel closed-form decode gives the values of a lookup
+        in the 256-entry table: the kernel equals a matmul against the
+        table-gathered weight."""
         r = np.random.default_rng(9)
         w = jnp.asarray(r.normal(size=(256, 128)) * 0.02, jnp.float32)
         codes, qp = eq.quantize(w, 7)
         lut = eq.decode_table(qp)
-        qmeta = jnp.asarray(
-            [qp.alpha, qp.beta, qp.base, float(qp.bits)], jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(lut), np.asarray(eq.decode_meta(
+                jnp.arange(256), eq.pack_qmeta(qp))))
         x = jnp.asarray(r.normal(size=(32, 256)), jnp.float32)
-        g = lut_dequant_matmul(x, codes, lut, qmeta, decode_mode="gather")
-        a = lut_dequant_matmul(x, codes, lut, qmeta, decode_mode="alu")
+        g = x @ lut[codes.astype(jnp.int32)]
+        a = lut_dequant_matmul(x, codes, eq.pack_qmeta(qp))
         np.testing.assert_allclose(np.asarray(g), np.asarray(a),
                                    rtol=1e-4, atol=1e-5)
 
@@ -74,7 +79,7 @@ class TestLutDequantMatmul:
         leaf = eq.pack_qtensor(codes, qp)
         x = jnp.asarray(r.normal(size=(16, 128)), jnp.float32)
         dense_out = ll.dense(x, leaf, dtype=jnp.float32)
-        kern_out = lut_dequant_matmul(x, codes, eq.decode_table(qp),
+        kern_out = lut_dequant_matmul(x, codes, eq.pack_qmeta(qp),
                                       out_dtype=jnp.float32)
         np.testing.assert_allclose(np.asarray(dense_out),
                                    np.asarray(kern_out), rtol=2e-5, atol=1e-5)

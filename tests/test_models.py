@@ -196,9 +196,8 @@ def test_moe_ep_a2a_matches_routed():
     x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 16, cfg.d_model)),
                     jnp.float32)
     routed, _ = M.apply_moe_routed(params, x, cfg)
-    from repro.launch.mesh import use_mesh
     mesh = make_host_mesh(model=1)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         ep, _ = jax.jit(lambda p, xx: M.apply_moe(
             p, xx, cfg.replace(moe_impl="ep_a2a")))(params, x)
     np.testing.assert_allclose(np.asarray(ep), np.asarray(routed),

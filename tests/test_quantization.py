@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, hypothesis, settings, st
+import hypothesis
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import exponential_quant as eq
 
