@@ -1,0 +1,7 @@
+"""Rows decoded per decode dispatch in the traced span: decoded tokens
+over the change of the Engine's ``engine.decode.steps`` counter."""
+
+
+def read(run):
+    decoded = sum(len(d.valid) for d in run.dispatches if d.kind == "decode")
+    return decoded / run.decode_steps if run.decode_steps else None
