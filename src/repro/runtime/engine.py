@@ -245,6 +245,8 @@ _QUEUED, _RUNNING, _FINISHED = "queued", "running", "finished"
 # arrays via update_pages, so the inputs are dead and XLA can scatter
 # the new token's KV in place instead of copying the whole pool each
 # tick.  (CPU lacks donation support — measured strictly slower there.)
+# Each inner function's name names its compiled program in a profiler
+# trace: ``jit_prefill_step``, ``jit_decode_step``, ``jit_spec_verify_step``.
 
 def _donate(*argnums):
     return argnums if jax.default_backend() != "cpu" else ()
@@ -258,24 +260,26 @@ def _donate(*argnums):
 
 @functools.lru_cache(maxsize=None)
 def _jit_prefill(prefill_fn):
-    def fn(params, tokens, view, start, cfg):
+    def prefill_step(params, tokens, view, start, cfg):
         logits, view = prefill_fn(params, tokens, view, cfg, start)
         last = logits[:, -1, :]
         nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
         ok = jnp.all(jnp.isfinite(last), axis=-1)
         return nxt, ok, view
-    return jax.jit(fn, static_argnums=(4,), donate_argnums=_donate(2))
+    return jax.jit(prefill_step, static_argnums=(4,),
+                   donate_argnums=_donate(2))
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_decode(step_fn):
-    def fn(params, view, tokens, active, cfg):
+    def decode_step(params, view, tokens, active, cfg):
         logits, view = step_fn(params, view, tokens, active, cfg)
         last = logits[:, -1, :]
         nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
         ok = jnp.all(jnp.isfinite(last), axis=-1)
         return nxt, ok, view
-    return jax.jit(fn, static_argnums=(4,), donate_argnums=_donate(1))
+    return jax.jit(decode_step, static_argnums=(4,),
+                   donate_argnums=_donate(1))
 
 
 @functools.lru_cache(maxsize=None)
@@ -283,9 +287,10 @@ def _jit_spec_verify(verify_fn):
     """One speculative verify-and-commit dispatch: greedy tokens,
     accept counts, and finite flags all computed in-dispatch (same
     one-host-round-trip discipline as the decode step)."""
-    def fn(params, tokens, view, start, n_tokens, cfg):
+    def spec_verify_step(params, tokens, view, start, n_tokens, cfg):
         return verify_fn(params, tokens, view, cfg, start, n_tokens)
-    return jax.jit(fn, static_argnums=(5,), donate_argnums=_donate(2))
+    return jax.jit(spec_verify_step, static_argnums=(5,),
+                   donate_argnums=_donate(2))
 
 
 @dataclasses.dataclass
@@ -795,27 +800,46 @@ class Engine:
         """Occupied decode lanes (the router's load signal)."""
         return sum(s is not None for s in self._slots)
 
+    def _span(self, name: str, **args):
+        """A host span on this worker's scheduler row
+        (:meth:`~repro.runtime.telemetry.Telemetry.span`).  The phases
+        of a tick are ``engine.step`` > ``engine.housekeep``,
+        ``engine.admit``, ``engine.{prefill,decode}.prepare``,
+        ``engine.dispatch.{prefill,decode,verify}`` (the jitted call),
+        ``engine.wait.{prefill,decode,verify}`` and
+        ``engine.{prefill,decode}.commit`` > ``engine.retire``.  With the
+        page checksums and the drift probe off (the default) the host
+        waits on the device only inside an ``engine.wait.*`` span."""
+        return self.telemetry.span(name, self.worker_id, **args)
+
     def step(self) -> list[Completion]:
         """One scheduler tick: expire deadlines, audit checksums,
         admit, advance prefills by one chunk, decode once, retire.
         Returns the completions that finished during this tick."""
-        t_tick = self._clock()
         self._tick_no += 1
+        with self._span("engine.step", tick=self._tick_no):
+            return self._step()
+
+    def _step(self) -> list[Completion]:
+        t_tick = self._clock()
         self._tick_tokens = 0         # prefill + decode tokens this tick
         self._chaos_blocked = False
         if self.chaos is not None:
             delay = self.chaos.tick_delay()
             if delay > 0.0:
                 time.sleep(delay)
-        self._expire_deadlines()
-        self._audit_pages()
-        ec = self.engine_cfg
-        if ec.drift_check_every and self._tick_no % ec.drift_check_every == 0:
-            self._drift_check()
-        for slot in [s for s, until in self._quarantined.items()
-                     if until <= self._tick_no]:
-            del self._quarantined[slot]
-        self._admit()
+        with self._span("engine.housekeep"):
+            self._expire_deadlines()
+            self._audit_pages()
+            ec = self.engine_cfg
+            if (ec.drift_check_every
+                    and self._tick_no % ec.drift_check_every == 0):
+                self._drift_check()
+            for slot in [s for s, until in self._quarantined.items()
+                         if until <= self._tick_no]:
+                del self._quarantined[slot]
+        with self._span("engine.admit"):
+            self._admit()
         if (self._queue and all(s is None for s in self._slots)
                 and not self._chaos_blocked and not self._quarantined):
             raise RuntimeError(
@@ -847,8 +871,6 @@ class Engine:
                            finished=len(finished))
         if self.tracer.enabled:
             pid = self.worker_id
-            self.tracer.complete(pid, SCHED_TID, "tick", t_tick, t_end,
-                                 tick=self._tick_no)
             self.tracer.counter(pid, "queue_depth", t_end,
                                 depth=len(self._queue))
             self.tracer.counter(pid, "live_slots", t_end,
@@ -884,84 +906,93 @@ class Engine:
                                    + blocks * bs * n_kv * hd * 2)
 
     def _decode_tick(self, active) -> list[Completion]:
-        # grow any sequence whose next write crosses a block boundary —
-        # oldest first, so page pressure falls on the youngest (it is
-        # the one evicted/preempted if the free list runs dry)
-        for i, st in sorted(active, key=lambda t: t[1].seq_no):
-            if self._slots[i] is st:     # not preempted earlier this tick
-                self._grow(i)
-        active = [(i, s) for i, s in enumerate(self._slots)
-                  if s is not None and s.prefill_done]
-        if not active:
-            return []
-
-        # speculative path: when any slot has a prompt-lookup proposal
-        # this tick becomes ONE verification dispatch (draftless rows
-        # ride along as single-token steps); with no proposals anywhere
-        # fall through to the vanilla dispatch — an adversarial stream
-        # pays nothing for having speculation enabled
-        if self.drafter is not None:
-            drafts = self._draft(active)
-            if drafts:
-                return self._spec_tick(active, drafts)
-
         ec = self.engine_cfg
-        tokens = np.zeros((ec.num_slots, 1), np.int32)
-        active_mask = np.zeros((ec.num_slots,), bool)
-        pre_pos: dict[int, int] = {}    # write position, for checksums
-        for i, st in active:
-            tokens[i, 0] = st.next_token
-            active_mask[i] = True
-            pre_pos[i] = int(self.cache.lengths[i])
-            self._attn_accounting(1, pre_pos[i] + 1)
+        with self._span("engine.decode.prepare"):
+            # grow any sequence whose next write crosses a block
+            # boundary — oldest first, so page pressure falls on the
+            # youngest (it is the one evicted/preempted if the free list
+            # runs dry)
+            for i, st in sorted(active, key=lambda t: t[1].seq_no):
+                if self._slots[i] is st:  # not preempted earlier this tick
+                    self._grow(i)
+            active = [(i, s) for i, s in enumerate(self._slots)
+                      if s is not None and s.prefill_done]
+            if not active:
+                return []
+
+            # speculative path: when any slot has a prompt-lookup
+            # proposal this tick becomes ONE verification dispatch
+            # (draftless rows ride along as single-token steps); with no
+            # proposals anywhere fall through to the vanilla dispatch —
+            # an adversarial stream pays nothing for having speculation
+            # enabled
+            drafts = (self._draft(active) if self.drafter is not None
+                      else None)
+            if not drafts:
+                tokens = np.zeros((ec.num_slots, 1), np.int32)
+                active_mask = np.zeros((ec.num_slots,), bool)
+                pre_pos: dict[int, int] = {}  # write position (checksums)
+                for i, st in active:
+                    tokens[i, 0] = st.next_token
+                    active_mask[i] = True
+                    pre_pos[i] = int(self.cache.lengths[i])
+                    self._attn_accounting(1, pre_pos[i] + 1)
+                view = self.cache.view(cols=self._live_cols(active))
+                tokens, active_mask = (jnp.asarray(tokens),
+                                       jnp.asarray(active_mask))
+        if drafts:
+            return self._spec_tick(active, drafts)
 
         t0 = self._clock()
-        nxt_dev, ok_dev, view = self._decode(
-            self.params, self.cache.view(cols=self._live_cols(active)),
-            jnp.asarray(tokens), jnp.asarray(active_mask), self.cfg)
-        nxt = np.asarray(nxt_dev)   # blocks until the step is done
-        ok = np.array(ok_dev)       # writable: chaos may force a row low
+        with self._span("engine.dispatch.decode"):
+            nxt_dev, ok_dev, view = self._decode(
+                self.params, view, tokens, active_mask, self.cfg)
+        with self._span("engine.wait.decode"):
+            nxt = np.asarray(nxt_dev)   # blocks until the step is done
+            ok = np.array(ok_dev)       # writable: chaos may force a row low
+            lengths = np.asarray(view.lengths)
         t1 = self._clock()
         dt = t1 - t0
-        self.cache.update_pages(view)
-        # the device-computed lengths are the single source of truth
-        # for *decoding* slots; prefilling slots keep their host value
-        # (their lengths ride through the decode step unchanged)
-        self.cache.lengths[:] = np.asarray(view.lengths)
-        self.total_decode_steps += 1
-        if self.chaos is not None:
-            bad = self.chaos.nan_slot([i for i, _ in active])
-            if bad is not None:
-                ok[bad] = False     # identical path to a real device NaN
-        finished: list[Completion] = []
-        bs = ec.block_size
-        for i, st in active:
-            if not ok[i]:
-                # non-finite logits: fail THIS request, rest the lane,
-                # keep the batch running
-                self.nan_rows_detected += 1
-                self._quarantine(i)
-                self._fault(st, "nan_logits")
-                continue
-            st.decode_steps += 1
-            st.decode_s += dt
-            tok = int(nxt[i])
-            st.tokens.append(tok)
-            st.next_token = tok
-            self._tick_tokens += 1
-            if self.tracer.enabled:
-                # per-lane span on this worker's slot row + a stamp on
-                # the request's own timeline, every decode tick
-                self.tracer.complete(self.worker_id, lane_tid(i), "decode",
-                                     t0, t1, uid=st.request.uid, token=tok)
-                if st.trace is not None:
-                    st.trace.stamp("decode_tick", t1, slot=i)
-            if self._checksum:
-                page = int(self.cache.block_tables[i, pre_pos[i] // bs])
-                self._page_crc[page] = self.cache.page_checksum(page)
-            if self._should_stop(st):
-                finished.append(self._retire(i))
-        return finished
+        with self._span("engine.decode.commit"):
+            self.cache.update_pages(view)
+            # the device-computed lengths are the single source of truth
+            # for *decoding* slots; prefilling slots keep their host value
+            # (their lengths ride through the decode step unchanged)
+            self.cache.lengths[:] = lengths
+            self.total_decode_steps += 1
+            if self.chaos is not None:
+                bad = self.chaos.nan_slot([i for i, _ in active])
+                if bad is not None:
+                    ok[bad] = False     # identical path to a real device NaN
+            finished: list[Completion] = []
+            bs = ec.block_size
+            for i, st in active:
+                if not ok[i]:
+                    # non-finite logits: fail THIS request, rest the lane,
+                    # keep the batch running
+                    self.nan_rows_detected += 1
+                    self._quarantine(i)
+                    self._fault(st, "nan_logits")
+                    continue
+                st.decode_steps += 1
+                st.decode_s += dt
+                tok = int(nxt[i])
+                st.tokens.append(tok)
+                st.next_token = tok
+                self._tick_tokens += 1
+                if self.tracer.enabled:
+                    # per-lane span on this worker's slot row + a stamp on
+                    # the request's own timeline, every decode tick
+                    self.tracer.complete(self.worker_id, lane_tid(i), "decode",
+                                         t0, t1, uid=st.request.uid, token=tok)
+                    if st.trace is not None:
+                        st.trace.stamp("decode_tick", t1, slot=i)
+                if self._checksum:
+                    page = int(self.cache.block_tables[i, pre_pos[i] // bs])
+                    self._page_crc[page] = self.cache.page_checksum(page)
+                if self._should_stop(st):
+                    finished.append(self._retire(i))
+            return finished
 
     # ------------------------------------------------ speculative decode
     def _draft(self, active) -> dict[int, np.ndarray]:
@@ -1001,92 +1032,97 @@ class Engine:
         ec = self.engine_cfg
         bs = ec.block_size
         width = ec.spec_k + 1
-        toks = np.zeros((ec.num_slots, width), np.int32)
-        n_tok = np.zeros((ec.num_slots,), np.int32)
-        # idle rows: start = length with zero valid tokens ⇒ trash
-        # writes, zero attention (same parking trick as chunked prefill)
-        start = np.asarray(self.cache.lengths, np.int32).copy()
-        pre_pos: dict[int, int] = {}
-        cols_need = 1
-        for i, st in active:
-            d = drafts.get(i)
-            n = 1 + (len(d) if d is not None else 0)
-            toks[i, 0] = st.next_token
-            if d is not None:
-                toks[i, 1:1 + len(d)] = d
-                self.spec_proposed += len(d)
-            n_tok[i] = n
-            pre_pos[i] = int(self.cache.lengths[i])
-            self._attn_accounting(n, pre_pos[i] + n)
-            cols_need = max(cols_need, -(-(pre_pos[i] + n) // bs))
-        cols = min(self._pow2(cols_need), self.cache.max_blocks_per_seq)
+        with self._span("engine.decode.prepare"):
+            toks = np.zeros((ec.num_slots, width), np.int32)
+            n_tok = np.zeros((ec.num_slots,), np.int32)
+            # idle rows: start = length with zero valid tokens ⇒ trash
+            # writes, zero attention (same parking trick as chunked
+            # prefill)
+            start = np.asarray(self.cache.lengths, np.int32).copy()
+            pre_pos: dict[int, int] = {}
+            cols_need = 1
+            for i, st in active:
+                d = drafts.get(i)
+                n = 1 + (len(d) if d is not None else 0)
+                toks[i, 0] = st.next_token
+                if d is not None:
+                    toks[i, 1:1 + len(d)] = d
+                    self.spec_proposed += len(d)
+                n_tok[i] = n
+                pre_pos[i] = int(self.cache.lengths[i])
+                self._attn_accounting(n, pre_pos[i] + n)
+                cols_need = max(cols_need, -(-(pre_pos[i] + n) // bs))
+            cols = min(self._pow2(cols_need), self.cache.max_blocks_per_seq)
+            view = self.cache.view(cols=cols)
 
         t0 = self._clock()
         # host arrays go straight into the jitted call: pjit ingests
         # them on its C fast path, and three explicit device_puts per
         # tick are measurable against a sub-millisecond dispatch
-        g_dev, acc_dev, ok_dev, view = self._spec_verify(
-            self.params, toks, self.cache.view(cols=cols),
-            start, n_tok, self.cfg)
-        g = np.asarray(g_dev)       # blocks until the dispatch is done
-        acc = np.asarray(acc_dev)
-        ok = np.array(ok_dev)       # writable: chaos may force a row low
+        with self._span("engine.dispatch.verify"):
+            g_dev, acc_dev, ok_dev, view = self._spec_verify(
+                self.params, toks, view, start, n_tok, self.cfg)
+        with self._span("engine.wait.verify"):
+            g = np.asarray(g_dev)       # blocks until the dispatch is done
+            acc = np.asarray(acc_dev)
+            ok = np.array(ok_dev)       # writable: chaos may force a row low
         t1 = self._clock()
         dt = t1 - t0
-        self.cache.update_pages(view)
-        self.total_decode_steps += 1
-        self.spec_dispatches += 1
-        if self.chaos is not None:
-            bad = self.chaos.nan_slot([i for i, _ in active])
-            if bad is not None:
-                ok[bad] = False     # identical path to a real device NaN
-        finished: list[Completion] = []
-        for i, st in active:
-            if not ok[i]:
-                self.nan_rows_detected += 1
-                self._quarantine(i)
-                self._fault(st, "nan_logits")
-                continue
-            d = drafts.get(i)
-            a = int(acc[i]) if d is not None else 0
-            self.spec_accepted += a
-            committed = [int(t) for t in (d[:a] if d is not None else ())]
-            committed.append(int(g[i, a]))
-            stop = st.request.stop_token
-            if stop is not None and stop in committed:
-                # vanilla would have stopped AT the stop token: commit
-                # through it and drop the (correctly verified but now
-                # out-of-sequence) tokens behind it
-                committed = committed[:committed.index(stop) + 1]
-            st.decode_steps += 1
-            st.decode_s += dt
-            st.tokens.extend(committed)
-            st.next_token = committed[-1]
-            # the commit IS the rewind: only committed positions count;
-            # position len(committed) holds the still-unwritten KV slot
-            # of next_token, exactly the vanilla invariant
-            self.cache.lengths[i] = pre_pos[i] + len(committed)
-            self._tick_tokens += len(committed)
-            if self.tracer.enabled:
-                self.tracer.complete(self.worker_id, lane_tid(i),
-                                     "spec_decode", t0, t1,
-                                     uid=st.request.uid,
-                                     tokens=len(committed), accepted=a)
-                if st.trace is not None:
-                    st.trace.stamp(
-                        "spec_verify", t1, slot=i, accepted=a,
-                        proposed=(len(d) if d is not None else 0))
-            if self._checksum:
-                # every page the window touched, accepted or not: the
-                # rejected tail's bytes are live page content until
-                # overwritten, and the audit must track what is there
-                for c in range(pre_pos[i] // bs,
-                               (pre_pos[i] + int(n_tok[i]) - 1) // bs + 1):
-                    page = int(self.cache.block_tables[i, c])
-                    self._page_crc[page] = self.cache.page_checksum(page)
-            if self._should_stop(st):
-                finished.append(self._retire(i))
-        return finished
+        with self._span("engine.decode.commit"):
+            self.cache.update_pages(view)
+            self.total_decode_steps += 1
+            self.spec_dispatches += 1
+            if self.chaos is not None:
+                bad = self.chaos.nan_slot([i for i, _ in active])
+                if bad is not None:
+                    ok[bad] = False     # identical path to a real device NaN
+            finished: list[Completion] = []
+            for i, st in active:
+                if not ok[i]:
+                    self.nan_rows_detected += 1
+                    self._quarantine(i)
+                    self._fault(st, "nan_logits")
+                    continue
+                d = drafts.get(i)
+                a = int(acc[i]) if d is not None else 0
+                self.spec_accepted += a
+                committed = [int(t) for t in (d[:a] if d is not None else ())]
+                committed.append(int(g[i, a]))
+                stop = st.request.stop_token
+                if stop is not None and stop in committed:
+                    # vanilla would have stopped AT the stop token: commit
+                    # through it and drop the (correctly verified but now
+                    # out-of-sequence) tokens behind it
+                    committed = committed[:committed.index(stop) + 1]
+                st.decode_steps += 1
+                st.decode_s += dt
+                st.tokens.extend(committed)
+                st.next_token = committed[-1]
+                # the commit IS the rewind: only committed positions count;
+                # position len(committed) holds the still-unwritten KV slot
+                # of next_token, exactly the vanilla invariant
+                self.cache.lengths[i] = pre_pos[i] + len(committed)
+                self._tick_tokens += len(committed)
+                if self.tracer.enabled:
+                    self.tracer.complete(self.worker_id, lane_tid(i),
+                                         "spec_decode", t0, t1,
+                                         uid=st.request.uid,
+                                         tokens=len(committed), accepted=a)
+                    if st.trace is not None:
+                        st.trace.stamp(
+                            "spec_verify", t1, slot=i, accepted=a,
+                            proposed=(len(d) if d is not None else 0))
+                if self._checksum:
+                    # every page the window touched, accepted or not: the
+                    # rejected tail's bytes are live page content until
+                    # overwritten, and the audit must track what is there
+                    for c in range(pre_pos[i] // bs,
+                                   (pre_pos[i] + int(n_tok[i]) - 1) // bs + 1):
+                        page = int(self.cache.block_tables[i, c])
+                        self._page_crc[page] = self.cache.page_checksum(page)
+                if self._should_stop(st):
+                    finished.append(self._retire(i))
+            return finished
 
     def stream(self, handle: int) -> Iterator[int]:
         """Yield tokens for one request as the engine produces them,
@@ -1434,21 +1470,22 @@ class Engine:
         inserted into the trie (keyed by the token content they hold)
         instead of freed — the next request sharing the prefix skips
         both the FLOPs and the HBM writes."""
-        st = self._slots[slot]
-        self._slots[slot] = None
-        if self.prefix is None:
-            self.cache.release_slot(slot)
-        else:
-            content_len = int(self.cache.lengths[slot])
-            content = st.full_prompt()[:content_len]
-            shared = set(self.cache.slot_shared[slot])
-            blocks = self.cache.clear_slot(slot)
-            self.prefix.insert(content, blocks, shared)
-            self.prefix.unpin(st.pinned)
-            st.pinned = []
-        st.status = _FINISHED
-        self._finish_trace(st, st.term)
-        return st.completion()
+        with self._span("engine.retire"):
+            st = self._slots[slot]
+            self._slots[slot] = None
+            if self.prefix is None:
+                self.cache.release_slot(slot)
+            else:
+                content_len = int(self.cache.lengths[slot])
+                content = st.full_prompt()[:content_len]
+                shared = set(self.cache.slot_shared[slot])
+                blocks = self.cache.clear_slot(slot)
+                self.prefix.insert(content, blocks, shared)
+                self.prefix.unpin(st.pinned)
+                st.pinned = []
+            st.status = _FINISHED
+            self._finish_trace(st, st.term)
+            return st.completion()
 
     def _export_handoff(self, slot: int, st: _SeqState) -> None:
         """Prefill role: the request's last chunk landed — copy its KV
@@ -1815,99 +1852,106 @@ class Engine:
             return []
         ec = self.engine_cfg
         bs = ec.block_size
-        remaining = max(len(st.full_prompt()) - st.prefix_len - st.prefill_pos
-                        for _, st in pref)
-        w = self._chunk_width(remaining)
-        toks = np.zeros((ec.num_slots, w), np.int32)
-        # non-prefilling rows: start = length ⇒ zero valid tokens
-        start = np.asarray(self.cache.lengths, np.int32).copy()
-        takes: dict[int, int] = {}
-        cols_need = 1
-        for i, st in pref:
-            prompt = st.full_prompt()
-            s0 = st.prefix_len + st.prefill_pos
-            take = min(w, len(prompt) - s0)
-            toks[i, :take] = prompt[s0:s0 + take]
-            start[i] = s0
-            takes[i] = take
-            self.prefill_tokens_computed += take
-            self._tick_tokens += take
-            self._attn_accounting(take, s0 + take)
-            cols_need = max(cols_need, -(-(s0 + take) // bs))
-        self.prefill_batches += 1
-        cols = min(self._pow2(cols_need), self.cache.max_blocks_per_seq)
+        with self._span("engine.prefill.prepare"):
+            remaining = max(len(st.full_prompt()) - st.prefix_len
+                            - st.prefill_pos for _, st in pref)
+            w = self._chunk_width(remaining)
+            toks = np.zeros((ec.num_slots, w), np.int32)
+            # non-prefilling rows: start = length ⇒ zero valid tokens
+            start = np.asarray(self.cache.lengths, np.int32).copy()
+            takes: dict[int, int] = {}
+            cols_need = 1
+            for i, st in pref:
+                prompt = st.full_prompt()
+                s0 = st.prefix_len + st.prefill_pos
+                take = min(w, len(prompt) - s0)
+                toks[i, :take] = prompt[s0:s0 + take]
+                start[i] = s0
+                takes[i] = take
+                self.prefill_tokens_computed += take
+                self._tick_tokens += take
+                self._attn_accounting(take, s0 + take)
+                cols_need = max(cols_need, -(-(s0 + take) // bs))
+            self.prefill_batches += 1
+            # every row computes the full width, prefilling or not
+            self.prefill_dispatched_tokens += ec.num_slots * w
+            cols = min(self._pow2(cols_need), self.cache.max_blocks_per_seq)
+            view = self.cache.view(cols=cols)
+            toks_dev, start_dev = jnp.asarray(toks), jnp.asarray(start)
 
         t0 = self._clock()
-        nxt_dev, ok_dev, view = self._prefill(
-            self.params, jnp.asarray(toks), self.cache.view(cols=cols),
-            jnp.asarray(start), self.cfg)
-        nxt = np.asarray(nxt_dev)   # blocks until the dispatch is done
-        ok = np.array(ok_dev)       # writable: chaos may force a row low
+        with self._span("engine.dispatch.prefill"):
+            nxt_dev, ok_dev, view = self._prefill(
+                self.params, toks_dev, view, start_dev, self.cfg)
+        with self._span("engine.wait.prefill"):
+            nxt = np.asarray(nxt_dev)   # blocks until the dispatch is done
+            ok = np.array(ok_dev)       # writable: chaos may force a row low
         t1 = self._clock()
         dt = t1 - t0
-        self.cache.update_pages(view)
+        with self._span("engine.prefill.commit"):
+            self.cache.update_pages(view)
 
-        # pages this dispatch wrote, recorded per-row BEFORE retiring /
-        # faulting mutates the block tables
-        row_pages: dict[int, list[int]] = {}
-        if self._checksum:
+            # pages this dispatch wrote, recorded per-row BEFORE
+            # retiring / faulting mutates the block tables
+            row_pages: dict[int, list[int]] = {}
+            if self._checksum:
+                for i, st in pref:
+                    s0, take = int(start[i]), takes[i]
+                    if take:
+                        written = range(s0 // bs, (s0 + take - 1) // bs + 1)
+                        row_pages[i] = [int(self.cache.block_tables[i, c])
+                                        for c in written]
+            # only rows COMPLETING their prompt this tick consume
+            # logits — chaos (like a real device NaN) can only hit those
+            completing = [i for i, st in pref
+                          if st.prefix_len + st.prefill_pos + takes[i]
+                          >= len(st.full_prompt())
+                          and st.request.max_new_tokens > 0]
+            if self.chaos is not None:
+                bad = self.chaos.nan_slot(completing)
+                if bad is not None:
+                    ok[bad] = False
+            finished: list[Completion] = []
+            faulted: set[int] = set()
             for i, st in pref:
-                s0, take = int(start[i]), takes[i]
-                if take:
-                    row_pages[i] = [int(self.cache.block_tables[i, c])
-                                    for c in range(s0 // bs,
-                                                   (s0 + take - 1) // bs + 1)]
-        # only rows COMPLETING their prompt this tick consume logits —
-        # chaos (like a real device NaN) can only hit those
-        completing = [i for i, st in pref
-                      if st.prefix_len + st.prefill_pos + takes[i]
-                      >= len(st.full_prompt())
-                      and st.request.max_new_tokens > 0]
-        if self.chaos is not None:
-            bad = self.chaos.nan_slot(completing)
-            if bad is not None:
-                ok[bad] = False
-        finished: list[Completion] = []
-        faulted: set[int] = set()
-        for i, st in pref:
-            st.prefill_s += dt      # coalesced rows share the stamp
-            st.prefill_pos += takes[i]
-            if self.tracer.enabled and takes[i]:
-                self.tracer.complete(self.worker_id, lane_tid(i),
-                                     "prefill_chunk", t0, t1,
-                                     uid=st.request.uid, tokens=takes[i])
-                if st.trace is not None:
-                    st.trace.stamp("prefill_chunk", t1, slot=i,
-                                   tokens=takes[i])
-            if st.prefix_len + st.prefill_pos < len(st.full_prompt()):
-                continue            # more chunks to go
-            if i in completing and not ok[i]:
-                self.nan_rows_detected += 1
-                self._quarantine(i)
-                self._fault(st, "nan_logits")
-                faulted.add(i)
-                continue
-            st.prefill_done = True
-            r = st.request
-            if r.max_new_tokens > 0 and len(st.tokens) < r.max_new_tokens:
-                tok = int(nxt[i])
-                st.tokens.append(tok)
-                st.next_token = tok
-            if st.first_token_t is None and st.tokens:
-                st.first_token_t = self._clock()
-                if st.trace is not None:
-                    st.trace.stamp("first_token", st.first_token_t)
-            if self._should_stop(st):
-                finished.append(self._retire(i))
-            elif self.engine_cfg.role == "prefill":
-                # disaggregation: this worker's job ends at the first
-                # token — export the KV pages instead of decoding
-                self._export_handoff(i, st)
-        for i, pages in row_pages.items():
-            if i not in faulted:    # a faulted row's pages were freed
-                for page in pages:
-                    self._page_crc[page] = self.cache.page_checksum(page)
-        return finished
+                st.prefill_s += dt      # coalesced rows share the stamp
+                st.prefill_pos += takes[i]
+                if self.tracer.enabled and takes[i]:
+                    self.tracer.complete(self.worker_id, lane_tid(i),
+                                         "prefill_chunk", t0, t1,
+                                         uid=st.request.uid, tokens=takes[i])
+                    if st.trace is not None:
+                        st.trace.stamp("prefill_chunk", t1, slot=i,
+                                       tokens=takes[i])
+                if st.prefix_len + st.prefill_pos < len(st.full_prompt()):
+                    continue            # more chunks to go
+                if i in completing and not ok[i]:
+                    self.nan_rows_detected += 1
+                    self._quarantine(i)
+                    self._fault(st, "nan_logits")
+                    faulted.add(i)
+                    continue
+                st.prefill_done = True
+                r = st.request
+                if r.max_new_tokens > 0 and len(st.tokens) < r.max_new_tokens:
+                    tok = int(nxt[i])
+                    st.tokens.append(tok)
+                    st.next_token = tok
+                if st.first_token_t is None and st.tokens:
+                    st.first_token_t = self._clock()
+                    if st.trace is not None:
+                        st.trace.stamp("first_token", st.first_token_t)
+                if self._should_stop(st):
+                    finished.append(self._retire(i))
+                elif self.engine_cfg.role == "prefill":
+                    # disaggregation: this worker's job ends at the first
+                    # token — export the KV pages instead of decoding
+                    self._export_handoff(i, st)
+            for i, pages in row_pages.items():
+                if i not in faulted:    # a faulted row's pages were freed
+                    for page in pages:
+                        self._page_crc[page] = self.cache.page_checksum(page)
+            return finished
 
 
 # Engine counters live in the metrics registry — ONE store with stable
@@ -1923,6 +1967,9 @@ _ENGINE_COUNTERS = {
         ("engine.prefill.tokens", "prompt tokens actually computed"),
     "prefill_batches":
         ("engine.prefill.chunks", "chunked prefill dispatches issued"),
+    "prefill_dispatched_tokens":
+        ("engine.prefill.dispatched_tokens",
+         "rows × width computed per prefill dispatch"),
     "preemptions":
         ("engine.sched.preemptions", "sequences preempted for pages"),
     "admission_reorders":

@@ -30,8 +30,13 @@ pieces, shared by every worker in a process:
   request), counter tracks for queue depth / live slots / free pages
   / tok-s, and flow arrows linking a handoff's export to its import.
   Load the file in https://ui.perfetto.dev or ``chrome://tracing``.
-  A JSONL sink (:meth:`MetricsRegistry.dump_jsonl`,
-  :meth:`Tracer.write_jsonl`) serves machine consumers.
+  A JSONL sink (:meth:`MetricsRegistry.dump_jsonl`) serves machine
+  consumers.
+- **host spans on the profiler's clock** (:meth:`Telemetry.span`): one
+  call site, two sinks.  Every span enters a
+  ``jax.profiler.TraceAnnotation``, so a profiler session holds it on
+  the same clock as the device ops it brackets; with tracing armed it
+  is also a complete event in the Chrome trace.
 - a **flight recorder** (:class:`FlightRecorder`): a bounded ring of
   the last N per-tick records (queue depth, live slots, free pages,
   tokens, tick latency) that the engine dumps alongside the chaos
@@ -47,10 +52,11 @@ trace to human time.  Workers sharing one ``Telemetry`` share one
 clock, which is what makes handoff-crossing spans provably monotonic.
 
 Overhead budget: with tracing off (the default) the cost is counter
-increments — the same integer adds the ad-hoc dicts paid.  With
-tracing on, each event is one dict append; the bench row
-``telemetry/trace_overhead_frac`` asserts the traced ``disagg``
-scenario stays within 5% tok/s of untraced.
+increments plus one ``TraceAnnotation`` enter and exit per span (about
+a microsecond on the host, about fifteen spans a scheduler tick); with
+tracing on, each event is one dict append.  What the spans cost on a
+TPU, with and without a profiler session, is measured by the serving
+benchmark and recorded in PERF.md (section 6, the tracing findings).
 """
 
 from __future__ import annotations
@@ -60,6 +66,8 @@ import json
 import time
 from collections import deque
 from typing import Callable, Iterable
+
+import jax
 
 from repro.runtime.fault_tolerance import LatencyTracker
 
@@ -458,14 +466,6 @@ class Tracer:
                 json.dump(doc, f)
         return doc
 
-    def write_jsonl(self, path: str) -> int:
-        """Stream every event (one JSON object per line) — the sink for
-        consumers that don't want the whole document in memory."""
-        with open(path, "w") as f:
-            for ev in self._metadata_events() + self.events:
-                f.write(json.dumps(ev) + "\n")
-        return len(self.events)
-
 
 # -------------------------------------------------------- flight recorder
 
@@ -493,6 +493,31 @@ class FlightRecorder:
         return len(self._ring)
 
 
+# ------------------------------------------------------------ host spans
+
+
+class _Span:
+    """A ``TraceAnnotation`` that is also a complete event in a
+    tracer: what :meth:`Telemetry.span` returns while tracing is on."""
+
+    __slots__ = ("_tracer", "_ann", "_name", "_pid", "_args", "_t0")
+
+    def __init__(self, tracer: Tracer, ann, name: str, pid: int,
+                 args: dict):
+        self._tracer, self._ann = tracer, ann
+        self._name, self._pid, self._args = name, pid, args
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = self._tracer.clock()
+        return self
+
+    def __exit__(self, *exc):
+        self._tracer.complete(self._pid, SCHED_TID, self._name, self._t0,
+                              self._tracer.clock(), **self._args)
+        return self._ann.__exit__(*exc)
+
+
 # ------------------------------------------------------------- the bundle
 
 
@@ -515,6 +540,19 @@ class Telemetry:
     @property
     def tracing(self) -> bool:
         return self.tracer.enabled
+
+    def span(self, name: str, pid: int = 0, **args):
+        """A context manager around one phase of host work.  It always
+        enters ``jax.profiler.TraceAnnotation(name)``, which a profiler
+        session records on the clock of the device ops; while tracing
+        is armed it also adds a complete event named ``name`` on worker
+        ``pid``'s scheduler row of the Chrome trace, with ``args``.
+        With no profiler session and tracing off it costs one
+        annotation enter and exit."""
+        ann = jax.profiler.TraceAnnotation(name)
+        if not self.tracer.enabled:
+            return ann
+        return _Span(self.tracer, ann, name, pid, args)
 
     def finish_trace(self, trace: Trace) -> None:
         """Archive a finished request trace.  Only while tracing is

@@ -17,6 +17,7 @@ The load-bearing invariants:
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -205,7 +206,23 @@ class TestTracer:
         assert {m["name"] for m in metas} == {"process_name",
                                               "thread_name"}
         assert json.loads(p.read_text()) == doc      # file round-trips
-        assert tr.write_jsonl(str(tmp_path / "t.jsonl")) == 1
+
+    def test_span_is_one_complete_event_when_tracing(self):
+        tel = Telemetry(tracing=True)
+        with tel.span("engine.admit", 3, tick=7):
+            pass
+        (ev,) = tel.tracer.events
+        assert (ev["ph"], ev["pid"], ev["tid"], ev["name"]) == \
+            ("X", 3, SCHED_TID, "engine.admit")
+        assert ev["args"] == {"tick": 7} and ev["dur"] >= 0
+
+    def test_span_untraced_is_only_an_annotation(self):
+        tel = Telemetry()
+        span = tel.span("engine.admit", 3)
+        assert isinstance(span, jax.profiler.TraceAnnotation)
+        with span:
+            pass
+        assert tel.tracer.events == []
 
     def test_flow_ids_are_unique_per_export(self):
         tr = Tracer(enabled=True)
@@ -307,6 +324,19 @@ class TestEngineTracing:
         st = validate_chrome_trace(doc)
         assert st["requests"] == len(reqs)           # one span per uid
         assert st["spans"] > 0 and st["tracks"] > 1
+        # the scheduler row holds one engine.step span a tick, with the
+        # tick's phases nested inside it (validated above)
+        sched = [e for e in doc["traceEvents"] if e["ph"] == "X"
+                 and (e["pid"], e["tid"]) == (0, SCHED_TID)]
+        steps = [e for e in sched if e["name"] == "engine.step"]
+        assert [e["args"]["tick"] for e in steps] == \
+            list(range(1, eng.fault_stats()["ticks"] + 1))
+        assert {"engine.housekeep", "engine.admit", "engine.prefill.prepare",
+                "engine.dispatch.prefill", "engine.wait.prefill",
+                "engine.prefill.commit", "engine.decode.prepare",
+                "engine.dispatch.decode", "engine.wait.decode",
+                "engine.decode.commit", "engine.retire"} <= \
+            {e["name"] for e in sched}
 
     def test_untraced_engine_archives_nothing(self):
         cfg = tiny_cfg()
