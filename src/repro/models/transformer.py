@@ -221,8 +221,42 @@ def decode_step(params, cache, tokens: jax.Array, cfg: ModelConfig):
 
 def _scatter_token_kv(pages, new, blk_idx, off):
     """Write one token's KV per sequence into its page.
-    pages [N, bs, n_kv, hd]; new [B, n_kv, hd]; blk_idx/off [B]."""
+    pages [P, bs, n_kv, hd]; new [B, n_kv, hd]; blk_idx/off [B]."""
     return pages.at[blk_idx, off].set(new.astype(pages.dtype))
+
+
+def _scan_paged(body, carry, params, view, cfg):
+    """Run ``body`` over the layers with the page pools as scan carry.
+
+    Each pool ``[L, N, bs, n_kv, hd]`` is viewed as one flat page array
+    ``[L*N, bs, n_kv, hd]`` (only the major dims merge, so the reshape
+    is a bitcast) and carried through the scan: every layer scatters
+    its new K/V into the flat pool in place.  Handed to the scan as
+    per-layer ``xs`` instead, each layer's whole pool would be sliced
+    out, updated and stacked back, and the stacked result copied once
+    more: the whole pool moved every step to write a few pages.
+
+    ``body(carry, blk_params, k_pages, v_pages, base)`` gets the flat
+    pools and ``base = layer * N``: the layer's page ``p`` is flat page
+    ``base + p`` (its trash page is ``base``), so scatter targets and
+    block tables are offset by ``base``.  It returns ``(carry, k_pages,
+    v_pages)``.  Returns ``(carry, view)`` with the updated pools."""
+    n_layers, n_pages = view.k_pages.shape[:2]
+
+    def flat(pages):
+        return pages.reshape(n_layers * n_pages, *pages.shape[2:])
+
+    def step(c, layer_in):
+        inner, k_pages, v_pages = c
+        blk_params, layer = layer_in
+        return body(inner, blk_params, k_pages, v_pages,
+                    layer * n_pages), None
+
+    (carry, k_pages, v_pages), _ = scan_blocks(
+        step, (carry, flat(view.k_pages), flat(view.v_pages)),
+        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)), cfg)
+    return carry, view._replace(k_pages=k_pages.reshape(view.k_pages.shape),
+                                v_pages=v_pages.reshape(view.v_pages.shape))
 
 
 def prefill_into_cache(
@@ -292,26 +326,25 @@ def prefill_into_cache(
                      0)                                       # trash page
     off = jnp.where(tok_ok, positions % bs, 0)
 
-    def body(carry, layer_in):
+    def body(carry, blk_params, k_pages, v_pages, base):
         x, aux = carry
-        blk_params, k_pages_l, v_pages_l = layer_in
         aq = blk_params.get("act_q")
         h = L.apply_norm(blk_params["ln1"], x, cfg)
         k_new, v_new = L.self_kv(blk_params["attn"], h, cfg, positions,
                                  act_q=aq)
-        if k_pages_l.dtype == jnp.uint8:
+        if k_pages.dtype == jnp.uint8:
             # codes-mode cache: quantize-at-write through the per-head
             # attn_k/attn_v metas (a u8 page stores DNA-TEQ codes, and
             # a raw astype would bit-truncate floats into junk codes)
             k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
-        k_pages_l = k_pages_l.at[page, off].set(
-            k_new.astype(k_pages_l.dtype))
-        v_pages_l = v_pages_l.at[page, off].set(
-            v_new.astype(v_pages_l.dtype))
+        k_pages = k_pages.at[base + page, off].set(
+            k_new.astype(k_pages.dtype))
+        v_pages = v_pages.at[base + page, off].set(
+            v_new.astype(v_pages.dtype))
         attn = L.mha_prefill_paged(blk_params["attn"], h, cfg, positions,
-                                   k_pages_l, v_pages_l,
-                                   view.block_tables, start, kv_lens,
-                                   act_q=aq)
+                                   k_pages, v_pages,
+                                   view.block_tables + base, start,
+                                   kv_lens, act_q=aq)
         x = x + attn
         h = L.apply_norm(blk_params["ln2"], x, cfg)
         if cfg.is_moe:
@@ -319,17 +352,16 @@ def prefill_into_cache(
         else:
             y, a = (L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq),
                     jnp.zeros((), jnp.float32))
-        return (L.constrain_act(x + y), aux + a), (k_pages_l, v_pages_l)
+        return (L.constrain_act(x + y), aux + a), k_pages, v_pages
 
-    (x, _aux), (ks, vs) = scan_blocks(
-        body, (x, jnp.zeros((), jnp.float32)),
-        (params["blocks"], view.k_pages, view.v_pages), cfg)
+    (x, _aux), view = _scan_paged(
+        body, (x, jnp.zeros((), jnp.float32)), params, view, cfg)
     x = L.apply_norm(params["ln_f"], x, cfg)
     idx = jnp.clip(view.lengths - 1 - start, 0, s - 1)
     x_last = jnp.take_along_axis(
         x, idx[:, None, None].astype(jnp.int32), axis=1)      # [B, 1, D]
     logits = L.logits_fn(params, x_last, cfg)
-    return logits, view._replace(k_pages=ks, v_pages=vs)
+    return logits, view
 
 
 def decode_step_paged(params, view, tokens: jax.Array, active: jax.Array,
@@ -358,36 +390,36 @@ def decode_step_paged(params, view, tokens: jax.Array, active: jax.Array,
     off = jnp.where(active, pos % bs, 0)
     attn_lengths = jnp.where(active, pos + 1, 0).astype(jnp.int32)
 
-    def body(carry, layer_in):
+    def body(carry, blk_params, k_pages, v_pages, base):
         x, = carry
-        blk_params, k_pages_l, v_pages_l = layer_in
         aq = blk_params.get("act_q")
         h = L.apply_norm(blk_params["ln1"], x, cfg)
         k_new, v_new = L.self_kv(blk_params["attn"], h, cfg, positions,
                                  act_q=aq)
-        if k_pages_l.dtype == jnp.uint8:
+        if k_pages.dtype == jnp.uint8:
             # codes-mode cache: quantize-at-write (see prefill body)
             k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
-        k_pages_l = _scatter_token_kv(k_pages_l, k_new[:, 0], blk_idx, off)
-        v_pages_l = _scatter_token_kv(v_pages_l, v_new[:, 0], blk_idx, off)
+        k_pages = _scatter_token_kv(k_pages, k_new[:, 0], base + blk_idx,
+                                    off)
+        v_pages = _scatter_token_kv(v_pages, v_new[:, 0], base + blk_idx,
+                                    off)
         attn = L.mha_decode_paged(blk_params["attn"], h, cfg, positions,
-                                  k_pages_l, v_pages_l, view.block_tables,
-                                  attn_lengths, act_q=aq)
+                                  k_pages, v_pages,
+                                  view.block_tables + base, attn_lengths,
+                                  act_q=aq)
         x = x + attn
         h = L.apply_norm(blk_params["ln2"], x, cfg)
         if cfg.is_moe:
             y, _ = M.apply_moe(blk_params["moe"], h, cfg, act_q=aq)
         else:
             y = L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq)
-        return (L.constrain_act(x + y),), (k_pages_l, v_pages_l)
+        return (L.constrain_act(x + y),), k_pages, v_pages
 
-    (x,), (ks, vs) = scan_blocks(
-        body, (x,), (params["blocks"], view.k_pages, view.v_pages), cfg)
+    (x,), view = _scan_paged(body, (x,), params, view, cfg)
     x = L.apply_norm(params["ln_f"], x, cfg)
     logits = L.logits_fn(params, x, cfg)
     new_lengths = jnp.where(active, pos + 1, pos).astype(jnp.int32)
-    return logits, view._replace(k_pages=ks, v_pages=vs,
-                                 lengths=new_lengths)
+    return logits, view._replace(lengths=new_lengths)
 
 
 def spec_verify_into_cache(
@@ -446,24 +478,23 @@ def spec_verify_into_cache(
                      0)                                       # trash page
     off = jnp.where(tok_ok, positions % bs, 0)
 
-    def body(carry, layer_in):
+    def body(carry, blk_params, k_pages, v_pages, base):
         x, aux = carry
-        blk_params, k_pages_l, v_pages_l = layer_in
         aq = blk_params.get("act_q")
         h = L.apply_norm(blk_params["ln1"], x, cfg)
         k_new, v_new = L.self_kv(blk_params["attn"], h, cfg, positions,
                                  act_q=aq)
-        if k_pages_l.dtype == jnp.uint8:
+        if k_pages.dtype == jnp.uint8:
             # codes-mode cache: quantize-at-write (see prefill body)
             k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
-        k_pages_l = k_pages_l.at[page, off].set(
-            k_new.astype(k_pages_l.dtype))
-        v_pages_l = v_pages_l.at[page, off].set(
-            v_new.astype(v_pages_l.dtype))
+        k_pages = k_pages.at[base + page, off].set(
+            k_new.astype(k_pages.dtype))
+        v_pages = v_pages.at[base + page, off].set(
+            v_new.astype(v_pages.dtype))
         attn = L.mha_prefill_paged(blk_params["attn"], h, cfg, positions,
-                                   k_pages_l, v_pages_l,
-                                   view.block_tables, start, kv_lens,
-                                   act_q=aq)
+                                   k_pages, v_pages,
+                                   view.block_tables + base, start,
+                                   kv_lens, act_q=aq)
         x = x + attn
         h = L.apply_norm(blk_params["ln2"], x, cfg)
         if cfg.is_moe:
@@ -471,11 +502,10 @@ def spec_verify_into_cache(
         else:
             y, a = (L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq),
                     jnp.zeros((), jnp.float32))
-        return (L.constrain_act(x + y), aux + a), (k_pages_l, v_pages_l)
+        return (L.constrain_act(x + y), aux + a), k_pages, v_pages
 
-    (x, _aux), (ks, vs) = scan_blocks(
-        body, (x, jnp.zeros((), jnp.float32)),
-        (params["blocks"], view.k_pages, view.v_pages), cfg)
+    (x, _aux), view = _scan_paged(
+        body, (x, jnp.zeros((), jnp.float32)), params, view, cfg)
     x = L.apply_norm(params["ln_f"], x, cfg)
     logits = L.logits_fn(params, x, cfg)                      # [B, S, V]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # [B, S]
@@ -488,8 +518,7 @@ def spec_verify_into_cache(
     finite = jnp.all(jnp.isfinite(logits), axis=-1)           # [B, S]
     at_valid = jnp.arange(s)[None, :] < valid[:, None]
     ok = jnp.all(jnp.where(at_valid, finite, True), axis=1)   # [B]
-    return greedy, accept.astype(jnp.int32), ok, \
-        view._replace(k_pages=ks, v_pages=vs)
+    return greedy, accept.astype(jnp.int32), ok, view
 
 
 # ----------------------------------------------------- act calibration --

@@ -242,9 +242,11 @@ _QUEUED, _RUNNING, _FINISHED = "queued", "running", "finished"
 # happens *inside* the jitted call: one dispatch per scheduler tick
 # instead of per-op host round-trips (slice + argmax) on the hot path.
 # Off-CPU the view (page pools) is donated: the host adopts the returned
-# arrays via update_pages, so the inputs are dead and XLA can scatter
-# the new token's KV in place instead of copying the whole pool each
-# tick.  (CPU lacks donation support — measured strictly slower there.)
+# arrays via update_pages, so the inputs are dead and, with the pools
+# carried through the layer scan (``transformer._scan_paged``), XLA
+# scatters the new tokens' KV in place instead of copying the whole pool
+# each tick.  (CPU lacks donation support — measured strictly slower
+# there.)
 # Each inner function's name names its compiled program in a profiler
 # trace: ``jit_prefill_step``, ``jit_decode_step``, ``jit_spec_verify_step``.
 

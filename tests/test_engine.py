@@ -21,11 +21,14 @@ from repro.kernels.decode_gqa import (
     decode_gqa_paged_ref,
 )
 from repro.models import api as mapi
+from repro.models import layers as L
+from repro.runtime.calibration import calibrate_act_quant
 from repro.runtime.engine import Engine, EngineConfig, Request
 from repro.runtime.paged_cache import (
     TRASH_PAGE,
     BlockAllocator,
     PagedKVCache,
+    PagedView,
 )
 from repro.runtime.server import InferenceServer
 
@@ -215,6 +218,119 @@ class TestPagedModelPath:
             cfg.num_layers, 12, cfg.num_kv_heads, -1)[:, :plen]
         ref_k = np.asarray(ref_cache["k"])[:, 0, :plen]
         np.testing.assert_allclose(got_k, ref_k, rtol=2e-5, atol=2e-5)
+
+    # Four slots over pages of 4: slot 1 reads page 1 as a prefix shared
+    # with slot 0 through the table; slot 2 is idle.  Per step: the
+    # chunk width, each row's first position, and a count per row (the
+    # prompt length for prefill, the window's valid tokens for verify;
+    # 0 marks an idle decode row).
+    TABLES = [[1, 2, 3, 0], [1, 4, 0, 0], [0, 0, 0, 0], [5, 0, 0, 0]]
+    STEPS = {"decode": (1, [9, 6, 0, 2], [9, 6, 0, 2]),
+             "prefill": (8, [4, 4, 0, 0], [9, 6, 0, 2]),
+             "verify": (4, [9, 6, 0, 2], [3, 2, 0, 1])}
+
+    @pytest.mark.parametrize("kv", ["f8", "codes"])
+    @pytest.mark.parametrize("step", ["decode", "prefill", "verify"])
+    def test_step_writes_only_addressed_cells(self, step, kv, tmp_path):
+        """A paged step writes each new K/V at (layer, page, offset) of
+        the block table and nowhere else: every other cell of both pools
+        is bit-identical after the step (the trash page's first offset
+        absorbs idle rows and padding), and the written cells hold what
+        a layer-by-layer reference on each layer's own pool writes."""
+        cfg = tiny_cfg(num_layers=3)
+        api = mapi.get_model(cfg)
+        params = api.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+        if kv == "codes":
+            params, _ = calibrate_act_quant(
+                api, params, cfg, bits=7, path=str(tmp_path / "calib.json"))
+        dtype = jnp.uint8 if kv == "codes" else jnp.float8_e4m3fn
+        width, start, n = self.STEPS[step]
+        rng = np.random.default_rng(7)
+        n_layers, n_pages, bs = cfg.num_layers, 8, 4
+        shape = (n_layers, n_pages, bs, cfg.num_kv_heads,
+                 cfg.resolved_head_dim)
+        if kv == "codes":
+            pools = [jnp.asarray(rng.integers(0, 256, shape), jnp.uint8)
+                     for _ in range(2)]
+        else:
+            pools = [jnp.asarray(rng.normal(size=shape), jnp.float32)
+                     .astype(dtype) for _ in range(2)]
+        tables = jnp.asarray(self.TABLES, jnp.int32)
+        start = jnp.asarray(start, jnp.int32)
+        n = jnp.asarray(n, jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, width)),
+                             jnp.int32)
+        if step == "decode":
+            active = n > 0
+            view = PagedView(*pools, tables, start)
+            _, out = jax.jit(api.decode_step_paged, static_argnums=4)(
+                params, view, tokens, active, cfg)
+            valid = active.astype(jnp.int32)
+        elif step == "prefill":
+            view = PagedView(*pools, tables, n)
+            _, out = jax.jit(api.prefill_into_cache, static_argnums=3)(
+                params, tokens, view, cfg, start)
+            valid = jnp.clip(n - start, 0, width)
+        else:
+            view = PagedView(*pools, tables, start)
+            *_, out = jax.jit(api.spec_verify_into_cache,
+                              static_argnums=3)(
+                params, tokens, view, cfg, start, n)
+            valid = n
+
+        positions = start[:, None] + jnp.arange(width)[None, :]
+        ok = jnp.arange(width)[None, :] < valid[:, None]
+        page = jnp.where(ok, jnp.take_along_axis(
+            tables, positions // bs, axis=1), 0)
+        off = jnp.where(ok, positions % bs, 0)
+        kv_lens = jnp.where(valid > 0, start + valid, 0)
+
+        @jax.jit
+        def per_layer(params, k_pages, v_pages):
+            x = L.embed_tokens(params["embed"], tokens, cfg)
+            ks, vs = [], []
+            for l in range(n_layers):
+                p = jax.tree_util.tree_map(lambda a: a[l], params["blocks"])
+                aq = p.get("act_q")
+                h = L.apply_norm(p["ln1"], x, cfg)
+                k_new, v_new = L.self_kv(p["attn"], h, cfg, positions,
+                                         act_q=aq)
+                if kv == "codes":
+                    k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
+                k = k_pages[l].at[page, off].set(k_new.astype(dtype))
+                v = v_pages[l].at[page, off].set(v_new.astype(dtype))
+                if step == "decode":
+                    attn = L.mha_decode_paged(p["attn"], h, cfg, positions,
+                                              k, v, tables, kv_lens,
+                                              act_q=aq)
+                else:
+                    attn = L.mha_prefill_paged(p["attn"], h, cfg,
+                                               positions, k, v, tables,
+                                               start, kv_lens, act_q=aq)
+                x = x + attn
+                x = x + L.apply_mlp(p["mlp"], L.apply_norm(p["ln2"], x, cfg),
+                                    cfg, act_q=aq)
+                ks.append(k)
+                vs.append(v)
+            return jnp.stack(ks), jnp.stack(vs)
+
+        ref = per_layer(params, *pools)
+        written = np.zeros(shape[:3], bool)
+        for b, s in zip(*np.nonzero(np.asarray(ok))):
+            written[:, int(page[b, s]), int(off[b, s])] = True
+        assert written.sum() == n_layers * int(valid.sum())
+        trash = np.zeros(shape[:3], bool)
+        trash[:, TRASH_PAGE, 0] = True
+        bits = lambda a: np.asarray(a).view(np.uint8)
+        for before, after, want in zip(pools, (out.k_pages, out.v_pages),
+                                       ref):
+            same = ~written & ~trash
+            np.testing.assert_array_equal(bits(after)[same],
+                                          bits(before)[same])
+            np.testing.assert_array_equal(bits(after)[written],
+                                          bits(want)[written])
+            # the step really wrote: the cells it owns changed somewhere
+            assert np.any(bits(after)[written] != bits(before)[written])
 
 
 # -------------------------------------------------------------- engine --

@@ -12,7 +12,10 @@ only one process may hold the TPU library, and a test-collection-time
 call would make parallel workers collect different tests.
 """
 
+import math
 import os
+import re
+from unittest import mock
 
 import pytest
 
@@ -135,3 +138,95 @@ def test_flash_prefill_paged_codes(one_chip):
              [((SLOTS, CHUNK, N_KV, G, HD), U8), (_PAGES, U8), (_PAGES, U8),
               ((4,), F32), ((N_KV, 4), F32), ((N_KV, 4), F32), ((4,), F32)]
              + _PAGING + [((SLOTS,), jnp.int32)])
+
+
+# ------------------------------------------------ whole serving steps --
+#
+# The donated decode and prefill steps as the Engine jits them, at the
+# same widths with 2 layers, 8 slots and a 4,097-page pool.  The page
+# pools ride the layer scan as carry: the only ops that yield an array
+# of one layer's pool or more are parameters, bitcasts, tuple reads and
+# the in-place scatter of the new K/V.  A per-layer slice of the pool,
+# a rebuilt stack of it or a whole-pool copy fails here.
+
+LAYERS, PAGES = 2, 4097
+LAYER_POOL = PAGES * BLOCK * N_KV * HD      # elements (= bytes) of one layer
+_INST = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*\w+\[([\d,]+)\]\S*\s+"
+                   r"([\w\-]+)\(")
+_VIEWS = ("parameter", "bitcast", "get-tuple-element", "scatter")
+
+
+def _pool_sized_ops(hlo: str) -> list:
+    """Instructions of ``hlo`` that yield an array of at least one
+    layer's pool (minor dim the head size) and do more than view it or
+    scatter into it in place (a fusion that aliases its operand)."""
+    bad = []
+    for line in hlo.splitlines():
+        m = _INST.match(line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        op = m.group(2)
+        if (math.prod(dims) < LAYER_POOL or dims[-1] != HD or op in _VIEWS
+                or (op == "fusion" and '"aliasing_operands"' in line)):
+            continue
+        bad.append(line.strip()[:160])
+    return bad
+
+
+def _step_args(sharding, kv: str):
+    """Abstract params (7-bit weight codes; with ``kv="codes"`` the
+    activation tables that codes pages need) and the page view."""
+    from repro.configs import get_config
+    from repro.core import lama_layers as ll
+    from repro.models import api as mapi
+    from repro.models.layers import ACT_SITES
+    from repro.runtime.calibration import PER_HEAD_SITES
+    from repro.runtime.paged_cache import PagedView
+
+    cfg = get_config("qwen3-1.7b").replace(num_layers=LAYERS)
+    assert (cfg.d_model, cfg.d_ff, cfg.num_kv_heads) == (D, FF, N_KV)
+    api = mapi.get_model(cfg)
+    params, _ = ll.abstract_quantize(api.abstract_params(),
+                                     api.logical_axes(), bits=7)
+    pool_dtype = jnp.float8_e4m3fn
+    if kv == "codes":
+        pool_dtype = U8
+        params["blocks"]["act_q"] = {}
+        for site in ACT_SITES:
+            lead = (LAYERS, N_KV) if site in PER_HEAD_SITES else (LAYERS,)
+            params["blocks"]["act_q"][site] = {
+                "lut": jax.ShapeDtypeStruct(lead + (256,), F32),
+                "qmeta": jax.ShapeDtypeStruct(lead + (4,), F32)}
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        params)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+    pool = spec((LAYERS, PAGES, BLOCK, N_KV, HD), pool_dtype)
+    view = PagedView(pool, pool, spec((SLOTS, MAX_BLK), jnp.int32),
+                     spec((SLOTS,), jnp.int32))
+    return api, cfg, params, view, spec
+
+
+@pytest.mark.parametrize("kv", ["f8", "codes"])
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_step_moves_no_pool(one_chip, step, kv):
+    from repro.core import lama_layers as ll
+    from repro.runtime import engine
+
+    api, cfg, params, view, spec = _step_args(one_chip, kv)
+    # the kernels take their TPU branch; the jit wrappers donate the view
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            ll.policy(autotune=False):
+        if step == "decode":
+            fn = engine._jit_decode.__wrapped__(api.decode_step_paged)
+            lowered = fn.lower(params, view, spec((SLOTS, 1), jnp.int32),
+                               spec((SLOTS,), jnp.bool_), cfg)
+        else:
+            fn = engine._jit_prefill.__wrapped__(api.prefill_into_cache)
+            lowered = fn.lower(params, spec((SLOTS, CHUNK), jnp.int32),
+                               view, spec((SLOTS,), jnp.int32), cfg)
+        compiled = lowered.compile()
+    assert _pool_sized_ops(compiled.as_text()) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < LAYER_POOL
