@@ -25,7 +25,6 @@ import jax
 import numpy as np
 
 from bench.harness import adapter, loop, reference, warmup, weights
-from bench.harness.flops import Shapes
 from bench.harness.peaks import Peaks, peaks_for
 from bench.harness.spec import Cell, reader
 
@@ -74,15 +73,21 @@ class Run:
     """What the metric readers read."""
 
     cell: Cell
-    shapes: Shapes
+    shapes: object                 # arch.shapes(config)
     peaks: Peaks | None
     setup_s: float
     setup_compile_s: float
     window: loop.Window
     sent: list
-    decode_steps: int = 0          # over the traced span
+    # the change of every Engine counter over the traced span, by name
+    counters: dict = dataclasses.field(default_factory=dict)
     dispatches: list = dataclasses.field(default_factory=list)
     trace: object = None           # layer_metrics/_reduce.Trace
+
+    @property
+    def arch(self):
+        """The architecture module: the work counts over ``shapes``."""
+        return self.cell.arch
 
 
 def enable_compile_cache(root: Path) -> str:
@@ -95,24 +100,6 @@ def enable_compile_cache(root: Path) -> str:
     return path
 
 
-def _check_widths(cfg, config: dict) -> None:
-    d = int(config["hidden_size"])
-    want = {"num_layers": config["num_hidden_layers"], "d_model": d,
-            "num_heads": config["num_attention_heads"],
-            "num_kv_heads": config["num_key_value_heads"],
-            "d_ff": config["intermediate_size"],
-            "vocab_size": config["vocab_size"],
-            "resolved_head_dim": config.get("head_dim")
-            or d // int(config["num_attention_heads"]),
-            "tie_embeddings": config["tie_word_embeddings"],
-            "rope_theta": config["rope_theta"]}
-    got = {k: getattr(cfg, k) for k in want}
-    bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
-    if bad:
-        raise ValueError(f"program config differs from the configuration "
-                         f"file (program, file): {bad}")
-
-
 def build_engine(cell: Cell, seed: int):
     """Weights from the seed and the Engine over them; returns
     (engine, params, split seconds).  A configuration file with
@@ -123,11 +110,11 @@ def build_engine(cell: Cell, seed: int):
 
     config = cell.config
     cfg = get_config(config["model"], tiny=config.get("preset") == "tiny")
-    _check_widths(cfg, config)
+    cell.arch.check_widths(cfg, config)
     rep = config["representation"]
     t = time.perf_counter()
     params = weights.make_params(mapi.get_model(cfg).abstract_params(),
-                                 config, seed)
+                                 config, seed, cell.arch)
     jax.block_until_ready(params)
     t_w = time.perf_counter() - t
     kv_codes = rep["kv"] == "exp7"
@@ -168,9 +155,9 @@ def check(params, cell: Cell, sent, seed: int, control: bool) -> dict:
     gaps = {"served": [], "control": []}
     sample = _sample(sent, int(cell.mix["check_requests"]), seed)
     for s in sample:
-        for k, v in reference.served_gaps(params, cell.config, s.prompt,
-                                          s.tokens, length,
-                                          control=control).items():
+        for k, v in reference.served_gaps(cell.arch.forward, params,
+                                          cell.config, s.prompt, s.tokens,
+                                          length, control=control).items():
             gaps[k].append(v)
     out = {"requests": len(sample),
            "tokens": sum(len(s.tokens) for s in sample),
@@ -191,6 +178,13 @@ def judge(numbers: dict, limits: dict, failed: int,
     correct = (all(numbers[k] <= v for k, v in limits.items())
                and failed == 0 and requests >= 1)
     return bool(correct), checks
+
+
+def _counters(eng) -> dict:
+    """Every counter in the Engine's metrics registry, by name."""
+    reg = eng.telemetry.registry
+    return {k: reg.get(k).value for k in reg.keys()
+            if reg.get(k).kind == "counter"}
 
 
 def _read(cell: Cell, run: Run, metrics: list[dict], kind: str) -> dict:
@@ -235,7 +229,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
         state["ann"] = jax.profiler.TraceAnnotation("bench.window")
         state["ann"].__enter__()
-        state["steps0"] = eng.total_decode_steps
+        state["counters0"] = _counters(eng)
         dlog.recording = True
 
     compiles0 = clock.events
@@ -243,12 +237,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                           follow=bool(cell.mix.get("follow_to_end")))
     window = cl.run(on_tick if trace else None)
     compiles = clock.events - compiles0
-    run = Run(cell=cell, shapes=Shapes.of(cell.config), peaks=peaks,
+    run = Run(cell=cell, shapes=cell.arch.shapes(cell.config), peaks=peaks,
               setup_s=setup_s, setup_compile_s=setup_compile_s, window=window,
               sent=cl.sent)
     if trace:
         dlog.recording = False
-        run.decode_steps = eng.total_decode_steps - state["steps0"]
+        run.counters = {k: v - state["counters0"].get(k, 0)
+                        for k, v in _counters(eng).items()}
         state["ann"].__exit__(None, None, None)
         jax.profiler.stop_trace()
         dlog.close()

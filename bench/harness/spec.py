@@ -1,12 +1,16 @@
 """What a run is made of, found by name: the cell in ``BENCHMARK.json``,
-its configuration file, its traffic mix, the mix's generator and one
-reader per metric.
+its configuration file, the configuration's architecture, its traffic
+mix, the mix's generator and one reader per metric.
 
 Nothing here is keyed by a cell, a model or a metric: a new
-configuration, mix, generator or metric is a new file plus a new entry
-in ``BENCHMARK.json``, and this module finds it.
+configuration, architecture, mix, generator or metric is a new file plus
+a new entry in ``BENCHMARK.json``, and this module finds it.
 
 - configuration: the ``file`` named by its entry under ``configs``;
+- architecture ``<a>`` (the configuration's ``"architecture"``):
+  ``bench/architectures/<a>.py``, with ``check_widths``, ``leaf``,
+  ``act_tables``, ``forward``, ``shapes`` and the work counts
+  (``bench/architectures/decoder.py`` says what each does);
 - traffic mix ``<t>``: ``bench/mixes/<t>.json``;
 - generator ``<g>`` (the mix's ``"generator"``): ``bench/generators/<g>.py``,
   with ``make(mix, seed, vocab)``;
@@ -59,6 +63,7 @@ class Cell:
     config: dict
     traffic: str
     mix: dict
+    arch: ModuleType             # bench/architectures/<architecture>.py
     end_to_end: list[dict]       # the end-to-end metrics this cell reports
     per_layer: list[dict]        # the per-layer metrics this cell reports
 
@@ -96,10 +101,22 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     return Cell(root=root, name=name, chips=int(wl["chips"]),
                 config_name=wl["config"], config=config,
                 traffic=wl["traffic"], mix=mix,
+                arch=architecture(root, config, entry["file"]),
                 end_to_end=[m for m in bench["end_to_end"]
                             if _reports(m, name)],
                 per_layer=[m for m in bench["per_layer"]
                            if _reports(m, name)])
+
+
+def architecture(root: Path, config: dict, file: str) -> ModuleType:
+    """The module of the configuration's ``"architecture"``, read from
+    configuration file ``file``."""
+    if "architecture" not in config:
+        raise KeyError(f"{file}: no \"architecture\" key (the harness "
+                       f"looks for bench/architectures/<architecture>.py)")
+    name = config["architecture"]
+    return load_module(root / "bench" / "architectures" / f"{name}.py",
+                       f"bench_architectures.{name}")
 
 
 def reader(root: Path, kind: str, name: str) -> ModuleType:

@@ -1,12 +1,15 @@
 """The reduction shared by the per-layer readers: a profiler trace
 (``.xplane.pb``) to device busy time, time per kernel family, device
 programs told apart by the dispatch that launched them, and idle gaps
-named by the benchmark's host span around them.
+named by the host span around them.
 
 A trace is first cut down to plain lists (:class:`Trace`), which is
-also the form of the small recorded trace the tests read.  Times are
+also the form of the small recorded traces the tests read.  Times are
 nanoseconds on the trace's one clock.  The traced window is the
-benchmark's ``bench.window`` host span.
+benchmark's ``bench.window`` host span.  Host spans kept are the
+benchmark's (``bench.*``) and the program's (``engine.*``: the phases of
+``Engine.step``), so that a reader of the program's spans is one new
+file.
 
 Device ops nest: a program's layer loop (``while``) is an op whose
 interval holds the ops of its body.  Busy time is the union of the
@@ -31,11 +34,12 @@ import re
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_PREFIX = "bench."
+HOST_PREFIXES = ("bench.", "engine.")
 WINDOW = "bench.window"
 DISPATCH = "bench.dispatch."
 
-# Kernel families, matched against an op's name.
+# Kernel families, matched against an op's name.  A reader of a kernel
+# not here passes its own pattern to ``family_s``.
 FAMILIES = {
     "wmatmul": re.compile(r"lut_dequant_matmul"),
     "attn": re.compile(r"flash_prefill_paged|decode_gqa_paged"),
@@ -124,7 +128,7 @@ def from_xplane(path: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(HOST_PREFIX):
+                    if ev.name.startswith(HOST_PREFIXES):
                         host.append([ev.start_ns,
                                      ev.start_ns + ev.duration_ns, ev.name])
     return Trace(ops=ops, modules=modules, host=host)
@@ -150,9 +154,11 @@ def busy_s(trace: Trace) -> float:
     return sum(per) / len(per) * 1e-9 if per else 0.0
 
 
-def family_s(trace: Trace, family: str) -> float:
-    """Device seconds of a kernel family, summed over devices."""
-    pat = FAMILIES[family]
+def family_s(trace: Trace, family) -> float:
+    """Device seconds of a kernel family, summed over devices: a key of
+    :data:`FAMILIES`, or any other regex string or compiled pattern,
+    searched in each op's name."""
+    pat = re.compile(FAMILIES.get(family, family))
     return sum(e - s for evs in trace.ops.values()
                for s, e, name in trace.clipped(evs)
                if pat.search(name)) * 1e-9
@@ -213,7 +219,8 @@ def top_ops(trace: Trace, n: int = 10) -> list[list]:
 
 def idle_gaps(trace: Trace, n: int = 10) -> list[list]:
     """The ``n`` longest gaps between device ops in the window, each
-    named by the innermost benchmark host span around its midpoint."""
+    named by the innermost host span (``bench.*`` or ``engine.*``)
+    around its midpoint."""
     lo, hi = trace.window()
     gaps = []
     for evs in trace.ops.values():

@@ -3,5 +3,6 @@ over the change of the Engine's ``engine.decode.steps`` counter."""
 
 
 def read(run):
+    steps = run.counters.get("engine.decode.steps")
     decoded = sum(len(d.valid) for d in run.dispatches if d.kind == "decode")
-    return decoded / run.decode_steps if run.decode_steps else None
+    return decoded / steps if steps else None

@@ -1,7 +1,7 @@
 """The quantized matmuls' share of their roofline: for every dispatch,
 the least time its matmuls could take at the rows dispatched (the
-larger of FLOPs over the bf16 peak and bytes over HBM bandwidth; see
-``bench/harness/flops.py``), summed, over the device time of the
+larger of FLOPs over the bf16 peak and bytes over HBM bandwidth, by the
+architecture's counts), summed, over the device time of the
 ``lut_dequant_matmul`` kernels (every variant) in the traced window."""
 
 from bench.harness import flops
@@ -13,9 +13,9 @@ def read(run):
     busy = red.family_s(run.trace, "wmatmul")
     if not busy or not run.dispatches:
         return None
-    s, p = run.shapes, run.peaks
+    a, s, p = run.arch, run.shapes, run.peaks
     least = sum(flops.least_seconds(
-        flops.matmul_flops(s, d.rows * d.width, d.rows),
-        flops.matmul_bytes(s, d.rows * d.width, d.rows),
+        a.matmul_flops(s, d.rows * d.width, d.rows),
+        a.matmul_bytes(s, d.rows * d.width, d.rows),
         p.bf16_flops, p.hbm_bytes_s)[0] for d in run.dispatches)
     return 100.0 * least / busy

@@ -20,7 +20,8 @@ ENGINE = {"num_slots": 4, "block_size": 16, "max_seq_len": 128,
 
 TINY_CONFIGS = {
     "qwen-tiny": {
-        "model": "qwen3-1.7b", "preset": "tiny", "hidden_size": 64,
+        "model": "qwen3-1.7b", "architecture": "decoder", "preset": "tiny",
+        "hidden_size": 64,
         "intermediate_size": 192, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "vocab_size": 512, "rope_theta": 1000000, "tie_word_embeddings": True,
@@ -31,7 +32,8 @@ TINY_CONFIGS = {
                            "control_activations": "float8_e4m3fn"},
         "engine": ENGINE, "reduced": []},
     "olmo-tiny": {
-        "model": "olmo-1b", "preset": "tiny", "hidden_size": 64,
+        "model": "olmo-1b", "architecture": "decoder", "preset": "tiny",
+        "hidden_size": 64,
         "intermediate_size": 256, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 4,
         "vocab_size": 512, "rope_theta": 10000.0, "tie_word_embeddings": True,

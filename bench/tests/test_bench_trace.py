@@ -8,6 +8,7 @@ OLMo cell, written by ``bench/tools/record_trace.py``)."""
 
 import gzip
 import json
+import re
 from pathlib import Path
 
 import pytest
@@ -48,6 +49,11 @@ def test_hand_made():
     assert R.busy_s(t) == pytest.approx(0.0085)   # 0-6, 8-10.5 ms
     assert R.family_s(t, "wmatmul") == pytest.approx(0.003)
     assert R.family_s(t, "attn") == pytest.approx(0.002)
+    # a reader's own family: a regex string or a compiled pattern
+    assert R.family_s(t, r"matmul_dual") == pytest.approx(0.001)
+    assert R.family_s(t, re.compile(r"^flash_prefill")) == \
+        pytest.approx(0.002)
+    assert R.family_s(t, "no_such_kernel") == 0.0
     assert sorted(R.programs(t)) == [("decode", pytest.approx(0.002)),
                                      ("prefill", pytest.approx(0.006))]
     # gaps 6-8 ms (inside the decode dispatch, the innermost span) and
