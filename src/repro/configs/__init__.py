@@ -1,6 +1,7 @@
-"""Architecture registry: the ten assigned configs + shapes."""
+"""Architecture registry: the assigned configs + shapes."""
 
 from repro.configs import (
+    deepseek_v2_lite,
     grok_1_314b,
     llama4_scout_17b_a16e,
     minicpm_2b,
@@ -24,7 +25,7 @@ from repro.configs.base import (  # noqa: F401
 _MODULES = (
     olmo_1b, qwen3_14b, qwen3_1_7b, minicpm_2b, recurrentgemma_2b,
     seamless_m4t_medium, paligemma_3b, rwkv6_3b, llama4_scout_17b_a16e,
-    grok_1_314b,
+    grok_1_314b, deepseek_v2_lite,
 )
 
 ARCHS = {m.ARCH: m for m in _MODULES}

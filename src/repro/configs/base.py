@@ -40,8 +40,27 @@ class ModelConfig:
     # MoE
     num_experts: int = 0
     experts_per_token: int = 0
-    moe_impl: str = "routed"               # routed | dense_mixture
+    moe_impl: str = "routed"               # routed | dense_mixture | dropless
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True            # renormalise the top-k gates
+    shared_experts_width: int = 0          # always-on gated MLP (0: none)
+    experts_held: tuple[int, int] | None = None  # [lo, hi) held here; None: all
+    first_dense_layers: int = 0            # leading dense-MLP layers
+    dense_d_ff: int = 0                    # their MLP width (0: d_ff)
+
+    # multi-head latent attention (DeepSeek-V2); kv_lora_rank 0: off
+    kv_lora_rank: int = 0                  # latent width c_kv
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # YaRN rope scaling (DeepSeek's form); factor 0: plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # hybrid (recurrentgemma) / local attention
     attention_pattern: tuple[str, ...] = ()  # e.g. ("rglru","rglru","local")
@@ -83,6 +102,25 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """The ``[lo, hi)`` range of routed experts whose weights this
+        process holds (all of them unless ``experts_held`` says)."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def latent_dim(self) -> int:
+        """Numbers an MLA cache page stores per token: the normed
+        ``c_kv`` and the roped ``k_pe``, padded to a whole number of
+        128-lane tiles (a 576-lane page makes the TPU attention kernel
+        relayout the whole pool on every call)."""
+        n = self.kv_lora_rank + self.qk_rope_head_dim
+        return -(-n // 128) * 128
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

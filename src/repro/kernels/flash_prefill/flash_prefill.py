@@ -19,7 +19,10 @@ The query tile is ``[tq·H, hd]`` rows (token-major, then query head),
 one page is ``[bs·n_kv, hd]`` columns (slot-major, then KV head), and
 one MXU dot scores every row against every column of the page; a
 head-match mask (query head ``h`` reads KV head ``h // g``) joins the
-positional mask.  With ``bs·n_kv = 128`` (pages of 16, 8 KV heads) the
+positional mask.  A single KV head (latent attention) may come as
+``[N, bs, hd]`` pages: at ``n_kv = 1`` a ``[bs, 1, hd]`` page's minor pair
+``(1, hd)`` would make XLA relayout the whole pool for the kernel.
+With ``bs·n_kv = 128`` (pages of 16, 8 KV heads) the
 scores fill exactly one lane width, so the cross-head columns cost no
 MXU passes that a per-head dot would not.  The online-softmax carries
 are ``[tq·H, 1]`` and ``[tq·H, hd]``; the chunk is tiled along a query
@@ -112,7 +115,10 @@ def _context(m_ref, l_ref, acc_ref):
 
 def _fold(kv):
     """[bs, n_kv, hd] page -> [bs*n_kv, hd] columns (a free reshape:
-    the merged dims are above the tiled minor pair)."""
+    the merged dims are above the tiled minor pair); a single-head
+    ``[bs, hd]`` page already is its columns."""
+    if kv.ndim == 2:
+        return kv
     bs, n_kv, hd = kv.shape
     return kv.reshape(bs * n_kv, hd)
 
@@ -177,8 +183,12 @@ def _paged_call(body, q, k_pages, v_pages, block_tables, q_start, kv_lens,
     tq = q_tile(s, heads)
     rows = tq * heads
     q_rows = q.reshape(b, s * heads, hd)
-    page_spec = pl.BlockSpec((1, block_s, n_kv, hd),
-                             lambda i, t, j, S, L, T: (T[i, j], 0, 0, 0))
+    if k_pages.ndim == 3:        # one KV head: pages [N, bs, hd]
+        page_spec = pl.BlockSpec((1, block_s, hd),
+                                 lambda i, t, j, S, L, T: (T[i, j], 0, 0))
+    else:
+        page_spec = pl.BlockSpec((1, block_s, n_kv, hd),
+                                 lambda i, t, j, S, L, T: (T[i, j], 0, 0, 0))
     row_spec = pl.BlockSpec((1, rows, hd),
                             lambda i, t, j, S, L, T: (i, t, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -242,7 +252,8 @@ def flash_prefill_paged_codes_kernel(
 def flash_prefill_paged_kernel(
     q: jax.Array,             # [B, S, n_kv, g, hd] — roped query chunk
     k_pages: jax.Array,       # [N_blocks, bs, n_kv, hd] (bf16 / f8 / ...)
-    v_pages: jax.Array,       # [N_blocks, bs, n_kv, hd]
+    v_pages: jax.Array,       # [N_blocks, bs, n_kv, hd]; both [N, bs, hd]
+                              # for one KV head
     block_tables: jax.Array,  # [B, max_blk] int32 — page id per logical block
     q_start: jax.Array,       # [B] int32 — absolute position of query row 0
     kv_lens: jax.Array,       # [B] int32 — cache positions actually written

@@ -31,6 +31,7 @@ def flash_prefill_paged(q, k_pages, v_pages, block_tables, q_start,
 
     q: [B, S, n_kv, g, hd] — a chunk of roped queries whose row 0 sits
     at absolute position ``q_start[b]``; pages [N_blocks, bs, n_kv, hd]
+    or, for a single KV head (latent attention), [N_blocks, bs, hd]
     (any narrow dtype — dequant happens in-kernel); block_tables
     [B, max_blk]; ``kv_lens`` [B] caps validity at the cache positions
     actually written (trash-page columns mask out).  Rows with zero

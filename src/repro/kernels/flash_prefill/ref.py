@@ -22,9 +22,11 @@ from repro.kernels._codes import decode_heads
 
 def flash_prefill_paged_ref(q, k_pages, v_pages, block_tables, q_start,
                             kv_lens, out_dtype=jnp.float32):
-    """q: [B, S, n_kv, g, hd]; pages [N, bs, n_kv, hd];
-    block_tables [B, max_blk]; q_start/kv_lens [B].
+    """q: [B, S, n_kv, g, hd]; pages [N, bs, n_kv, hd] (or [N, bs, hd]
+    for one KV head); block_tables [B, max_blk]; q_start/kv_lens [B].
     Returns [B, S, n_kv, g, hd]."""
+    if k_pages.ndim == 3:
+        k_pages, v_pages = k_pages[:, :, None], v_pages[:, :, None]
     b, s, n_kv, g, hd = q.shape
     bs = k_pages.shape[1]
     max_blk = block_tables.shape[1]

@@ -13,6 +13,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import lama_layers as ll
 from repro.configs.base import ModelConfig
@@ -93,12 +94,18 @@ def apply_head_rms(p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 # -------------------------------------------------------------- rope --
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: [..., seq, heads, head_dim]; positions: [..., seq]."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq=None) -> jax.Array:
+    """Rotary embedding. x: [..., seq, heads, head_dim]; positions: [..., seq].
+    ``inv_freq`` [head_dim // 2] replaces ``theta ** (-2i / head_dim)``
+    (YaRN, :func:`yarn_inv_freq`)."""
     d = x.shape[-1]
     half = d // 2
-    freq = jnp.arange(half, dtype=jnp.float32) / half
-    inv = theta ** (-freq)                                # [half]
+    if inv_freq is None:
+        freq = jnp.arange(half, dtype=jnp.float32) / half
+        inv = theta ** (-freq)                            # [half]
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv   # [..., seq, half]
     cos = jnp.cos(ang)[..., None, :]                       # [..., seq, 1, half]
     sin = jnp.sin(ang)[..., None, :]
@@ -106,6 +113,60 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _yarn_correction_dim(rotations: float, dim: int, base: float,
+                         max_pos: int) -> float:
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(base)))
+
+
+def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
+    """DeepSeek's YaRN inverse frequencies over the rope dims: the
+    interpolated (``/ factor``) and the original frequencies blended by
+    a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow``.  The blend changes the low frequencies at every
+    position, not only past the original context."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / cfg.yarn_factor
+    low = max(math.floor(_yarn_correction_dim(
+        cfg.yarn_beta_fast, dim, base, cfg.yarn_original_max_position)), 0)
+    high = min(math.ceil(_yarn_correction_dim(
+        cfg.yarn_beta_slow, dim, base, cfg.yarn_original_max_position)),
+        dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_rope_scale(cfg: ModelConfig) -> float:
+    """YaRN's scale on the roped cos/sin, ``mscale / mscale_all_dim``."""
+    return (_yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+            / _yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``q_head_dim ** -0.5``, times YaRN's ``mscale_all_dim`` factor
+    squared when the rope is YaRN."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= _yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x: jax.Array, positions: jax.Array,
+              cfg: ModelConfig) -> jax.Array:
+    if not cfg.yarn_factor:
+        return rope(x, positions, cfg.rope_theta)
+    out = rope(x, positions, cfg.rope_theta, yarn_inv_freq(cfg))
+    m = yarn_rope_scale(cfg)
+    return out if m == 1.0 else (out * m).astype(x.dtype)
 
 
 # ------------------------------------------------- act quantization --
@@ -608,6 +669,120 @@ def self_kv(p: Params, x: jax.Array, cfg: ModelConfig,
     if use_rope:
         k = rope(k, positions, cfg.rope_theta)
     return k, v
+
+
+# ------------------------------------------- multi-head latent attention --
+#
+# DeepSeek-V2's MLA, absorbed: a token's cache entry is its normed
+# latent ``c_kv`` [kv_lora_rank] and its roped shared ``k_pe``
+# [qk_rope_head_dim], zero-padded to ``cfg.latent_dim`` lanes.  Per
+# head h, ``q_nope_h . k_nope_h = (q_nope_h W_UK_h^T) . c_kv``, so the
+# query is taken into the latent space once and attention runs as MQA:
+# one "KV head" of latent_dim numbers that every query head reads, the
+# values being the same entries' first kv_lora_rank numbers, brought
+# back per head through W_UV after the softmax.  No per-head K or V
+# exists anywhere.
+
+def mla_specs(cfg: ModelConfig) -> dict:
+    d, h = cfg.d_model, cfg.num_heads
+    c, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    n, v = cfg.qk_nope_head_dim, cfg.v_head_dim
+    return {
+        "wq": ParamSpec((d, h, n + r), ("embed", "heads", "head"), "scaled"),
+        "wkv_a": ParamSpec((d, c + r), ("embed", None), "scaled"),
+        "kv_norm": {"scale": ParamSpec((c,), (None,), "ones")},
+        "wk_b": ParamSpec((h, n, c), ("heads", "head", None), "scaled",
+                          fan_in_axis=2),
+        "wv_b": ParamSpec((h, c, v), ("heads", None, "head"), "scaled",
+                          fan_in_axis=1),
+        "wo": ParamSpec((h, v, d), ("heads", "head", "embed"), "scaled",
+                        fan_in_axis=0),
+    }
+
+
+def _pad_latent(parts, cfg: ModelConfig) -> jax.Array:
+    x = jnp.concatenate(parts, axis=-1)
+    pad = cfg.latent_dim - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def mla_latent(p: Params, x: jax.Array, cfg: ModelConfig,
+               positions: jax.Array) -> jax.Array:
+    """The cache entry of each token, [B, S, latent_dim]: ``c_kv``
+    through its own RMSNorm, the roped ``k_pe``, zeros."""
+    c = cfg.kv_lora_rank
+    kv = ll.dense(x, p["wkv_a"], dtype=x.dtype)              # [B, S, c+r]
+    c_kv = apply_head_rms(p["kv_norm"], kv[..., :c])
+    k_pe = _mla_rope(kv[..., None, c:], positions, cfg)[..., 0, :]
+    return _pad_latent([c_kv, k_pe], cfg)
+
+
+def mla_query(p: Params, x: jax.Array, cfg: ModelConfig,
+              positions: jax.Array) -> jax.Array:
+    """The absorbed query, [B, S, H, latent_dim]: ``q_nope W_UK^T`` in
+    the latent space, the roped ``q_pe``, zeros; scaled so that an
+    attend's ``1/sqrt(latent_dim)`` gives the model's softmax scale."""
+    dt = x.dtype
+    n = cfg.qk_nope_head_dim
+    q = ll.dense_general(x, p["wq"], "bsd,dnh->bsnh", dtype=dt)
+    # the per-head contractions leave in f32: the CPU backend has no
+    # batched bf16 x bf16 -> f32 dot, and the kernel computes in f32
+    q_lat = ll.dense_general(q[..., :n], p["wk_b"], "bshn,hnc->bshc",
+                             dtype=jnp.float32)
+    q_pe = _mla_rope(q[..., n:], positions, cfg)
+    scale = mla_softmax_scale(cfg) * math.sqrt(cfg.latent_dim)
+    return (_pad_latent([q_lat, q_pe.astype(jnp.float32)], cfg)
+            * scale).astype(dt)
+
+
+def mla_output(p: Params, ctx: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Attention's mix of cache entries [B, S, H, latent_dim] -> [B, S, D]:
+    the ``c_kv`` part through each head's W_UV, then W_o."""
+    dt = ctx.dtype
+    o = ll.dense_general(ctx[..., :cfg.kv_lora_rank], p["wv_b"],
+                         "bshc,hcv->bshv", dtype=jnp.float32)
+    return ll.dense_general(o.astype(dt), p["wo"], "bshv,hvd->bsd", dtype=dt)
+
+
+def mla(p: Params, x: jax.Array, cfg: ModelConfig, positions: jax.Array,
+        mask, latent: jax.Array | None = None) -> jax.Array:
+    """Full-sequence MLA (forward and the contiguous cache path);
+    ``latent`` [B, T, latent_dim] overrides the self-derived entries
+    (decode-with-cache), ``mask`` as in :func:`mha`."""
+    dt = x.dtype
+    q = mla_query(p, x, cfg, positions)[:, :, None]        # [B,S,1,H,P]
+    lat = mla_latent(p, x, cfg, positions) if latent is None else latent
+    k = lat.astype(dt)[:, :, None]                         # [B,T,1,P]
+    b, s, t = q.shape[0], q.shape[1], k.shape[1]
+    if isinstance(mask, tuple):
+        kind, arg = (mask[0], mask[1] if len(mask) > 1 else None)
+        if b * cfg.num_heads * s * t > FLASH_THRESHOLD:
+            out = _attend_flash(q, k, k, kind, arg, 0, dt)
+        else:
+            out = _attend_dense(q, k, k,
+                                _materialize_mask(kind, arg, s, t, 0), dt)
+    else:
+        out = _attend_dense(q, k, k, mask, dt)
+    return mla_output(p, out[:, :, 0], cfg)
+
+
+def mla_paged(p: Params, x: jax.Array, cfg: ModelConfig,
+              positions: jax.Array, pages: jax.Array,
+              block_tables: jax.Array, q_start: jax.Array,
+              kv_lens: jax.Array) -> jax.Array:
+    """MLA over a latent page pool ``[N, bs, latent_dim]`` through the
+    paged flash kernel as MQA (prefill chunks and, at S = 1 with
+    ``q_start = kv_lens - 1``, decode steps).  The kernel reads the pool
+    as keys and again as values; the values' padding and ``k_pe`` lanes
+    are dropped by :func:`mla_output`."""
+    from repro.kernels.flash_prefill import (flash_prefill_paged,
+                                             flash_prefill_paged_ref)
+
+    q = mla_query(p, x, cfg, positions)[:, :, None]        # [B,S,1,H,P]
+    attend = (flash_prefill_paged if ll.get_policy().flash_decode
+              else flash_prefill_paged_ref)
+    out = attend(q, pages, pages, block_tables, q_start, kv_lens)
+    return mla_output(p, out[:, :, 0].astype(x.dtype), cfg)
 
 
 # --------------------------------------------------------------- mlp --

@@ -1,15 +1,23 @@
-"""Mixture-of-Experts layers (llama4-scout: 16e top-1; grok-1: 8e top-2).
+"""Mixture-of-Experts layers (llama4-scout: 16e top-1; grok-1: 8e top-2;
+deepseek-v2-lite: 64e top-6 plus shared experts).
 
-Two implementations, selectable via ``cfg.moe_impl``:
+Implementations, selected by ``cfg.moe_impl``:
 
-* ``routed`` — production path: top-k routing with sort-based,
-  capacity-dropped dispatch (GShard capacity discipline, MegaBlocks-style
-  sorted grouping, no [T,E,C] one-hot blow-up).  Expert FFNs run as
+* ``routed`` — llama4-scout's and grok-1's path: top-k routing with
+  sort-based, capacity-dropped dispatch (GShard capacity discipline,
+  MegaBlocks-style sorted grouping, no [T,E,C] one-hot blow-up): a
+  token past its expert's capacity is dropped.  Expert FFNs run as
   grouped einsums over the ``experts`` axis, which shards as EP on the
   mesh "model" axis when divisible.
+* ``dropless`` — the serving path (:func:`apply_moe_dropless`): no
+  token is dropped.  Routes over all ``num_experts`` and computes the
+  experts held here (``cfg.experts_held``) on the rows sorted by
+  expert, giving the part of the output they contribute; rows that
+  carry no token route nowhere; shared experts are added once.
 * ``dense_mixture`` — naive oracle: every expert computes every token,
   mixed by router weights.  E/k x more FLOPs; used as the correctness
   reference and as the §Perf baseline for the MoE hillclimb cell.
+* ``ep_a2a`` — expert parallelism over the mesh (``moe_ep``, training).
 """
 
 from __future__ import annotations
@@ -19,13 +27,19 @@ import jax.numpy as jnp
 
 from repro.core import lama_layers as ll
 from repro.configs.base import ModelConfig
+from repro.models import layers as L
 from repro.models.params import ParamSpec
 
 
 def moe_specs(cfg: ModelConfig) -> dict:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
-    return {
-        "router": ParamSpec((d, e), ("embed", "experts"), "scaled"),
+    """The router over all ``num_experts``; the weights of the experts
+    held here; the shared experts as one gated MLP."""
+    d, f = cfg.d_model, cfg.d_ff
+    lo, hi = cfg.held_experts
+    e = hi - lo
+    s = {
+        "router": ParamSpec((d, cfg.num_experts), ("embed", "experts"),
+                            "scaled"),
         "w_gate": ParamSpec((e, d, f), ("experts", "embed", "mlp"), "scaled",
                             fan_in_axis=1),
         "w_up": ParamSpec((e, d, f), ("experts", "embed", "mlp"), "scaled",
@@ -33,6 +47,9 @@ def moe_specs(cfg: ModelConfig) -> dict:
         "w_down": ParamSpec((e, f, d), ("experts", "mlp", "embed"), "scaled",
                             fan_in_axis=1),
     }
+    if cfg.shared_experts_width:
+        s["shared"] = L.mlp_specs(cfg, cfg.shared_experts_width)
+    return s
 
 
 def _capacity(cfg: ModelConfig, tokens: int) -> int:
@@ -41,11 +58,20 @@ def _capacity(cfg: ModelConfig, tokens: int) -> int:
     return max(128, -(-cap // 128) * 128)  # pad to a lane-friendly multiple
 
 
-def _router(p, xf: jax.Array, cfg: ModelConfig):
-    logits = ll.dense(xf, p["router"], dtype=jnp.float32)
+def _router(p, xf: jax.Array, cfg: ModelConfig, precision=None):
+    """Softmax gates over all experts and the top-k of them, renormalised
+    to sum to one when ``cfg.norm_topk_prob``.  ``precision`` computes
+    the logits from a float router in float32 at that precision."""
+    if precision is None:
+        logits = ll.dense(xf, p["router"], dtype=jnp.float32)
+    else:
+        logits = jnp.dot(xf.astype(jnp.float32),
+                         ll.materialize(p["router"], jnp.float32),
+                         precision=precision)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top_w, top_e = jax.lax.top_k(probs, cfg.experts_per_token)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if cfg.norm_topk_prob:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     # load-balancing auxiliary loss (Switch/GShard)
     density = jnp.mean(
         jax.nn.one_hot(top_e[..., 0], cfg.num_experts, dtype=jnp.float32), 0)
@@ -202,7 +228,111 @@ def apply_moe_dense(p, x: jax.Array, cfg: ModelConfig,
          _expert_slices(p["w_up"], xf.dtype),
          _expert_slices(p["w_down"], xf.dtype),
          w.T.astype(jnp.float32)))
-    return y.reshape(b, s, d), aux
+    return _with_shared(p, x, y.reshape(b, s, d), cfg, act_q), aux
+
+
+def _with_shared(p, x, y, cfg: ModelConfig, act_q=None):
+    """``y`` plus the shared experts' output, where the layer has them."""
+    if "shared" not in p:
+        return y
+    return y + L.apply_mlp(p["shared"], x, cfg, act_q=act_q)
+
+
+def _tile_rows(pairs: int) -> int:
+    """Rows per expert tile: a bf16 sublane tile for decode-sized
+    dispatches, an MXU-height tile for prefill-sized ones."""
+    return 16 if pairs <= 512 else 128
+
+
+def _grouped_ffn(p, xbuf, tile_expert, used, tm: int, cfg: ModelConfig):
+    """Each ``tm``-row tile of ``xbuf`` through its expert's gated MLP:
+    the grouped ``moe_expert`` kernels for weight codes, gathered float
+    weights otherwise."""
+    from repro.core import exponential_quant as eq
+
+    wg, wu, wd = p["w_gate"], p["w_up"], p["w_down"]
+    if eq.is_qtensor(wg):
+        from repro.kernels.moe_expert import moe_expert_down, moe_expert_gated
+
+        mid = moe_expert_gated(xbuf, wg["codes"], wu["codes"], wg["qmeta"],
+                               wu["qmeta"], tile_expert, used, tm=tm,
+                               activation=cfg.activation,
+                               out_dtype=xbuf.dtype)
+        return moe_expert_down(mid, wd["codes"], wd["qmeta"], tile_expert,
+                               used, tm=tm, out_dtype=jnp.float32)
+    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    xt = xbuf.reshape(-1, tm, xbuf.shape[-1]).astype(jnp.float32)
+
+    def mm(a, w):
+        return jnp.einsum("tmk,tkn->tmn", a, w[tile_expert].astype(
+            jnp.float32), preferred_element_type=jnp.float32)
+
+    mid = (act(mm(xt, wg)) * mm(xt, wu)).astype(xbuf.dtype)
+    return mm(mid.astype(jnp.float32), wd).reshape(xbuf.shape[0], -1)
+
+
+def apply_moe_dropless(p, x: jax.Array, cfg: ModelConfig,
+                       valid: jax.Array | None = None):
+    """Serving expert layer that drops no token.  x: [B, S, D]; ``valid``
+    [B, S] marks the rows that carry a token (None: all do).
+
+    The router (float32, ``Precision.HIGHEST``) picks each valid row's
+    top-k of all ``num_experts``; the (row, expert) pairs that land on
+    the experts held here, ``[lo, hi)``, are sorted by expert into a
+    buffer whose groups are padded to whole tiles, sized for the worst
+    case so that nothing can overflow.  The grouped kernels compute
+    each tile with its own expert's weights; every pair's output comes
+    back to its row weighted by its gate.  What the absent experts
+    would add is not computed.  The shared experts are added once.
+
+    Returns ``(y, aux_loss, counts)``: ``counts [hi - lo]`` int32 is the
+    number of rows routed to each held expert."""
+    b, s, d = x.shape
+    t = b * s
+    k = cfg.experts_per_token
+    lo, hi = cfg.held_experts
+    eh = hi - lo
+    xf = x.reshape(t, d)
+    _, top_w, top_e, aux = _router(p, xf, cfg,
+                                   precision=jax.lax.Precision.HIGHEST)
+    ok = (jnp.ones((t,), bool) if valid is None
+          else valid.reshape(t).astype(bool))
+    held = ((top_e >= lo) & (top_e < hi) & ok[:, None]).reshape(t * k)
+    local = jnp.where(held, top_e.reshape(t * k) - lo, eh)   # eh: not here
+    counts = jnp.bincount(local, length=eh + 1)[:eh].astype(jnp.int32)
+
+    tm = _tile_rows(t * k)
+    n_tiles = -(-t * min(k, eh) // tm) + eh      # sum_e ceil(count_e / tm)
+    rows = n_tiles * tm
+    tiles = -(-counts // tm)
+    tile_end = jnp.cumsum(tiles)
+    used = tile_end[-1]
+    # pair -> buffer row: its expert's first row plus its rank there
+    order = jnp.argsort(local, stable=True)
+    sorted_e = local[order]
+    e_c = jnp.minimum(sorted_e, eh - 1)
+    rank = jnp.arange(t * k) - (jnp.cumsum(counts) - counts)[e_c]
+    dest_sorted = jnp.where(sorted_e < eh,
+                            (tile_end - tiles)[e_c] * tm + rank, rows)
+    dest = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32))
+    row_tok = jnp.full((rows,), t, jnp.int32).at[dest].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+    xbuf = jnp.concatenate([xf, jnp.zeros((1, d), x.dtype)])[row_tok]
+    idx = jnp.arange(n_tiles)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, idx, side="right"), eh - 1)
+    tile_expert = jnp.where(idx < used, tile_expert,
+                            tile_expert[jnp.maximum(used - 1, 0)])
+
+    ybuf = _grouped_ffn(p, xbuf, tile_expert.astype(jnp.int32), used, tm,
+                        cfg)
+    y_pair = ybuf[jnp.minimum(dest, rows - 1)]              # [t*k, d]
+    y_pair = jnp.where(held[:, None],
+                       y_pair * top_w.reshape(t * k)[:, None], 0.0)
+    y = jnp.sum(y_pair.reshape(t, k, d), axis=1).astype(x.dtype)
+    y = _with_shared(p, x, y.reshape(b, s, d), cfg)
+    return y, aux, counts
 
 
 def apply_moe(p, x: jax.Array, cfg: ModelConfig,
@@ -214,4 +344,7 @@ def apply_moe(p, x: jax.Array, cfg: ModelConfig,
         return apply_moe_ep(p, x, cfg)
     if cfg.moe_impl == "routed":
         return apply_moe_routed(p, x, cfg, act_q=act_q)
+    if cfg.moe_impl == "dropless":
+        y, aux, _ = apply_moe_dropless(p, x, cfg)
+        return y, aux
     return apply_moe_dense(p, x, cfg, act_q=act_q)

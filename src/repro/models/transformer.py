@@ -21,13 +21,17 @@ from repro.models.params import ParamSpec, stack_specs, scan_blocks
 
 # --------------------------------------------------------------- specs --
 
-def block_specs(cfg: ModelConfig) -> dict:
+def block_specs(cfg: ModelConfig, dense: bool = False) -> dict:
+    """One block; ``dense`` is one of an MoE model's leading dense-MLP
+    layers."""
     s = {
         "ln1": L.norm_specs(cfg),
-        "attn": L.attention_specs(cfg),
+        "attn": L.mla_specs(cfg) if cfg.is_mla else L.attention_specs(cfg),
         "ln2": L.norm_specs(cfg),
     }
-    if cfg.is_moe:
+    if dense:
+        s["mlp"] = L.mlp_specs(cfg, cfg.dense_d_ff or cfg.d_ff)
+    elif cfg.is_moe:
         s["moe"] = M.moe_specs(cfg)
     else:
         s["mlp"] = L.mlp_specs(cfg)
@@ -35,33 +39,72 @@ def block_specs(cfg: ModelConfig) -> dict:
 
 
 def model_specs(cfg: ModelConfig) -> dict:
+    """``blocks`` are the layers stacked for the layer scan; an MoE
+    model's leading dense layers stack apart, as ``dense_blocks``."""
+    n_dense = cfg.first_dense_layers
     s = {
         "embed": L.embed_specs(cfg),
-        "blocks": stack_specs(block_specs(cfg), cfg.num_layers),
+        "blocks": stack_specs(block_specs(cfg), cfg.num_layers - n_dense),
         "ln_f": L.norm_specs(cfg),
     }
+    if n_dense:
+        s["dense_blocks"] = stack_specs(block_specs(cfg, dense=True),
+                                        n_dense)
     s.update({"unembed": L.unembed_specs(cfg)} if not cfg.tie_embeddings else {})
     return s
 
 
+def _segments(params) -> list:
+    """The stacked block params of each run of like layers, in layer
+    order: the leading dense layers (where a model has them), then the
+    rest."""
+    return [params[k] for k in ("dense_blocks", "blocks") if k in params]
+
+
+def _scan_segments(body, carry, params, cfg, xs=None):
+    """``scan_blocks`` over every segment in layer order.  ``xs``
+    (leaves stacked over all layers) are split at the segment bounds;
+    per-layer outputs are joined back along the layer axis."""
+    segs = _segments(params)
+    if len(segs) == 1:
+        inp = segs[0] if xs is None else (segs[0], *xs)
+        return scan_blocks(body, carry, inp, cfg)
+    outs, first = [], 0
+    for seg in segs:
+        n = jax.tree_util.tree_leaves(seg)[0].shape[0]
+        inp = seg if xs is None else (seg, *(x[first:first + n]
+                                             for x in xs))
+        carry, ys = scan_blocks(body, carry, inp, cfg)
+        outs.append(ys)
+        first += n
+    if outs[0] is None:
+        return carry, None
+    return carry, jax.tree_util.tree_map(
+        lambda *a: jnp.concatenate(a), *outs)
+
+
 # --------------------------------------------------------------- cache --
 
+def _cache_shapes(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    """``k``/``v`` ``[L, B, T, n_kv, hd]``; MLA: one latent ``k``
+    ``[L, B, T, latent_dim]``."""
+    if cfg.is_mla:
+        return {"k": (cfg.num_layers, batch, max_len, cfg.latent_dim)}
+    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads,
+             cfg.resolved_head_dim)
+    return {"k": shape, "v": shape}
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    return {
-        "k": jnp.zeros((cfg.num_layers, batch, max_len, kv, hd), dtype),
-        "v": jnp.zeros((cfg.num_layers, batch, max_len, kv, hd), dtype),
-        "pos": jnp.zeros((), jnp.int32),
-    }
+    out = {k: jnp.zeros(v, dtype)
+           for k, v in _cache_shapes(cfg, batch, max_len).items()}
+    return {**out, "pos": jnp.zeros((), jnp.int32)}
 
 
 def abstract_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    return {
-        "k": jax.ShapeDtypeStruct((cfg.num_layers, batch, max_len, kv, hd), dtype),
-        "v": jax.ShapeDtypeStruct((cfg.num_layers, batch, max_len, kv, hd), dtype),
-        "pos": jax.ShapeDtypeStruct((), jnp.int32),
-    }
+    out = {k: jax.ShapeDtypeStruct(v, dtype)
+           for k, v in _cache_shapes(cfg, batch, max_len).items()}
+    return {**out, "pos": jax.ShapeDtypeStruct((), jnp.int32)}
 
 
 # ------------------------------------------------------------- forward --
@@ -78,15 +121,15 @@ def _block(p, x, cfg: ModelConfig, positions, mask, kv=None):
     as uint8 codes."""
     aq = p.get("act_q")
     h = L.apply_norm(p["ln1"], x, cfg)
-    new_kv = L.self_kv(p["attn"], h, cfg, positions, act_q=aq)
-    attn = L.mha(p["attn"], h, cfg, positions, mask, kv=kv, act_q=aq)
+    if cfg.is_mla:
+        new_kv = L.mla_latent(p["attn"], h, cfg, positions)
+        attn = L.mla(p["attn"], h, cfg, positions, mask, latent=kv)
+    else:
+        new_kv = L.self_kv(p["attn"], h, cfg, positions, act_q=aq)
+        attn = L.mha(p["attn"], h, cfg, positions, mask, kv=kv, act_q=aq)
     x = x + attn
     h = L.apply_norm(p["ln2"], x, cfg)
-    if cfg.is_moe:
-        y, aux = M.apply_moe(p["moe"], h, cfg, act_q=aq)
-    else:
-        y, aux = (L.apply_mlp(p["mlp"], h, cfg, act_q=aq),
-                  jnp.zeros((), jnp.float32))
+    y, aux, _ = _ffn(p, h, cfg, aq)
     return x + y, aux, new_kv
 
 
@@ -114,8 +157,8 @@ def forward(
         return (L.constrain_act(y), aux + a), None
 
     body_fn = jax.checkpoint(body) if cfg.remat == "block" else body
-    (x, aux), _ = scan_blocks(body_fn, (x, jnp.zeros((), jnp.float32)),
-                              params["blocks"], cfg)
+    (x, aux), _ = _scan_segments(body_fn, (x, jnp.zeros((), jnp.float32)),
+                                 params, cfg)
     x = L.apply_norm(params["ln_f"], x, cfg)
     return L.logits_fn(params, x, cfg), aux / max(cfg.num_layers, 1)
 
@@ -139,21 +182,23 @@ def prefill(
     else:
         mask = ("causal", None)
 
+    def pad(a):
+        widths = [(0, 0), (0, max_len - s)] + [(0, 0)] * (a.ndim - 2)
+        return jnp.pad(a, widths).astype(cache_dtype)
+
     def body(carry, blk_params):
         x, aux = carry
-        y, a, (k, v) = _block(blk_params, x, cfg, positions, mask)
+        y, a, new_kv = _block(blk_params, x, cfg, positions, mask)
         y = L.constrain_act(y)
-        pad = max_len - s
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(cache_dtype)
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(cache_dtype)
-        return (y, aux + a), (k, v)
+        return (y, aux + a), jax.tree_util.tree_map(pad, new_kv)
 
-    (x, _aux), (ks, vs) = scan_blocks(
-        body, (x, jnp.zeros((), jnp.float32)), params["blocks"], cfg)
+    (x, _aux), entries = _scan_segments(
+        body, (x, jnp.zeros((), jnp.float32)), params, cfg)
     x = L.apply_norm(params["ln_f"], x, cfg)
     logits = L.logits_fn(params, x[:, -1:, :], cfg)
-    cache = {"k": ks, "v": vs, "pos": jnp.asarray(s, jnp.int32)}
-    return logits, cache
+    cache = ({"k": entries} if cfg.is_mla
+             else {"k": entries[0], "v": entries[1]})
+    return logits, {**cache, "pos": jnp.asarray(s, jnp.int32)}
 
 
 def decode_step(params, cache, tokens: jax.Array, cfg: ModelConfig):
@@ -178,6 +223,26 @@ def decode_step(params, cache, tokens: jax.Array, cfg: ModelConfig):
     flash = ll.get_policy().flash_decode and s == 1
     lengths = jnp.broadcast_to(pos + 1, (b,)).astype(jnp.int32)
 
+    def mla_body(carry, layer_in):
+        x, = carry
+        blk_params, lat_cache = layer_in
+        h = L.apply_norm(blk_params["ln1"], x, cfg)
+        lat_cache = jax.lax.dynamic_update_slice_in_dim(
+            lat_cache, L.mla_latent(blk_params["attn"], h, cfg,
+                                    positions).astype(lat_cache.dtype),
+            pos, axis=1)
+        x = x + L.mla(blk_params["attn"], h, cfg, positions, mask,
+                      latent=lat_cache.astype(x.dtype))
+        h = L.apply_norm(blk_params["ln2"], x, cfg)
+        y, _, _ = _ffn(blk_params, h, cfg)
+        return (L.constrain_act(x + y),), lat_cache
+
+    if cfg.is_mla:
+        (x,), lat = _scan_segments(mla_body, (x,), params, cfg,
+                                   xs=(cache["k"],))
+        x = L.apply_norm(params["ln_f"], x, cfg)
+        return L.logits_fn(params, x, cfg), {"k": lat, "pos": pos + 1}
+
     def body(carry, layer_in):
         x, = carry
         blk_params, k_cache, v_cache = layer_in
@@ -198,14 +263,11 @@ def decode_step(params, cache, tokens: jax.Array, cfg: ModelConfig):
                              v_cache.astype(x.dtype)), act_q=aq)
         x = x + attn
         h = L.apply_norm(blk_params["ln2"], x, cfg)
-        if cfg.is_moe:
-            y, _ = M.apply_moe(blk_params["moe"], h, cfg, act_q=aq)
-        else:
-            y = L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq)
+        y, _, _ = _ffn(blk_params, h, cfg, aq)
         return (L.constrain_act(x + y),), (k_cache, v_cache)
 
-    (x,), (ks, vs) = scan_blocks(
-        body, (x,), (params["blocks"], cache["k"], cache["v"]), cfg)
+    (x,), (ks, vs) = _scan_segments(
+        body, (x,), params, cfg, xs=(cache["k"], cache["v"]))
     x = L.apply_norm(params["ln_f"], x, cfg)
     logits = L.logits_fn(params, x, cfg)
     return logits, {"k": ks, "v": vs, "pos": pos + 1}
@@ -225,38 +287,132 @@ def _scatter_token_kv(pages, new, blk_idx, off):
     return pages.at[blk_idx, off].set(new.astype(pages.dtype))
 
 
+def _ffn(blk_params, h, cfg: ModelConfig, aq=None, valid=None):
+    """The block's feed-forward half: ``(y, aux, counts)``.  ``counts``
+    are the rows routed to each held expert where the layer is the
+    serving (dropless) MoE one, else None; a dense MLP has no loss."""
+    if "moe" not in blk_params:
+        return (L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq),
+                jnp.zeros((), jnp.float32), None)
+    if cfg.moe_impl == "dropless":
+        return M.apply_moe_dropless(blk_params["moe"], h, cfg, valid=valid)
+    y, a = M.apply_moe(blk_params["moe"], h, cfg, act_q=aq)
+    return y, a, None
+
+
 def _scan_paged(body, carry, params, view, cfg):
     """Run ``body`` over the layers with the page pools as scan carry.
 
-    Each pool ``[L, N, bs, n_kv, hd]`` is viewed as one flat page array
-    ``[L*N, bs, n_kv, hd]`` (only the major dims merge, so the reshape
-    is a bitcast) and carried through the scan: every layer scatters
-    its new K/V into the flat pool in place.  Handed to the scan as
-    per-layer ``xs`` instead, each layer's whole pool would be sliced
-    out, updated and stacked back, and the stacked result copied once
-    more: the whole pool moved every step to write a few pages.
+    Each pool ``[L, N, bs, ...]`` is viewed as one flat page array
+    ``[L*N, bs, ...]`` (only the major dims merge, so the reshape is a
+    bitcast) and carried through the scan: every layer scatters its
+    new K/V (or latent entries) into the flat pool in place.  Handed to
+    the scan as per-layer ``xs`` instead, each layer's whole pool would
+    be sliced out, updated and stacked back, and the stacked result
+    copied once more: the whole pool moved every step to write a few
+    pages.
 
     ``body(carry, blk_params, k_pages, v_pages, base)`` gets the flat
-    pools and ``base = layer * N``: the layer's page ``p`` is flat page
-    ``base + p`` (its trash page is ``base``), so scatter targets and
-    block tables are offset by ``base``.  It returns ``(carry, k_pages,
-    v_pages)``.  Returns ``(carry, view)`` with the updated pools."""
-    n_layers, n_pages = view.k_pages.shape[:2]
+    pools (``v_pages`` None for a latent pool) and ``base = layer * N``:
+    the layer's page ``p`` is flat page ``base + p`` (its trash page is
+    ``base``), so scatter targets and block tables are offset by
+    ``base``.  It returns ``(carry, k_pages, v_pages, counts)``, counts
+    being the layer's routed rows per held expert or None.  Returns
+    ``(carry, view)`` with the updated pools and, for an MoE model,
+    ``view.expert_counts`` ``[MoE layers, held experts]``."""
+    n_pages = view.k_pages.shape[1]
 
     def flat(pages):
-        return pages.reshape(n_layers * n_pages, *pages.shape[2:])
+        if pages is None:
+            return None
+        return pages.reshape(-1, *pages.shape[2:])
 
     def step(c, layer_in):
         inner, k_pages, v_pages = c
         blk_params, layer = layer_in
-        return body(inner, blk_params, k_pages, v_pages,
-                    layer * n_pages), None
+        inner, k_pages, v_pages, counts = body(
+            inner, blk_params, k_pages, v_pages, layer * n_pages)
+        return (inner, k_pages, v_pages), counts
 
-    (carry, k_pages, v_pages), _ = scan_blocks(
-        step, (carry, flat(view.k_pages), flat(view.v_pages)),
-        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)), cfg)
-    return carry, view._replace(k_pages=k_pages.reshape(view.k_pages.shape),
-                                v_pages=v_pages.reshape(view.v_pages.shape))
+    c, first = (carry, flat(view.k_pages), flat(view.v_pages)), 0
+    for blocks in _segments(params):
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        c, counts = scan_blocks(
+            step, c,
+            (blocks, jnp.arange(first, first + n, dtype=jnp.int32)), cfg)
+        first += n
+    carry, k_pages, v_pages = c
+    view = view._replace(
+        k_pages=k_pages.reshape(view.k_pages.shape),
+        v_pages=None if v_pages is None else v_pages.reshape(
+            view.v_pages.shape))
+    if counts is not None:
+        view = view._replace(expert_counts=counts)
+    return carry, view
+
+
+def _chunk_layers(params, x, view, cfg: ModelConfig, start, valid):
+    """Every layer over a chunk of tokens per row: row ``b``'s tokens
+    sit at absolute positions ``start[b] + i`` and the first
+    ``valid[b]`` of them are real.  Per layer, the chunk's K/V (roped at
+    their positions), or its latent entries, are scattered per token
+    into ``page[pos // bs]``, offset ``pos % bs`` *first* (padding and
+    positions past the table go to the trash page), then attention
+    reads every written position ``<=`` each query's own straight from
+    the pages through the ``flash_prefill_paged`` kernel.  Returns
+    ``(x, view)``."""
+    b, s, _ = x.shape
+    bs = view.block_size
+    max_blk = view.block_tables.shape[1]
+    # cache positions populated once this chunk's scatter lands; rows
+    # with an empty chunk mask everything out (zero attention)
+    kv_lens = jnp.where(valid > 0, start + valid, 0)          # [B]
+    positions = start[:, None] + jnp.arange(s)[None, :]       # [B, S]
+    in_chunk = jnp.arange(s)[None, :] < valid[:, None]        # [B, S]
+
+    # per-token scatter targets: chunk token i of row b lands at page
+    # table[b, pos // bs], offset pos % bs; padding and positions past
+    # the table go to the trash page.
+    tok_ok = in_chunk & (positions // bs < max_blk)           # [B, S]
+    col = jnp.where(tok_ok, positions // bs, 0)
+    page = jnp.where(tok_ok,
+                     jnp.take_along_axis(view.block_tables, col, axis=1),
+                     0)                                       # trash page
+    off = jnp.where(tok_ok, positions % bs, 0)
+
+    def body(carry, blk_params, k_pages, v_pages, base):
+        x, aux = carry
+        aq = blk_params.get("act_q")
+        p = blk_params["attn"]
+        h = L.apply_norm(blk_params["ln1"], x, cfg)
+        if cfg.is_mla:
+            k_pages = k_pages.at[base + page, off].set(
+                L.mla_latent(p, h, cfg, positions).astype(k_pages.dtype))
+            attn = L.mla_paged(p, h, cfg, positions, k_pages,
+                               view.block_tables + base, start, kv_lens)
+        else:
+            k_new, v_new = L.self_kv(p, h, cfg, positions, act_q=aq)
+            if k_pages.dtype == jnp.uint8:
+                # codes-mode cache: quantize-at-write through the
+                # per-head attn_k/attn_v metas (a u8 page stores DNA-TEQ
+                # codes, and a raw astype would bit-truncate floats into
+                # junk codes)
+                k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
+            k_pages = k_pages.at[base + page, off].set(
+                k_new.astype(k_pages.dtype))
+            v_pages = v_pages.at[base + page, off].set(
+                v_new.astype(v_pages.dtype))
+            attn = L.mha_prefill_paged(p, h, cfg, positions, k_pages,
+                                       v_pages, view.block_tables + base,
+                                       start, kv_lens, act_q=aq)
+        x = x + attn
+        h = L.apply_norm(blk_params["ln2"], x, cfg)
+        y, a, counts = _ffn(blk_params, h, cfg, aq, in_chunk)
+        return (L.constrain_act(x + y), aux + a), k_pages, v_pages, counts
+
+    (x, _aux), view = _scan_paged(
+        body, (x, jnp.zeros((), jnp.float32)), params, view, cfg)
+    return x, view
 
 
 def prefill_into_cache(
@@ -275,29 +431,27 @@ def prefill_into_cache(
     zeros: a cold whole-prompt call).  ``view.lengths`` carries the
     *true total* prompt lengths, so the per-row valid token count
     within this chunk is ``clip(lengths - start_pos, 0, S)``; positions
-    past it are padding whose KV is redirected to the trash page.  A
-    row with nothing to do (``start_pos >= lengths``, e.g. a decoding
-    or empty slot riding in a full-width serving dispatch) writes
-    nothing and returns zero attention.
+    past it are padding whose KV is redirected to the trash page (and
+    which an expert layer routes to no expert).  A row with nothing to
+    do (``start_pos >= lengths``, e.g. a decoding or empty slot riding
+    in a full-width serving dispatch) writes nothing and returns zero
+    attention.
 
     The caller must size the block table to cover ``view.lengths``
     (the Engine's admission raises when a prompt exceeds
-    ``max_blocks_per_seq``): the trash-page redirect below exists for
+    ``max_blocks_per_seq``): the trash-page redirect exists for
     *padding* overflow only — a valid token past the table would be
     silently dropped, not an error, since the bound is dynamic
     (``start_pos``) and cannot be asserted under jit.
 
-    Per layer: the chunk's K/V (roped at absolute positions) is
-    scattered per-token at ``page[pos // bs], pos % bs`` *first*, then
-    attention reads every written position ``<=`` each query's own
-    straight from the pages through the ``flash_prefill_paged`` kernel
-    (block-table scalar prefetch, online softmax over pages, in-kernel
-    dequant of narrow KV dtypes).  Within-chunk causality and
-    attention over the cached prefix fall out of the same positional
-    mask — no ``[B, S, T]`` mask or ``[S, T]`` score matrix is ever
-    materialized, and cached prefix pages are never gathered into a
-    contiguous buffer.  Writes never touch a shared prefix page: the
-    scheduler copy-on-writes the boundary page before admission.
+    Per layer (:func:`_chunk_layers`) the chunk's KV lands in the pages
+    first and attention reads it back through the paged flash kernel,
+    so within-chunk causality and attention over the cached prefix fall
+    out of the same positional mask — no ``[B, S, T]`` mask or
+    ``[S, T]`` score matrix is ever materialized, and cached prefix
+    pages are never gathered into a contiguous buffer.  Writes never
+    touch a shared prefix page: the scheduler copy-on-writes the
+    boundary page before admission.
 
     Returns (last_logits [B, 1, V] taken at each row's true last token
     — meaningful only for rows whose final chunk this is — and the
@@ -305,57 +459,10 @@ def prefill_into_cache(
     """
     x = L.embed_tokens(params["embed"], tokens, cfg)
     b, s, _ = x.shape
-    bs = view.block_size
-    max_blk = view.block_tables.shape[1]
     start = (jnp.zeros((b,), jnp.int32) if start_pos is None
              else start_pos.astype(jnp.int32))                # [B]
     valid = jnp.clip(view.lengths - start, 0, s)              # [B]
-    # cache positions populated once this chunk's scatter lands; rows
-    # with an empty chunk mask everything out (zero attention, above)
-    kv_lens = jnp.where(valid > 0, start + valid, 0)          # [B]
-    positions = start[:, None] + jnp.arange(s)[None, :]       # [B, S]
-
-    # per-token scatter targets: chunk token i of row b lands at page
-    # table[b, pos // bs], offset pos % bs; padding and positions past
-    # the table go to the trash page.
-    tok_ok = ((jnp.arange(s)[None, :] < valid[:, None])
-              & (positions // bs < max_blk))                  # [B, S]
-    col = jnp.where(tok_ok, positions // bs, 0)
-    page = jnp.where(tok_ok,
-                     jnp.take_along_axis(view.block_tables, col, axis=1),
-                     0)                                       # trash page
-    off = jnp.where(tok_ok, positions % bs, 0)
-
-    def body(carry, blk_params, k_pages, v_pages, base):
-        x, aux = carry
-        aq = blk_params.get("act_q")
-        h = L.apply_norm(blk_params["ln1"], x, cfg)
-        k_new, v_new = L.self_kv(blk_params["attn"], h, cfg, positions,
-                                 act_q=aq)
-        if k_pages.dtype == jnp.uint8:
-            # codes-mode cache: quantize-at-write through the per-head
-            # attn_k/attn_v metas (a u8 page stores DNA-TEQ codes, and
-            # a raw astype would bit-truncate floats into junk codes)
-            k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
-        k_pages = k_pages.at[base + page, off].set(
-            k_new.astype(k_pages.dtype))
-        v_pages = v_pages.at[base + page, off].set(
-            v_new.astype(v_pages.dtype))
-        attn = L.mha_prefill_paged(blk_params["attn"], h, cfg, positions,
-                                   k_pages, v_pages,
-                                   view.block_tables + base, start,
-                                   kv_lens, act_q=aq)
-        x = x + attn
-        h = L.apply_norm(blk_params["ln2"], x, cfg)
-        if cfg.is_moe:
-            y, a = M.apply_moe(blk_params["moe"], h, cfg, act_q=aq)
-        else:
-            y, a = (L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq),
-                    jnp.zeros((), jnp.float32))
-        return (L.constrain_act(x + y), aux + a), k_pages, v_pages
-
-    (x, _aux), view = _scan_paged(
-        body, (x, jnp.zeros((), jnp.float32)), params, view, cfg)
+    x, view = _chunk_layers(params, x, view, cfg, start, valid)
     x = L.apply_norm(params["ln_f"], x, cfg)
     idx = jnp.clip(view.lengths - 1 - start, 0, s - 1)
     x_last = jnp.take_along_axis(
@@ -369,12 +476,13 @@ def decode_step_paged(params, view, tokens: jax.Array, active: jax.Array,
     """One continuous-batching decode step over the paged cache.
 
     tokens: [B, 1] — last sampled token per slot; active: [B] bool.
-    Per slot, the new token's KV is scattered to page
+    Per slot, the new token's KV (or latent entry) is scattered to page
     ``table[len // bs]``, offset ``len % bs`` (inactive slots write the
-    trash page), then attention runs through the block-table
-    flash-decode kernel with per-slot lengths (+1 for the token just
-    written; 0 for inactive slots, which therefore return zeros).
-    Returns (logits [B, 1, V], updated view with active lengths +1).
+    trash page and route to no expert), then attention runs through the
+    block-table flash-decode kernel with per-slot lengths (+1 for the
+    token just written; 0 for inactive slots, which therefore return
+    zeros).  Returns (logits [B, 1, V], updated view with active
+    lengths +1).
     """
     x = L.constrain_act(L.embed_tokens(params["embed"], tokens, cfg))
     b, s, _ = x.shape
@@ -393,27 +501,31 @@ def decode_step_paged(params, view, tokens: jax.Array, active: jax.Array,
     def body(carry, blk_params, k_pages, v_pages, base):
         x, = carry
         aq = blk_params.get("act_q")
+        p = blk_params["attn"]
         h = L.apply_norm(blk_params["ln1"], x, cfg)
-        k_new, v_new = L.self_kv(blk_params["attn"], h, cfg, positions,
-                                 act_q=aq)
-        if k_pages.dtype == jnp.uint8:
-            # codes-mode cache: quantize-at-write (see prefill body)
-            k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
-        k_pages = _scatter_token_kv(k_pages, k_new[:, 0], base + blk_idx,
-                                    off)
-        v_pages = _scatter_token_kv(v_pages, v_new[:, 0], base + blk_idx,
-                                    off)
-        attn = L.mha_decode_paged(blk_params["attn"], h, cfg, positions,
-                                  k_pages, v_pages,
-                                  view.block_tables + base, attn_lengths,
-                                  act_q=aq)
+        if cfg.is_mla:
+            k_pages = k_pages.at[base + blk_idx, off].set(
+                L.mla_latent(p, h, cfg, positions)[:, 0].astype(
+                    k_pages.dtype))
+            attn = L.mla_paged(p, h, cfg, positions, k_pages,
+                               view.block_tables + base, attn_lengths - 1,
+                               attn_lengths)
+        else:
+            k_new, v_new = L.self_kv(p, h, cfg, positions, act_q=aq)
+            if k_pages.dtype == jnp.uint8:
+                # codes-mode cache: quantize-at-write (see _chunk_layers)
+                k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
+            k_pages = _scatter_token_kv(k_pages, k_new[:, 0],
+                                        base + blk_idx, off)
+            v_pages = _scatter_token_kv(v_pages, v_new[:, 0],
+                                        base + blk_idx, off)
+            attn = L.mha_decode_paged(p, h, cfg, positions, k_pages,
+                                      v_pages, view.block_tables + base,
+                                      attn_lengths, act_q=aq)
         x = x + attn
         h = L.apply_norm(blk_params["ln2"], x, cfg)
-        if cfg.is_moe:
-            y, _ = M.apply_moe(blk_params["moe"], h, cfg, act_q=aq)
-        else:
-            y = L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq)
-        return (L.constrain_act(x + y),), k_pages, v_pages
+        y, _, counts = _ffn(blk_params, h, cfg, aq, active[:, None])
+        return (L.constrain_act(x + y),), k_pages, v_pages, counts
 
     (x,), view = _scan_paged(body, (x,), params, view, cfg)
     x = L.apply_norm(params["ln_f"], x, cfg)
@@ -438,12 +550,12 @@ def spec_verify_into_cache(
 
     Mechanically this is :func:`prefill_into_cache` with the valid
     count supplied by the caller instead of derived from
-    ``view.lengths``: every valid position's KV scatters into the
-    pages first (codes-mode pages quantize-at-write, padding goes to
-    the trash page), then the chunked flash kernel attends each
-    position against the cached prefix plus the window's own causal
-    left — so position ``i``'s logits are computed *as if* drafts
-    ``< i`` were already accepted.
+    ``view.lengths`` (both run :func:`_chunk_layers`): every valid
+    position's KV scatters into the pages first (codes-mode pages
+    quantize-at-write, padding goes to the trash page), then the
+    chunked flash kernel attends each position against the cached
+    prefix plus the window's own causal left — so position ``i``'s
+    logits are computed *as if* drafts ``< i`` were already accepted.
 
     The greedy commit happens in-dispatch (one host round-trip per
     tick, same policy as the decode step): returns
@@ -463,49 +575,9 @@ def spec_verify_into_cache(
     """
     x = L.embed_tokens(params["embed"], tokens, cfg)
     b, s, _ = x.shape
-    bs = view.block_size
-    max_blk = view.block_tables.shape[1]
     start = start_pos.astype(jnp.int32)                       # [B]
     valid = jnp.clip(n_tokens.astype(jnp.int32), 0, s)        # [B]
-    kv_lens = jnp.where(valid > 0, start + valid, 0)          # [B]
-    positions = start[:, None] + jnp.arange(s)[None, :]       # [B, S]
-
-    tok_ok = ((jnp.arange(s)[None, :] < valid[:, None])
-              & (positions // bs < max_blk))                  # [B, S]
-    col = jnp.where(tok_ok, positions // bs, 0)
-    page = jnp.where(tok_ok,
-                     jnp.take_along_axis(view.block_tables, col, axis=1),
-                     0)                                       # trash page
-    off = jnp.where(tok_ok, positions % bs, 0)
-
-    def body(carry, blk_params, k_pages, v_pages, base):
-        x, aux = carry
-        aq = blk_params.get("act_q")
-        h = L.apply_norm(blk_params["ln1"], x, cfg)
-        k_new, v_new = L.self_kv(blk_params["attn"], h, cfg, positions,
-                                 act_q=aq)
-        if k_pages.dtype == jnp.uint8:
-            # codes-mode cache: quantize-at-write (see prefill body)
-            k_new, v_new = L.encode_kv_codes(k_new, v_new, aq)
-        k_pages = k_pages.at[base + page, off].set(
-            k_new.astype(k_pages.dtype))
-        v_pages = v_pages.at[base + page, off].set(
-            v_new.astype(v_pages.dtype))
-        attn = L.mha_prefill_paged(blk_params["attn"], h, cfg, positions,
-                                   k_pages, v_pages,
-                                   view.block_tables + base, start,
-                                   kv_lens, act_q=aq)
-        x = x + attn
-        h = L.apply_norm(blk_params["ln2"], x, cfg)
-        if cfg.is_moe:
-            y, a = M.apply_moe(blk_params["moe"], h, cfg, act_q=aq)
-        else:
-            y, a = (L.apply_mlp(blk_params["mlp"], h, cfg, act_q=aq),
-                    jnp.zeros((), jnp.float32))
-        return (L.constrain_act(x + y), aux + a), k_pages, v_pages
-
-    (x, _aux), view = _scan_paged(
-        body, (x, jnp.zeros((), jnp.float32)), params, view, cfg)
+    x, view = _chunk_layers(params, x, view, cfg, start, valid)
     x = L.apply_norm(params["ln_f"], x, cfg)
     logits = L.logits_fn(params, x, cfg)                      # [B, S, V]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # [B, S]

@@ -208,8 +208,9 @@ class KVHandoff:
     request: Request
     tokens: list[int]             # sampled so far (len 1 after prefill)
     length: int                   # KV tokens written (== prompt length)
-    k_pages: np.ndarray           # [L, n_pages, bs, n_kv, hd]
-    v_pages: np.ndarray
+    k_pages: np.ndarray           # [L, n_pages, bs, n_kv, hd] (latent:
+                                  # [L, n_pages, bs, latent_dim])
+    v_pages: np.ndarray | None    # None for a latent pool
     block_size: int
     submit_t: float = 0.0
     admit_t: float | None = None
@@ -231,7 +232,8 @@ class KVHandoff:
     @property
     def nbytes(self) -> int:
         """Bytes a real deployment would move across the interconnect."""
-        return self.k_pages.nbytes + self.v_pages.nbytes
+        return self.k_pages.nbytes + (0 if self.v_pages is None
+                                      else self.v_pages.nbytes)
 
 
 _QUEUED, _RUNNING, _FINISHED = "queued", "running", "finished"
@@ -252,6 +254,11 @@ _QUEUED, _RUNNING, _FINISHED = "queued", "running", "finished"
 
 def _donate(*argnums):
     return argnums if jax.default_backend() != "cpu" else ()
+
+
+def _host(x):
+    """A step's small device result on the host (None stays None)."""
+    return None if x is None else np.asarray(x)
 
 
 # Both wrappers also return a per-row finite flag over the logits the
@@ -402,6 +409,11 @@ class Engine:
             # time params are final — validated below, after the
             # calibration step has had its chance to fit them.
             self.kv_dtype = jnp.dtype(jnp.uint8)
+        if cfg.is_mla and (act_quant is not None or self.kv_codes):
+            raise ValueError(
+                "latent attention serves float activations and a float "
+                "(or float8) latent pool: act_quant and kv_codes have no "
+                "latent sites")
         if params is None:
             params = self.api.init(jax.random.PRNGKey(rng_seed),
                                    dtype=jnp.float32)
@@ -453,11 +465,7 @@ class Engine:
         if num_blocks is None:
             # full occupancy: every slot can run to max_seq_len (+ trash)
             num_blocks = ec.num_slots * max_blk + 1
-        self.cache = PagedKVCache(
-            num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.resolved_head_dim, num_slots=ec.num_slots,
-            block_size=ec.block_size, num_blocks=num_blocks,
-            max_blocks_per_seq=max_blk, dtype=self.kv_dtype)
+        self.cache = self._new_cache(ec.num_slots, num_blocks, max_blk)
         self.prefix: PrefixCache | None = (
             PrefixCache(self.cache.allocator, ec.block_size)
             if ec.prefix_cache else None)
@@ -534,6 +542,18 @@ class Engine:
         self._drift_db: dict[str, float] = {}
         self._drift_delta_db: dict[str, float] = {}
         self._drift_registered: set[str] = set()
+
+    def _new_cache(self, num_slots: int, num_blocks: int,
+                   max_blk: int) -> PagedKVCache:
+        """A page pool of this model's kind: K and V pools, or one latent
+        pool for latent attention."""
+        cfg = self.cfg
+        return PagedKVCache(
+            num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
+            head_dim=cfg.resolved_head_dim, num_slots=num_slots,
+            block_size=self.engine_cfg.block_size, num_blocks=num_blocks,
+            max_blocks_per_seq=max_blk, dtype=self.kv_dtype,
+            latent_dim=cfg.latent_dim if cfg.is_mla else 0)
 
     def _register_gauges(self) -> None:
         """Callback gauges over live engine state: evaluated at read
@@ -884,6 +904,15 @@ class Engine:
                                     tok_s=self._tick_tokens / dt_tick)
         return finished
 
+    def _count_experts(self, counts: np.ndarray | None) -> None:
+        """Add one dispatch's rows per (MoE layer, held expert) to the
+        ``engine.moe.*`` counters."""
+        if counts is None:
+            return
+        self.moe_routed_tokens += int(counts.sum())
+        self.moe_busiest_expert_tokens += int(counts.max(axis=1).sum())
+        self.moe_active_experts += int((counts > 0).sum())
+
     def _attn_accounting(self, q_tokens: int, kv_tokens: int) -> None:
         """Analytic attention-boundary traffic for one dispatched row:
         bytes the attention kernel reads (q + touched KV pages),
@@ -893,19 +922,23 @@ class Engine:
         kernels cannot count, and the model is exact for the dense
         page-block access pattern both kernels use."""
         cfg = self.cfg
-        hd = cfg.resolved_head_dim
-        n_kv = cfg.num_kv_heads
+        if cfg.is_mla:
+            # MQA over the latent pool, read once as keys, once as values
+            q_width, kv_width = cfg.num_heads * cfg.latent_dim, \
+                2 * cfg.latent_dim
+        else:
+            q_width = cfg.num_heads * cfg.resolved_head_dim
+            kv_width = cfg.num_kv_heads * cfg.resolved_head_dim * 2
         bs = self.engine_cfg.block_size
         act_item = 1 if self.kv_codes else 4       # u8 codes vs f32
-        q_bytes = q_tokens * cfg.num_heads * hd * act_item
-        out_bytes = q_tokens * cfg.num_heads * hd * act_item
+        q_bytes = q_tokens * q_width * act_item
+        out_bytes = q_tokens * q_width * act_item
         blocks = -(-kv_tokens // bs)
-        kv_bytes = blocks * bs * n_kv * hd * 2 * self.kv_dtype.itemsize
+        kv_bytes = blocks * bs * kv_width * self.kv_dtype.itemsize
         self.attn_bytes_read += q_bytes + kv_bytes
         self.attn_act_bytes += q_bytes + out_bytes
         if self.kv_codes:
-            self.attn_dequants += (q_tokens * cfg.num_heads * hd
-                                   + blocks * bs * n_kv * hd * 2)
+            self.attn_dequants += q_tokens * q_width + blocks * bs * kv_width
 
     def _decode_tick(self, active) -> list[Completion]:
         ec = self.engine_cfg
@@ -953,10 +986,12 @@ class Engine:
             nxt = np.asarray(nxt_dev)   # blocks until the step is done
             ok = np.array(ok_dev)       # writable: chaos may force a row low
             lengths = np.asarray(view.lengths)
+            counts = _host(view.expert_counts)
         t1 = self._clock()
         dt = t1 - t0
         with self._span("engine.decode.commit"):
             self.cache.update_pages(view)
+            self._count_experts(counts)
             # the device-computed lengths are the single source of truth
             # for *decoding* slots; prefilling slots keep their host value
             # (their lengths ride through the decode step unchanged)
@@ -1068,10 +1103,12 @@ class Engine:
             g = np.asarray(g_dev)       # blocks until the dispatch is done
             acc = np.asarray(acc_dev)
             ok = np.array(ok_dev)       # writable: chaos may force a row low
+            counts = _host(view.expert_counts)
         t1 = self._clock()
         dt = t1 - t0
         with self._span("engine.decode.commit"):
             self.cache.update_pages(view)
+            self._count_experts(counts)
             self.total_decode_steps += 1
             self.spec_dispatches += 1
             if self.chaos is not None:
@@ -1185,12 +1222,7 @@ class Engine:
         ec = self.engine_cfg
         n, longest = len(prompts), max(len(p) for p in prompts)
         max_blk = -(-longest // ec.block_size)
-        cache = PagedKVCache(
-            num_layers=self.cfg.num_layers,
-            num_kv_heads=self.cfg.num_kv_heads,
-            head_dim=self.cfg.resolved_head_dim, num_slots=n,
-            block_size=ec.block_size, num_blocks=n * max_blk + 1,
-            max_blocks_per_seq=max_blk, dtype=self.kv_dtype)
+        cache = self._new_cache(n, n * max_blk + 1, max_blk)
         for i, p in enumerate(prompts):
             cache.bind_slot(i, len(p), reserved=False)
         step = jax.jit(lambda params, toks, view, start:
@@ -1888,10 +1920,12 @@ class Engine:
         with self._span("engine.wait.prefill"):
             nxt = np.asarray(nxt_dev)   # blocks until the dispatch is done
             ok = np.array(ok_dev)       # writable: chaos may force a row low
+            counts = _host(view.expert_counts)
         t1 = self._clock()
         dt = t1 - t0
         with self._span("engine.prefill.commit"):
             self.cache.update_pages(view)
+            self._count_experts(counts)
 
             # pages this dispatch wrote, recorded per-row BEFORE
             # retiring / faulting mutates the block tables
@@ -2022,6 +2056,16 @@ _ENGINE_COUNTERS = {
     "spec_accepted":
         ("engine.spec.accepted", "drafted tokens accepted by greedy "
                                  "verification"),
+    "moe_routed_tokens":
+        ("engine.moe.routed_tokens", "(row, expert) pairs sent to the "
+                                     "experts held here"),
+    "moe_busiest_expert_tokens":
+        ("engine.moe.busiest_expert_tokens", "per dispatch and MoE layer, "
+                                             "the busiest held expert's "
+                                             "rows, summed"),
+    "moe_active_experts":
+        ("engine.moe.active_experts", "per dispatch and MoE layer, the "
+                                      "held experts given a row, summed"),
     "drift_checks":
         ("calib.drift.checks", "drift-guard SQNR probes run"),
     "drift_warnings":

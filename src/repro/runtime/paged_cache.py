@@ -4,7 +4,9 @@ The contiguous serving cache pays ``O(max_len)`` HBM per request the
 moment it is admitted — exactly the decoded-operand data movement the
 PuM literature says dominates modern workloads.  Here KV lives in a
 pool of fixed-size pages (``[num_layers, num_blocks, block_size, n_kv,
-hd]``); a sequence owns an ordered list of page ids (its *block
+hd]``, one pool for K and one for V; or, for latent attention, one
+latent pool ``[num_layers, num_blocks, block_size, latent_dim]`` and no
+V pool); a sequence owns an ordered list of page ids (its *block
 table*), pages are handed out by a free-list allocator as the sequence
 actually grows, and retirement returns them to the pool — memory
 scales with live tokens, not ``max_len``.
@@ -25,7 +27,8 @@ Layout / invariants
   pinned from the trie are read-only and the engine copy-on-writes
   (``cow_slot_page``) before the first write into a shared page.
 
-Device state (``k_pages``/``v_pages``) is functionally updated inside
+Device state (``k_pages``/``v_pages``; ``v_pages`` is None for a latent
+pool) is functionally updated inside
 jitted prefill/decode steps; the host keeps the allocator, block
 tables, and lengths, and re-materializes the small int32 view tensors
 each step.
@@ -47,15 +50,21 @@ TRASH_PAGE = 0
 class PagedView(NamedTuple):
     """The jit-facing slice of the cache: pure arrays, a valid pytree.
 
-    k_pages/v_pages: [L, num_blocks, block_size, n_kv, hd]
+    k_pages/v_pages: [L, num_blocks, block_size, n_kv, hd]; a latent
+                     pool is k_pages [L, num_blocks, block_size,
+                     latent_dim] with v_pages None
     block_tables:    [B, max_blocks_per_seq] int32 (physical page ids)
     lengths:         [B] int32 — tokens already present per sequence
+    expert_counts:   what a step returns for an MoE model: rows routed
+                     to each held expert, [MoE layers, held experts]
+                     int32 (None going in)
     """
 
     k_pages: jax.Array
-    v_pages: jax.Array
+    v_pages: jax.Array | None
     block_tables: jax.Array
     lengths: jax.Array
+    expert_counts: jax.Array | None = None
 
     @property
     def block_size(self) -> int:
@@ -150,14 +159,23 @@ class PagedKVCache:
 
     def __init__(self, *, num_layers: int, num_kv_heads: int, head_dim: int,
                  num_slots: int, block_size: int, num_blocks: int,
-                 max_blocks_per_seq: int, dtype=jnp.float32):
+                 max_blocks_per_seq: int, dtype=jnp.float32,
+                 latent_dim: int = 0):
+        """``latent_dim`` > 0 makes one latent pool of that many numbers
+        per token (``num_kv_heads``/``head_dim`` unused)."""
         self.block_size = block_size
         self.num_slots = num_slots
         self.max_blocks_per_seq = max_blocks_per_seq
         self.dtype = jnp.dtype(dtype)
-        shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
-        self.k_pages = jnp.zeros(shape, self.dtype)
-        self.v_pages = jnp.zeros(shape, self.dtype)
+        if latent_dim:
+            self.k_pages = jnp.zeros(
+                (num_layers, num_blocks, block_size, latent_dim), self.dtype)
+            self.v_pages = None
+        else:
+            shape = (num_layers, num_blocks, block_size, num_kv_heads,
+                     head_dim)
+            self.k_pages = jnp.zeros(shape, self.dtype)
+            self.v_pages = jnp.zeros(shape, self.dtype)
         self.allocator = BlockAllocator(num_blocks)
         # host-side metadata; rows of unused slots point at the trash page
         self.block_tables = np.full((num_slots, max_blocks_per_seq),
@@ -173,10 +191,15 @@ class PagedKVCache:
         return max(1, math.ceil(tokens / self.block_size))
 
     @property
+    def pools(self) -> list:
+        """The page pools held: K and V, or the one latent pool."""
+        return [p for p in (self.k_pages, self.v_pages) if p is not None]
+
+    @property
     def bytes_per_block(self) -> int:
-        # K and V page for every layer
-        l, _, bs, kv, hd = self.k_pages.shape
-        return 2 * l * bs * kv * hd * self.dtype.itemsize
+        # every pool's page, every layer
+        return (sum(math.prod(p.shape) // p.shape[1] for p in self.pools)
+                * self.dtype.itemsize)
 
     def kv_bytes_in_use(self) -> int:
         return self.allocator.blocks_in_use * self.bytes_per_block
@@ -234,7 +257,8 @@ class PagedKVCache:
         assert old in self.slot_shared[slot], (slot, col, old)
         (new,) = self.allocator.alloc(1, reserved=False)
         self.k_pages = self.k_pages.at[:, new].set(self.k_pages[:, old])
-        self.v_pages = self.v_pages.at[:, new].set(self.v_pages[:, old])
+        if self.v_pages is not None:
+            self.v_pages = self.v_pages.at[:, new].set(self.v_pages[:, old])
         self.slot_blocks[slot][col] = new
         self.slot_shared[slot].discard(old)
         self.block_tables[slot, col] = new
@@ -275,11 +299,12 @@ class PagedKVCache:
         return blocks
 
     # ------------------------------------------------------- migration
-    def export_slot(self, slot: int) -> tuple[np.ndarray, np.ndarray]:
+    def export_slot(self, slot: int) -> tuple[np.ndarray, np.ndarray | None]:
         """Copy a slot's KV pages out of the pool for migration to
         another worker's cache: the disaggregation handoff unit.  The
         result is host-resident (``np``) page *content* in block-table
-        order — ``[L, n_pages, bs, n_kv, hd]`` for K and V — exactly
+        order — ``[L, n_pages, bs, n_kv, hd]`` for K and V (a latent
+        pool's ``[L, n_pages, bs, latent_dim]`` and None) — exactly
         what :meth:`import_slot` scatters into a peer pool, so the
         decode side never recomputes prefill.  Shared (trie-pinned)
         pages are exported too: the importing pool has no notion of
@@ -291,11 +316,11 @@ class PagedKVCache:
         assert blocks, f"slot {slot} has no pages to export"
         idx = jnp.asarray(blocks, jnp.int32)
         k = np.asarray(self.k_pages[:, idx])
-        v = np.asarray(self.v_pages[:, idx])
+        v = None if self.v_pages is None else np.asarray(self.v_pages[:, idx])
         return k, v
 
     def import_slot(self, slot: int, length: int, k_pages: np.ndarray,
-                    v_pages: np.ndarray, *, reserved: bool = False
+                    v_pages: np.ndarray | None, *, reserved: bool = False
                     ) -> list[int]:
         """Adopt migrated KV content into this pool: allocate fresh
         pages, scatter the exported bytes in, and bind the slot as if
@@ -304,19 +329,21 @@ class PagedKVCache:
         and block-table order migrate, which is all the paged kernels
         read.  Returns the newly allocated block list."""
         assert not self.slot_blocks[slot], "slot already bound"
-        l, n, bs, kv, hd = k_pages.shape
-        el, _, ebs, ekv, ehd = self.k_pages.shape
-        assert (l, bs, kv, hd) == (el, ebs, ekv, ehd), (
-            f"page geometry mismatch: import {(l, bs, kv, hd)} vs pool "
-            f"{(el, ebs, ekv, ehd)}")
+        n = k_pages.shape[1]
+        got = (k_pages.shape[:1] + k_pages.shape[2:], v_pages is None)
+        want = (self.k_pages.shape[:1] + self.k_pages.shape[2:],
+                self.v_pages is None)
+        assert got == want, (
+            f"page geometry mismatch: import {got} vs pool {want}")
         assert n == self.blocks_for(length), (n, length, self.block_size)
         assert n <= self.max_blocks_per_seq, (n, self.max_blocks_per_seq)
         blocks = self.allocator.alloc(n, reserved=reserved)
         idx = jnp.asarray(blocks, jnp.int32)
         self.k_pages = self.k_pages.at[:, idx].set(
             jnp.asarray(k_pages, self.dtype))
-        self.v_pages = self.v_pages.at[:, idx].set(
-            jnp.asarray(v_pages, self.dtype))
+        if v_pages is not None:
+            self.v_pages = self.v_pages.at[:, idx].set(
+                jnp.asarray(v_pages, self.dtype))
         self.slot_blocks[slot] = blocks
         self.slot_shared[slot] = set()
         self.block_tables[slot, :] = TRASH_PAGE
@@ -326,23 +353,26 @@ class PagedKVCache:
 
     # ------------------------------------------------------- checksums
     def page_checksum(self, page: int) -> int:
-        """CRC32 over a page's K and V bytes, all layers.  The engine's
-        optional per-tick checksum audit records this after every
-        legitimate write and verifies it before the next dispatch, so a
-        bit flip in stored KV is caught before it is ever attended."""
-        k = np.asarray(self.k_pages[:, page])
-        v = np.asarray(self.v_pages[:, page])
-        return zlib.crc32(v.tobytes(), zlib.crc32(k.tobytes()))
+        """CRC32 over a page's bytes in every pool (K and V, or the
+        latent pool), all layers.  The engine's optional per-tick
+        checksum audit records this after every legitimate write and
+        verifies it before the next dispatch, so a bit flip in stored KV
+        is caught before it is ever attended."""
+        crc = 0
+        for pool in self.pools:
+            crc = zlib.crc32(np.asarray(pool[:, page]).tobytes(), crc)
+        return crc
 
     def corrupt_page(self, page: int) -> None:
         """Chaos-test helper: deterministically flip one stored element
         of ``page`` to a value it cannot already hold (7 -> 11, else
         -> 7), guaranteeing the checksum changes in every KV dtype."""
         assert page != TRASH_PAGE, "corrupting the trash page is a no-op"
-        cur = self.k_pages[0, page, 0, 0, 0]
+        at = (0, page) + (0,) * (self.k_pages.ndim - 2)
+        cur = self.k_pages[at]
         bad = jnp.where(cur == 7, jnp.asarray(11, self.dtype),
                         jnp.asarray(7, self.dtype))
-        self.k_pages = self.k_pages.at[0, page, 0, 0, 0].set(bad)
+        self.k_pages = self.k_pages.at[at].set(bad)
 
     # ------------------------------------------------------------ audit
     def audit_partition(self, trie_pages: set[int],

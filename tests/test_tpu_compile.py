@@ -2,7 +2,8 @@
 
 Each test lowers one kernel variant at qwen3-1.7b widths (d_model 2048,
 d_ff 6144, 8 KV heads x 128, 2 query heads per KV head, vocab 151,936,
-pages of 16, 8 slots, prefill chunk 256) and compiles it with the TPU
+pages of 16, 8 slots, prefill chunk 256), or at deepseek-v2-lite's for
+its latent attention and grouped experts, and compiles it with the TPU
 compiler for one chip of a ``v5e:2x2`` topology, which needs no chip
 attached.  That catches what interpret mode cannot: layouts Mosaic
 refuses, casts it has no lowering for, VMEM over-use.  Nothing runs.
@@ -36,6 +37,10 @@ from repro.kernels.lut_dequant_matmul.lut_dequant_matmul import (
     lut_dequant_matmul_dual_kernel,
     lut_dequant_matmul_gated_kernel,
     lut_dequant_matmul_kernel,
+)
+from repro.kernels.moe_expert.moe_expert import (
+    moe_expert_down_kernel,
+    moe_expert_gated_kernel,
 )
 
 D, FF, VOCAB = 2048, 6144, 151_936
@@ -140,6 +145,41 @@ def test_flash_prefill_paged_codes(one_chip):
              + _PAGING + [((SLOTS,), jnp.int32)])
 
 
+# deepseek-v2-lite: 16 held experts of 1408, 16 query heads over one
+# latent "KV head" of 512 + 64 numbers padded to 640 lanes
+EXPERTS, EXPERT_FF, LATENT, HEADS = 16, 1408, 640, 16
+BF16 = jnp.bfloat16
+
+
+@pytest.mark.parametrize("tm,tiles", [(16, 22), (128, 208)])
+def test_moe_expert_grouped(one_chip, tm, tiles):
+    """Decode-sized (16 rows, top-6) and prefill-sized (4,096 rows)
+    dispatches' row buffers."""
+    rows = tm * tiles
+    sched = [((tiles,), jnp.int32), ((), jnp.int32)]
+    _compile(one_chip, moe_expert_gated_kernel,
+             [((rows, D), BF16), ((EXPERTS, D, EXPERT_FF), U8),
+              ((EXPERTS, D, EXPERT_FF), U8), ((2, 4), F32)] + sched,
+             tm=tm, bn=128, out_dtype=BF16)
+    _compile(one_chip, moe_expert_down_kernel,
+             [((rows, EXPERT_FF), BF16), ((EXPERTS, EXPERT_FF, D), U8),
+              ((4,), F32)] + sched, tm=tm, bn=256)
+
+
+@pytest.mark.parametrize("s", [1, CHUNK])
+def test_flash_prefill_latent_pages(one_chip, s):
+    """One KV head as ``[N, bs, 640]`` pages: the kernel takes the pool
+    in place (at 576 lanes, or as ``[N, bs, 1, hd]``, XLA copies the
+    whole pool into the kernel's layout first)."""
+    pages = ((POOL, BLOCK, LATENT), jnp.float8_e4m3fn)
+    compiled = _compile(
+        one_chip, flash_prefill_paged_kernel,
+        [((SLOTS, s, 1, HEADS, LATENT), BF16), pages, pages] + _PAGING
+        + [((SLOTS,), jnp.int32)])
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        POOL * BLOCK * LATENT
+
+
 # ------------------------------------------------ whole serving steps --
 #
 # The donated decode and prefill steps as the Engine jits them, at the
@@ -156,7 +196,8 @@ _INST = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*\w+\[([\d,]+)\]\S*\s+"
 _VIEWS = ("parameter", "bitcast", "get-tuple-element", "scatter")
 
 
-def _pool_sized_ops(hlo: str) -> list:
+def _pool_sized_ops(hlo: str, layer_pool: int = LAYER_POOL,
+                    minor: int = HD) -> list:
     """Instructions of ``hlo`` that yield an array of at least one
     layer's pool (minor dim the head size) and do more than view it or
     scatter into it in place (a fusion that aliases its operand)."""
@@ -167,7 +208,7 @@ def _pool_sized_ops(hlo: str) -> list:
             continue
         dims = [int(d) for d in m.group(1).split(",")]
         op = m.group(2)
-        if (math.prod(dims) < LAYER_POOL or dims[-1] != HD or op in _VIEWS
+        if (math.prod(dims) < layer_pool or dims[-1] != minor or op in _VIEWS
                 or (op == "fusion" and '"aliasing_operands"' in line)):
             continue
         bad.append(line.strip()[:160])
@@ -230,3 +271,43 @@ def test_step_moves_no_pool(one_chip, step, kv):
         compiled = lowered.compile()
     assert _pool_sized_ops(compiled.as_text()) == []
     assert compiled.memory_analysis().temp_size_in_bytes < LAYER_POOL
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_latent_step_moves_no_pool(one_chip, step):
+    """The deepseek-v2-lite steps (a dense layer and an MoE layer, 7-bit
+    weight codes, a float32 router, a float8 latent pool of 4,097
+    pages) keep their latent pool in place, as the K/V pools above (the
+    prefill step's temp is the expert layer's row buffers)."""
+    from repro.configs import get_config
+    from repro.core import lama_layers as ll
+    from repro.models import api as mapi
+    from repro.runtime import engine
+    from repro.runtime.paged_cache import PagedView
+
+    cfg = get_config("deepseek-v2-lite").replace(num_layers=LAYERS)
+    api = mapi.get_model(cfg)
+    params, _ = ll.abstract_quantize(api.abstract_params(),
+                                     api.logical_axes(), bits=7)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), params)
+    layer_pool = PAGES * BLOCK * cfg.latent_dim
+    view = PagedView(spec((LAYERS, PAGES, BLOCK, cfg.latent_dim),
+                          jnp.float8_e4m3fn), None,
+                     spec((SLOTS, MAX_BLK), jnp.int32),
+                     spec((SLOTS,), jnp.int32))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            ll.policy(autotune=False):
+        if step == "decode":
+            fn = engine._jit_decode.__wrapped__(api.decode_step_paged)
+            lowered = fn.lower(params, view, spec((SLOTS, 1), jnp.int32),
+                               spec((SLOTS,), jnp.bool_), cfg)
+        else:
+            fn = engine._jit_prefill.__wrapped__(api.prefill_into_cache)
+            lowered = fn.lower(params, spec((SLOTS, CHUNK), jnp.int32),
+                               view, spec((SLOTS,), jnp.int32), cfg)
+        compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert "moe_expert_gated_kernel" in hlo and "moe_expert_down_kernel" in hlo
+    assert _pool_sized_ops(hlo, layer_pool, cfg.latent_dim) == []
