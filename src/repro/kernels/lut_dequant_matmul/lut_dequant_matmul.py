@@ -32,8 +32,11 @@ so chains of quantized matmuls stay code-in/code-out: the only f32 form
 of the intermediate is the VMEM accumulator tile.
 
 Grid: (M/bm, N/bn, K/bk), K innermost ("arbitrary"); fp32 VMEM scratch
-accumulator(s), flushed to the output tile on the last K step.  MXU dims
-(bm, bk, bn) default to 128-multiples.
+accumulator(s), flushed to the output tile on the last K step.  The
+blocks are 128-multiples; the wrapper picks them by shape
+(``ops._default_tiling``), and a kernel whose counted footprint
+(:func:`vmem_bytes`) passes the chip's default scoped VMEM limit asks
+for that much.
 
 K-padding note: with a *float* activation operand, padded K positions
 contribute zero automatically (x is zero-padded).  With a *code*
@@ -54,8 +57,32 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.exponential_quant import decode_meta, encode_meta
 
 EPILOGUES = ("gelu", "silu", "relu")
-_PARALLEL_K_LAST = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+# the chip's default scoped VMEM limit (TPU v5e); a kernel whose counted
+# footprint passes it asks for what it counted
+SCOPED_VMEM = 16 * 1024 * 1024
+
+
+def vmem_bytes(kind: str, bm: int, bk: int, bn: int) -> int:
+    """Counted VMEM of one grid step of a ``kind`` kernel (``mm``,
+    ``gated``, ``dual``, ``dual_gated``) at blocks (bm, bk, bn): two
+    buffers of each input block and the decoded f32 form of each code
+    block (a float x block counts 8 bytes an element: two f32 buffers,
+    or two bf16 buffers and their f32 cast), the f32 accumulators and
+    two f32 buffers of the out tile."""
+    mats = 2 if kind in ("gated", "dual_gated") else 1
+    x = bm * bk * ((2 + 4) if kind.startswith("dual") else 2 * 4)
+    codes = mats * bk * bn * (2 + 4)
+    acc_out = (mats + 2) * bm * bn * 4
+    return x + codes + acc_out
+
+
+def _compiler_params(kind: str, bm: int, bk: int, bn: int):
+    """K innermost and sequential; the counted VMEM where it passes the
+    default limit."""
+    need = vmem_bytes(kind, bm, bk, bn)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=need if need > SCOPED_VMEM else None)
 
 
 def apply_activation(x: jax.Array, kind: str | None) -> jax.Array:
@@ -149,7 +176,7 @@ def lut_dequant_matmul_kernel(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_PARALLEL_K_LAST,
+        compiler_params=_compiler_params("mm", bm, bk, bn),
         interpret=interpret,
     )(x, codes.astype(jnp.uint8), qmeta.reshape(1, 4).astype(jnp.float32),
       bias.reshape(1, n).astype(jnp.float32))
@@ -217,7 +244,7 @@ def lut_dequant_matmul_gated_kernel(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_PARALLEL_K_LAST,
+        compiler_params=_compiler_params("gated", bm, bk, bn),
         interpret=interpret,
     )(x, codes_g.astype(jnp.uint8), codes_u.astype(jnp.uint8),
       qmetas.reshape(2, 4).astype(jnp.float32))
@@ -309,7 +336,7 @@ def lut_dequant_matmul_dual_kernel(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dt),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_PARALLEL_K_LAST,
+        compiler_params=_compiler_params("dual", bm, bk, bn),
         interpret=interpret,
     )(x_codes.astype(jnp.uint8), codes.astype(jnp.uint8),
       qmetas.reshape(3, 4).astype(jnp.float32),
@@ -387,7 +414,7 @@ def lut_dequant_matmul_dual_gated_kernel(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dt),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_PARALLEL_K_LAST,
+        compiler_params=_compiler_params("dual_gated", bm, bk, bn),
         interpret=interpret,
     )(x_codes.astype(jnp.uint8), codes_g.astype(jnp.uint8),
       codes_u.astype(jnp.uint8), qmetas.reshape(4, 4).astype(jnp.float32))

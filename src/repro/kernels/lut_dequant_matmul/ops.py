@@ -10,8 +10,9 @@ Two serving-critical behaviours live here (DESIGN.md §Fused-path):
   candidate tilings on the real device and cached persistently (JSON, see
   DESIGN.md for the format), keyed by the chip's ``device_kind``.
   Tuning only triggers on a real TPU backend (or with
-  ``REPRO_AUTOTUNE=1``); CPU/interpret runs use the default tiling so
-  tests never pay tuning time.  A candidate that fails to compile or run
+  ``REPRO_AUTOTUNE=1``); CPU/interpret runs use the default tiling
+  (:func:`_default_tiling`, picked by shape) so tests never pay tuning
+  time.  A candidate that fails to compile or run
   is counted (:func:`tune_stats`), never silently dropped.
 """
 
@@ -26,10 +27,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.lut_dequant_matmul.lut_dequant_matmul import (
+    SCOPED_VMEM,
     lut_dequant_matmul_dual_gated_kernel,
     lut_dequant_matmul_dual_kernel,
     lut_dequant_matmul_gated_kernel,
     lut_dequant_matmul_kernel,
+    vmem_bytes,
 )
 from repro.kernels.lut_dequant_matmul.ref import (
     lut_dequant_matmul_dual_gated_ref,
@@ -43,7 +46,17 @@ from repro.caches import repo_cache_path
 # ladder, multiples of 512 (decode batches and prefill token counts both
 # land there).
 M_LADDER = (8, 16, 32, 64, 128, 256, 512)
-_VMEM_BUDGET = 8 * 1024 * 1024
+# The default blocks: a grid step has a fixed cost (≈ 0.36 µs on a TPU
+# v5e, where a 128 x 128 block's decode and matmul take less), so a step
+# takes the whole contraction (up to _MAX_BK), the columns that make
+# about _STEP_CODES weight codes a matrix, and up to _MAX_BM rows (every
+# M bucket past 512 is a multiple of 512), within _VMEM_BUDGET counted
+# (a quarter of a v5e core's 128 MB; past SCOPED_VMEM the kernel raises
+# its limit to what it counted).
+_MAX_BM = 512
+_MAX_BK = 2048
+_STEP_CODES = 1024 * 1024
+_VMEM_BUDGET = 2 * SCOPED_VMEM
 # v3: keys lead with the chip's device_kind (not the backend name) and
 # lost the decode-mode component (one decode remains).
 _TUNE_VERSION = 3
@@ -83,18 +96,31 @@ def _pad_axis_to(x, size, axis):
     return jnp.pad(x, widths)
 
 
-def _default_tiling(m_pad: int, k_pad: int, n_pad: int):
-    return (min(128, m_pad), min(128, k_pad), min(128, n_pad))
+def _blocks(size: int, cap: int) -> list[int]:
+    """128-multiples that divide ``size`` and are at most ``cap``,
+    largest first."""
+    return [b for b in range(cap - cap % 128, 0, -128) if size % b == 0]
+
+
+def _default_tiling(m_pad: int, k_pad: int, n_pad: int, kind: str = "mm"):
+    """(bm, bk, bn) for a ``kind`` call (``mm``, ``gated``, ``dual``,
+    ``dual_gated``) of padded shape (m_pad, k_pad, n_pad): the largest
+    bk dividing K up to _MAX_BK, then the largest bn dividing N up to
+    _STEP_CODES codes a block, shrinking bn before bk until the counted
+    VMEM (:func:`vmem_bytes`) fits _VMEM_BUDGET."""
+    bm = min(m_pad, _MAX_BM)
+    for bk in _blocks(k_pad, _MAX_BK):
+        for bn in _blocks(n_pad, max(128, _STEP_CODES // bk)):
+            if vmem_bytes(kind, bm, bk, bn) <= _VMEM_BUDGET:
+                return bm, bk, bn
+    return bm, 128, 128
 
 
 def _candidate_tilings(m_pad: int, k_pad: int, n_pad: int,
-                       dual: bool = False):
-    """Divisibility- and VMEM-feasible (bm, bk, bn) candidates.
-    ``dual`` sizes for the gated kernel (two codes blocks, two
-    accumulators)."""
+                       kind: str = "mm"):
+    """Divisibility- and VMEM-feasible (bm, bk, bn) candidates for a
+    ``kind`` call."""
     out = []
-    n_codes = 2 if dual else 1
-    n_acc = 2 if dual else 1
     for bm in (32, 64, 128, 256):
         if bm > m_pad or m_pad % bm:
             continue
@@ -104,12 +130,9 @@ def _candidate_tilings(m_pad: int, k_pad: int, n_pad: int,
             for bn in (128, 256, 512):
                 if bn > n_pad or n_pad % bn:
                     continue
-                vmem = (bm * bk * 4                     # x block
-                        + n_codes * bk * bn             # codes (uint8)
-                        + (n_acc + 1) * bm * bn * 4)    # acc(s) + out tile
-                if vmem <= _VMEM_BUDGET:
+                if vmem_bytes(kind, bm, bk, bn) <= _VMEM_BUDGET:
                     out.append((bm, bk, bn))
-    default = _default_tiling(m_pad, k_pad, n_pad)
+    default = _default_tiling(m_pad, k_pad, n_pad, kind)
     if default not in out:
         out.insert(0, default)
     return out
@@ -280,10 +303,9 @@ def _tiling_for(kind: str, m_pad: int, k_pad: int, n_pad: int, xrep: str,
                 extra: str, interpret: bool, autotune: bool | None,
                 bench_factory=None):
     if not _autotune_enabled(autotune, interpret):
-        return _default_tiling(m_pad, k_pad, n_pad)
+        return _default_tiling(m_pad, k_pad, n_pad, kind)
     key = _tune_key(kind, m_pad, k_pad, n_pad, xrep, extra)
-    cands = _candidate_tilings(
-        m_pad, k_pad, n_pad, dual=kind in ("gated", "dual_gated"))
+    cands = _candidate_tilings(m_pad, k_pad, n_pad, kind)
     return _TUNER.get(key, cands, bench_factory(cands))
 
 

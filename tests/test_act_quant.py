@@ -95,10 +95,14 @@ class TestExponentIdentity:
         ref = np.asarray(eq.decode(ca, pa) @ eq.decode(cw, pw))
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
-    def test_k_padding_masked(self):
+    @pytest.mark.parametrize("m,k,n", [(9, 100, 17), (9, 300, 17),
+                                       (16, 4200, 40)])
+    def test_k_padding_masked(self, m, k, n):
         """K not a lane multiple: a zero pad BYTE is a live code (it
-        decodes to ±(α·base^e_min + β)) — the kernel must mask it."""
-        (a, ca, pa), (w, cw, pw) = _coded_pair(4, 9, 100, 17, 7, 6)
+        decodes to ±(α·base^e_min + β)) — the kernel must mask it, in a
+        block that covers the whole contraction (300) and in the last of
+        several (4200 -> blocks of 1408)."""
+        (a, ca, pa), (w, cw, pw) = _coded_pair(4, m, k, n, 7, 6)
         out = np.asarray(kops.lut_dequant_matmul_dual(
             ca, cw, eq.pack_qmeta(pa), eq.pack_qmeta(pw),
             out_dtype=jnp.float32))

@@ -274,6 +274,122 @@ class TestAutotuner:
         assert os.path.exists(path)
 
 
+_KERNEL_KINDS = {"lut_dequant_matmul_kernel": "mm",
+                 "lut_dequant_matmul_gated_kernel": "gated",
+                 "lut_dequant_matmul_dual_kernel": "dual",
+                 "lut_dequant_matmul_dual_gated_kernel": "dual_gated"}
+_PUBLIC_OPS = ("lut_dequant_matmul", "lut_dequant_matmul_gated",
+               "lut_dequant_matmul_dual", "lut_dequant_matmul_dual_gated")
+
+
+def _served_matmuls(arch: str, act_codes: bool) -> list:
+    """Every weight-code matmul that a decode step and a prefill step
+    (16 slots, chunks of 256) of ``arch`` at its published widths call:
+    (kind, codes as the model holds them, codes as the kernel gets
+    them, x rows the kernel gets, (bm, bk, bn)).  Traced, never run."""
+    from repro.models.layers import ACT_SITES
+    from repro.runtime.calibration import PER_HEAD_SITES
+    from repro.runtime.paged_cache import PagedView
+
+    cfg = get_config(arch).replace(num_layers=2)
+    api = mapi.get_model(cfg)
+    params, _ = ll.abstract_quantize(api.abstract_params(),
+                                     api.logical_axes(), bits=7)
+    S = jax.ShapeDtypeStruct
+    if act_codes:
+        lead = {s: (2, cfg.num_kv_heads) if s in PER_HEAD_SITES else (2,)
+                for s in ACT_SITES}
+        params["blocks"]["act_q"] = {
+            s: {"lut": S(lead[s] + (256,), jnp.float32),
+                "qmeta": S(lead[s] + (4,), jnp.float32)} for s in ACT_SITES}
+    slots, chunk, pages = 16, 256, (2, 33, 16)
+    paging = (S((slots, 128), jnp.int32), S((slots,), jnp.int32))
+    if cfg.latent_dim:
+        view = PagedView(S(pages + (cfg.latent_dim,), jnp.float8_e4m3fn),
+                         None, *paging)
+    else:
+        pool = S(pages + (cfg.num_kv_heads, cfg.resolved_head_dim),
+                 jnp.uint8 if act_codes else jnp.float8_e4m3fn)
+        view = PagedView(pool, pool, *paging)
+
+    raw, seen = [], []
+
+    def public(name):
+        orig = getattr(kops, name)
+
+        def spy(x, codes, *a, **k):
+            raw.append(codes.shape)
+            return orig(x, codes, *a, **k)
+        return spy
+
+    def kernel(name):
+        orig = getattr(kops, name)
+
+        def spy(x, codes, *a, **k):
+            seen.append((_KERNEL_KINDS[name], raw[len(seen)], codes.shape,
+                         x.shape[0], (k["bm"], k["bk"], k["bn"]),
+                         k.get("w_transposed", False)))
+            return orig(x, codes, *a, **k)
+        return spy
+
+    with mock.patch.multiple(
+            kops, **{n: public(n) for n in _PUBLIC_OPS},
+            **{n: kernel(n) for n in _KERNEL_KINDS}):
+        jax.eval_shape(
+            lambda p, v, t, a: api.decode_step_paged(p, v, t, a, cfg),
+            params, view, S((slots, 1), jnp.int32), S((slots,), jnp.bool_))
+        jax.eval_shape(
+            lambda p, t, v, s: api.prefill_into_cache(p, t, v, cfg, s),
+            params, S((slots, chunk), jnp.int32), view,
+            S((slots,), jnp.int32))
+    assert len(raw) == len(seen) and seen
+    return seen
+
+
+class TestDefaultTiling:
+    """The tiles the wrapper picks with the autotuner off, at every
+    weight-code matmul the benchmark's three models serve."""
+
+    @pytest.mark.parametrize("arch,act_codes", [
+        ("qwen3-1.7b", False), ("olmo-1b", True),
+        ("deepseek-v2-lite", False)])
+    def test_served_shapes(self, arch, act_codes):
+        from repro.kernels.lut_dequant_matmul.lut_dequant_matmul import (
+            SCOPED_VMEM, _compiler_params, vmem_bytes)
+
+        kinds = set()
+        for kind, raw, padded, m_pad, tile, transposed in _served_matmuls(
+                arch, act_codes):
+            kinds.add(kind)
+            # no code table is padded past the next multiple of 128
+            assert padded == tuple(-(-d // 128) * 128 for d in raw), (
+                raw, padded)
+            n_pad, k_pad = padded if transposed else padded[::-1]
+            bm, bk, bn = tile
+            assert m_pad % bm == 0 and k_pad % bk == 0 and n_pad % bn == 0
+            assert bm == min(m_pad, 512)
+            if k_pad <= 2048:
+                assert bk == k_pad, (kind, padded, tile)
+            else:
+                assert bk == max(kops._blocks(k_pad, 2048)), (padded, tile)
+            # the counted footprint, decoded f32 tiles included, fits
+            # the budget and the VMEM limit the kernel asks for
+            need = vmem_bytes(kind, bm, bk, bn)
+            limit = (_compiler_params(kind, bm, bk, bn).vmem_limit_bytes
+                     or SCOPED_VMEM)
+            assert need <= min(limit, kops._VMEM_BUDGET), (kind, tile)
+        assert kinds == ({"dual", "dual_gated", "mm"} if act_codes
+                         else {"gated", "mm"})
+
+    @pytest.mark.parametrize("budget_mib,tile", [
+        (4, (16, 2048, 128)), (2, (16, 1024, 128))])
+    def test_shrinks_bn_before_bk(self, budget_mib, tile):
+        """Past the VMEM budget the rule narrows the columns first, and
+        the contraction only once the columns are down to 128."""
+        with mock.patch.object(kops, "_VMEM_BUDGET", budget_mib << 20):
+            assert kops._default_tiling(16, 2048, 8192, "gated") == tile
+
+
 class TestDecodeGQAAnyLength:
     @pytest.mark.parametrize("max_len", [77, 130, 300, 512])
     def test_odd_max_len(self, max_len):

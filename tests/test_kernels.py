@@ -28,7 +28,7 @@ class TestLutDequantMatmul:
     @pytest.mark.parametrize(
         "m,k,n,bits",
         [(8, 128, 128, 4), (100, 256, 384, 6), (128, 128, 256, 7),
-         (33, 130, 70, 5)])
+         (33, 130, 70, 5), (16, 6144, 256, 7)])
     def test_shapes_vs_ref(self, m, k, n, bits):
         r = np.random.default_rng(m * 1000 + n)
         w = jnp.asarray(r.normal(size=(k, n)) * 0.05, jnp.float32)

@@ -2,8 +2,8 @@
 
 Each test lowers one kernel variant at qwen3-1.7b widths (d_model 2048,
 d_ff 6144, 8 KV heads x 128, 2 query heads per KV head, vocab 151,936,
-pages of 16, 8 slots, prefill chunk 256), or at deepseek-v2-lite's for
-its latent attention and grouped experts, and compiles it with the TPU
+pages of 16, 8 slots, prefill chunk 256), or at olmo-1b's (d_ff 8192) or
+deepseek-v2-lite's for its head, latent attention and grouped experts, and compiles it with the TPU
 compiler for one chip of a ``v5e:2x2`` topology, which needs no chip
 attached.  That catches what interpret mode cannot: layouts Mosaic
 refuses, casts it has no lowering for, VMEM over-use.  Nothing runs.
@@ -78,39 +78,65 @@ def _compile(sharding, fn, shapes, **static):
 F32, U8 = jnp.float32, jnp.uint8
 
 
-@pytest.mark.parametrize("m,k,n,bm", [(8, D, 2 * D, 8), (2048, D, D, 128)])
-def test_matmul(one_chip, m, k, n, bm):
+# blocks: "128" is 128 x 128 (bm up to 128); "rule" is what the ops
+# wrapper picks by shape with the autotuner off
+OLMO_FF, HEAD_DS = 8192, 102_400
+
+
+def _blocks(which, kind, m, k, n):
+    from repro.kernels.lut_dequant_matmul import ops as kops
+
+    if which == "128":
+        return dict(bm=min(m, 128), bk=128, bn=128)
+    bm, bk, bn = kops._default_tiling(kops.bucket_m(m), k, n, kind)
+    return dict(bm=bm, bk=bk, bn=bn)
+
+
+@pytest.mark.parametrize("m,k,n,which", [
+    (8, D, 2 * D, "128"), (2048, D, D, "128"),
+    (16, D, D, "rule"), (16, D, N_KV * HD, "rule"), (16, FF, D, "rule"),
+    (2048, D, D, "rule"), (2048, FF, D, "rule"),
+    (16, D, HEAD_DS, "rule")])          # deepseek-v2-lite's untied head
+def test_matmul(one_chip, m, k, n, which):
     _compile(one_chip, lut_dequant_matmul_kernel,
              [((m, k), F32), ((k, n), U8), ((1, 4), F32), ((n,), F32)],
-             bm=bm, bk=128, bn=128)
+             **_blocks(which, "mm", m, k, n))
 
 
-def test_matmul_tied_unembed(one_chip):
+@pytest.mark.parametrize("m,which", [(8, "128"), (16, "rule")])
+def test_matmul_tied_unembed(one_chip, m, which):
     _compile(one_chip, lut_dequant_matmul_kernel,
-             [((8, D), F32), ((VOCAB, D), U8), ((1, 4), F32),
+             [((m, D), F32), ((VOCAB, D), U8), ((1, 4), F32),
               ((VOCAB,), F32)],
-             bm=8, bk=128, bn=128, w_transposed=True)
+             **_blocks(which, "mm", m, D, VOCAB), w_transposed=True)
 
 
-def test_matmul_gated(one_chip):
+@pytest.mark.parametrize("m,which", [(8, "128"), (16, "rule"),
+                                     (2048, "rule")])
+def test_matmul_gated(one_chip, m, which):
     _compile(one_chip, lut_dequant_matmul_gated_kernel,
-             [((8, D), F32), ((D, FF), U8), ((D, FF), U8), ((2, 4), F32)],
-             bm=8, bk=128, bn=128)
+             [((m, D), F32), ((D, FF), U8), ((D, FF), U8), ((2, 4), F32)],
+             **_blocks(which, "gated", m, D, FF))
 
 
 @pytest.mark.parametrize("out_quant", [False, True])
-@pytest.mark.parametrize("m,bm", [(8, 8), (2048, 128)])
-def test_matmul_dual(one_chip, out_quant, m, bm):
+@pytest.mark.parametrize("m,k,which", [
+    (8, D, "128"), (2048, D, "128"),
+    (16, D, "rule"), (4096, D, "rule"),        # olmo-1b attention
+    (16, OLMO_FF, "rule"), (4096, OLMO_FF, "rule")])   # and down
+def test_matmul_dual(one_chip, out_quant, m, k, which):
     _compile(one_chip, lut_dequant_matmul_dual_kernel,
-             [((m, D), U8), ((D, D), U8), ((3, 4), F32), ((D,), F32)],
-             bm=bm, bk=128, bn=128, out_quant=out_quant)
+             [((m, k), U8), ((k, D), U8), ((3, 4), F32), ((D,), F32)],
+             **_blocks(which, "dual", m, k, D), out_quant=out_quant)
 
 
 @pytest.mark.parametrize("out_quant", [False, True])
-def test_matmul_dual_gated(one_chip, out_quant):
+@pytest.mark.parametrize("m,n,which", [
+    (8, FF, "128"), (16, OLMO_FF, "rule"), (4096, OLMO_FF, "rule")])
+def test_matmul_dual_gated(one_chip, out_quant, m, n, which):
     _compile(one_chip, lut_dequant_matmul_dual_gated_kernel,
-             [((8, D), U8), ((D, FF), U8), ((D, FF), U8), ((4, 4), F32)],
-             bm=8, bk=128, bn=128, out_quant=out_quant)
+             [((m, D), U8), ((D, n), U8), ((D, n), U8), ((4, 4), F32)],
+             **_blocks(which, "dual_gated", m, D, n), out_quant=out_quant)
 
 
 _PAGES = (POOL, BLOCK, N_KV, HD)
